@@ -74,15 +74,14 @@ def bench_main(sizes_mb):
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from sparkdl_tpu.utils.jax_compat import axis_size, shard_map
 
     by_proc = {}
     for d in jax.devices():
         by_proc.setdefault(d.process_index, d)
     mesh = Mesh(np.array([by_proc[p] for p in sorted(by_proc)]), ("hvd",))
     psum = jax.jit(
-        shard_map(
-            lambda x: jax.lax.psum(x, "hvd") / axis_size("hvd"),
+        jax.shard_map(
+            lambda x: jax.lax.psum(x, "hvd") / jax.lax.axis_size("hvd"),
             mesh=mesh, in_specs=P("hvd"), out_specs=P(),
         ),
         out_shardings=NamedSharding(mesh, P()),

@@ -150,7 +150,6 @@ def ring_section(tiny):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from sparkdl_tpu.parallel.ring_attention import ring_self_attention
-    from sparkdl_tpu.utils.jax_compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:n]).reshape(1, n),
                 ("data", "seq"))
@@ -162,7 +161,7 @@ def ring_section(tiny):
     rows, metrics = [], {}
     out = {}
     for name, overlap in (("overlap", True), ("serialized", False)):
-        ring = jax.jit(shard_map(
+        ring = jax.jit(jax.shard_map(
             partial(ring_self_attention, axis_name="seq", causal=True,
                     overlap=overlap),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

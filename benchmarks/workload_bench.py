@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Reference-workload benchmarks that don't need the TPU lease
+"""Reference-workload benchmarks that need no accelerator
 (BASELINE.json configs 1 and 3):
 
 - MNIST Keras CNN through ``HorovodRunner(np=-1)`` — the reference's

@@ -29,7 +29,7 @@ setup(
     install_requires=[
         "numpy",
         "cloudpickle",
-        "jax",
+        "jax>=0.9.0",
         "flax",
         "optax",
         "einops",

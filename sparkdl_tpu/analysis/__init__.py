@@ -127,13 +127,15 @@ def _context_for(fn, args, *, compile=True, params=None, shardings=None,
                  mesh=None, name=None, options=None):
     import contextlib
 
+    import jax
+
     from sparkdl_tpu.utils import jax_compat
 
     ctx_mgr = mesh if mesh is not None else contextlib.nullcontext()
     jaxpr = hlo_text = stablehlo = memory_stats = compiled = None
     with ctx_mgr:
         try:
-            jaxpr = jax_compat.closed_jaxpr(fn, *args)
+            jaxpr = jax.make_jaxpr(fn)(*args)
         except Exception:
             jaxpr = None
         lowered = jax_compat.lower(fn, *args)
@@ -153,7 +155,7 @@ def _context_for(fn, args, *, compile=True, params=None, shardings=None,
         param_info=info,
         example_args=tuple(args),
         fn=fn,
-        x64_enabled=jax_compat.x64_enabled(),
+        x64_enabled=bool(jax.config.jax_enable_x64),
         memory_stats=memory_stats,
         options=options or {},
         lowered=lowered,
@@ -176,6 +178,8 @@ def lint_fn(fn, *args, compile=True, params=None, shardings=None,
 
 def _lowered_context(lowered, *, params=None, shardings=None,
                      compile=True, name=None, options=None):
+    import jax
+
     from sparkdl_tpu.utils import jax_compat
 
     info = None
@@ -192,7 +196,7 @@ def _lowered_context(lowered, *, params=None, shardings=None,
         hlo_text=hlo_text,
         stablehlo_text=jax_compat.lowered_stablehlo(lowered),
         param_info=info,
-        x64_enabled=jax_compat.x64_enabled(),
+        x64_enabled=bool(jax.config.jax_enable_x64),
         memory_stats=memory_stats,
         options=options or {},
         lowered=lowered,
@@ -213,6 +217,8 @@ def lint_lowered(lowered, *, params=None, shardings=None, compile=True,
 
 def _compiled_context(compiled, *, params=None, shardings=None,
                       name=None, options=None):
+    import jax
+
     from sparkdl_tpu.utils import jax_compat
 
     info = None
@@ -222,7 +228,7 @@ def _compiled_context(compiled, *, params=None, shardings=None,
         fn_name=name or "<compiled>",
         hlo_text=compiled.as_text(),
         param_info=info,
-        x64_enabled=jax_compat.x64_enabled(),
+        x64_enabled=bool(jax.config.jax_enable_x64),
         memory_stats=jax_compat.memory_analysis(compiled),
         options=options or {},
         compiled=compiled,
@@ -288,14 +294,14 @@ def lint_gang(fns_or_jaxprs, args_per_rank=None, names=None):
     from sparkdl_tpu.analysis.passes_collectives import (
         check_gang_consistency,
     )
-    from sparkdl_tpu.utils import jax_compat
+    import jax
 
     jaxprs = []
     for i, obj in enumerate(fns_or_jaxprs):
         if callable(obj) and not hasattr(obj, "eqns") \
                 and not hasattr(obj, "jaxpr"):
             args = args_per_rank[i] if args_per_rank else ()
-            jaxprs.append(jax_compat.closed_jaxpr(obj, *args))
+            jaxprs.append(jax.make_jaxpr(obj)(*args))
         else:
             jaxprs.append(obj)
     return check_gang_consistency(jaxprs, names=names)

@@ -22,8 +22,10 @@ COLLECTIVE_PRIMS = frozenset({
 _REAL_COLLECTIVES = COLLECTIVE_PRIMS - {"axis_index"}
 
 # Primitives that force a device->host round trip (or a host->device
-# one) inside the step.
-HOST_CALLBACK_MARKERS = ("callback", "outside_call", "infeed", "outfeed")
+# one) inside the step. jax.debug.print is its own primitive
+# (``debug_print``), not a ``debug_callback``.
+HOST_CALLBACK_MARKERS = ("callback", "debug_print", "outside_call",
+                         "infeed", "outfeed")
 
 
 def _subjaxprs(params):

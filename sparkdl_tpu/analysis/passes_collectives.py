@@ -12,6 +12,8 @@ A TPU gang dies two ways that compile cleanly and dryrun green:
   scale, invisible on tiny dryrun shapes.
 """
 
+import re
+
 from sparkdl_tpu.analysis import hlo as hlo_mod
 from sparkdl_tpu.analysis import jaxpr_walk
 from sparkdl_tpu.analysis.core import Finding, Severity, register_pass
@@ -144,6 +146,9 @@ def check_gang_consistency(jaxprs, names=None):
     return findings
 
 
+_GATHER_DIM_RE = re.compile(r"\bdimensions=\{(\d+)\}")
+
+
 @register_pass("full-param-allgather",
                requires=("hlo_text", "param_info"),
                severities=("ERROR", "WARNING"))
@@ -155,8 +160,13 @@ def full_param_allgather(ctx):
     Tiers:
 
     - ERROR — the gather result is *exactly* a TP-sharded param's
-      full (dtype, shape): XLA is rematerializing the unsharded
-      weight, i.e. a lost sharding constraint.
+      full (dtype, shape), gathered along a dimension the param is
+      sharded on: XLA is rematerializing the unsharded weight, i.e. a
+      lost sharding constraint. A gather along a dimension the param
+      keeps whole cannot rebuild it — that is an activation whose
+      per-device shape happens to equal the param's (the graft
+      driver's (batch/data, seq, 16) pipeline input against its
+      (stages, 16, 16) pipeline weight).
     - WARNING — same dims in a different order (a relaid-out /
       transposed full copy), which is how the regather shows up when
       XLA also changed the layout.
@@ -185,7 +195,12 @@ def full_param_allgather(ctx):
             n = 1
             for d in shape:
                 n *= d
-            exact = by_shape.get((dtype, shape))
+            gdim = _GATHER_DIM_RE.search(col.line)
+            gdim = int(gdim.group(1)) if gdim else None
+            exact = [
+                p for p in by_shape.get((dtype, shape), ())
+                if gdim is None or gdim >= len(p.spec) or p.spec[gdim]
+            ]
             relaid = by_sorted.get((dtype, tuple(sorted(shape))))
             if exact:
                 names = ", ".join(p.path for p in exact)

@@ -84,10 +84,6 @@ def _shadow_trace_findings(ctx):
     import jax
 
     try:
-        from jax.experimental import enable_x64
-    except ImportError:  # pragma: no cover - very old jax
-        return []
-    try:
         base = jax.eval_shape(ctx.fn, *ctx.example_args)
         # Pin the arg avals to their canonicalized (32-bit) dtypes
         # BEFORE entering x64, so only *internal* 64-bit constants/ops
@@ -96,7 +92,7 @@ def _shadow_trace_findings(ctx):
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype),
             jax.eval_shape(lambda *a: a, *ctx.example_args),
         )
-        with enable_x64():
+        with jax.enable_x64(True):
             wide = jax.eval_shape(ctx.fn, *pinned)
     except Exception as e:  # tracing is user code; never let it throw
         return [Finding(

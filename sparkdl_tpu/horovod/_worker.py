@@ -102,7 +102,7 @@ def worker_io(rank, local_log_path=None):
     if client is not None:
         # Fail-fast failure detection in BOTH directions: the launcher
         # reaps dead workers; this reaps workers whose DRIVER died
-        # (even via SIGKILL) so orphans never pin chips or leases —
+        # (even via SIGKILL) so orphans never keep their chips —
         # and the same watchdog thread answers the driver's
         # hang-diagnosis DUMP_REQ frames with faulthandler stacks.
         client.start_driver_watchdog()

@@ -620,6 +620,12 @@ class ControlPlaneClient:
             except (RuntimeError, OSError):
                 self._native = None
 
+    @property
+    def log_transport(self):
+        """``"native"`` when log lines ride the C++ drop-oldest sender,
+        ``"python"`` when they ride this client's own socket."""
+        return "native" if self._native is not None else "python"
+
     def _send(self, mtype, payload):
         # Fault-injection hook (inert without SPARKDL_TPU_CHAOS_* env):
         # the chaos harness can delay or drop control frames to

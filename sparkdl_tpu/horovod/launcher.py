@@ -24,6 +24,8 @@ automatically when pyspark is importable (see
 :mod:`sparkdl_tpu.horovod.spark_backend`).
 """
 
+import collections
+import functools
 import logging
 import os
 import socket
@@ -36,14 +38,19 @@ from sparkdl_tpu.horovod.topology import HOSTS_ENV
 from sparkdl_tpu.hvd._state import COORD_ENV
 
 COORD_PORT_ENV = "SPARKDL_TPU_COORDINATOR_PORT"
-# Warm-start compilation: when the driver sets this env, every worker
-# env carries it (local Popen children inherit it via _worker_env's
-# base_env copy; remote ranks ride the SPARKDL_TPU_* forward), every
-# supervised relaunch re-ships it, and _worker.py points JAX's
-# persistent compile cache at it before backend init. The module is
-# import-light (jax only inside functions), so the launcher can take
-# the constant from its canonical home.
-from sparkdl_tpu.parallel.compile import COMPILE_CACHE_DIR_ENV
+# Warm-start compilation: when the driver's environment names a
+# compile cache (JAX_COMPILATION_CACHE_DIR, or below it
+# SPARKDL_TPU_COMPILE_CACHE_DIR), every worker env carries it (local
+# Popen children inherit it via _worker_env's base_env copy; remote
+# ranks ride the forward in _remote_worker_cmd), every supervised
+# relaunch re-ships it, and _worker.py turns JAX's persistent compile
+# cache on there before backend init. The module is import-light (jax
+# only inside functions), so the launcher can take its names from
+# their canonical home.
+from sparkdl_tpu.parallel.compile import (
+    JAX_CACHE_DIR_ENV,
+    persistent_cache_dir,
+)
 
 logger = logging.getLogger("HorovodRunner")
 
@@ -86,24 +93,40 @@ def _free_port():
     return port
 
 
-def _probe_local_device_count(platform):
+LocalDevices = collections.namedtuple(
+    "LocalDevices", "count platform chip_bounds")
+
+
+@functools.lru_cache(maxsize=None)
+def probe_local_devices(platform):
     """Count local accelerator devices WITHOUT initializing a backend in
     the driver process (a driver that claims the TPU would starve its
     own workers — the analogue of the reference's driver-has-no-GPU
-    assumption, ``runner_base.py:44-45``)."""
+    assumption, ``runner_base.py:44-45``): a child does, reports what
+    it saw (count, platform, and the chip grid its devices span) and
+    has exited — ``subprocess.run`` reaps it — before any worker is
+    spawned. Cached: a host's devices do not change under a driver,
+    and a supervised relaunch need not pay the child's backend
+    start-up again (failures are not cached)."""
     if platform == "cpu":
-        return os.cpu_count() or 1
+        return LocalDevices(os.cpu_count() or 1, "cpu", None)
     code = (
         "import jax\n"
         + (f"jax.config.update('jax_platforms', {platform!r})\n" if platform else "")
-        + "print(jax.local_device_count())\n"
+        + "ds = jax.local_devices()\n"
+        "cs = [getattr(d, 'coords', None) or (i, 0, 0) "
+        "for i, d in enumerate(ds)]\n"
+        "print(len(ds), ds[0].platform, "
+        "','.join(str(max(c) + 1) for c in zip(*cs)))\n"
     )
     try:
         out = subprocess.run(
             [sys.executable, "-c", code],
             capture_output=True, text=True, timeout=120,
         )
-        return int(out.stdout.strip().splitlines()[-1])
+        count, seen, bounds = out.stdout.strip().splitlines()[-1].split()
+        return LocalDevices(
+            int(count), seen, tuple(int(b) for b in bounds.split(",")))
     except subprocess.TimeoutExpired:
         raise SlotProbeError(
             "slot discovery timed out after 120s probing local "
@@ -128,7 +151,7 @@ def available_slots():
     override = os.environ.get(NUM_SLOTS_ENV)
     if override:
         return int(override)
-    return _probe_local_device_count(os.environ.get(WORKER_PLATFORM_ENV))
+    return probe_local_devices(os.environ.get(WORKER_PLATFORM_ENV)).count
 
 
 # -- slot registry ----------------------------------------------------------
@@ -289,7 +312,7 @@ def _resolve_num_workers(np_arg, placement=None):
 
 def _worker_env(base_env, *, rank, size, coordinator, control_addr,
                 control_secret, payload_path, job_dir, platform,
-                placement=None):
+                placement=None, tpu_chip_bounds=None):
     from sparkdl_tpu.horovod.topology import Placement
 
     env = dict(base_env)
@@ -314,7 +337,13 @@ def _worker_env(base_env, *, rank, size, coordinator, control_addr,
         placement = Placement.from_env(base_env)
     if placement is None:
         placement = Placement.single_host(size)
-    for k, v in placement.env_for_rank(rank, tpu=platform == "tpu").items():
+    # One chip per rank when the operator says the workers are TPU
+    # workers — or when this host's slot probe SAW TPU chips
+    # (tpu_chip_bounds): a chip host that sets nothing must not let
+    # every worker take every chip.
+    for k, v in placement.env_for_rank(
+            rank, tpu=platform == "tpu" or tpu_chip_bounds is not None,
+            chip_bounds=tpu_chip_bounds).items():
         if (k in ("TPU_PROCESS_BOUNDS", "TPU_CHIPS_PER_PROCESS_BOUNDS")
                 and base_env.get(k)):
             # An operator-exported slice layout (e.g. a 2D "2,2,1"
@@ -396,7 +425,7 @@ def _remote_worker_cmd(shell_tokens, host, env, base_env, remote_python):
     fwd = {
         k: v for k, v in env.items()
         if (k.startswith(("SPARKDL_TPU_", "TPU_"))
-            or k == "CLOUD_TPU_TASK_ID"
+            or k in ("CLOUD_TPU_TASK_ID", JAX_CACHE_DIR_ENV)
             or base_env.get(k) != v)
         and k != "XLA_FLAGS"
     }
@@ -802,6 +831,14 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
             "all" if mode == "local" else driver_log_verbosity
         )
         platform = os.environ.get(WORKER_PLATFORM_ENV)
+        tpu_chip_bounds = None
+        if (mode == "cluster" and spec_placement is None
+                and not os.environ.get(NUM_SLOTS_ENV)):
+            # what the slot probe of _resolve_num_workers saw (cached);
+            # SPARKDL_TPU_NUM_SLOTS bypasses discovery here as there
+            local = probe_local_devices(platform)
+            if local.platform == "tpu":
+                tpu_chip_bounds = local.chip_bounds
         server = ControlPlaneServer(
             num_workers,
             verbosity=effective_verbosity,
@@ -853,7 +890,7 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
             "Launching HorovodRunner gang: %d worker(s), mode=%s, job_dir=%s",
             num_workers, mode, job_dir,
         )
-        compile_cache = os.environ.get(COMPILE_CACHE_DIR_ENV)
+        compile_cache = persistent_cache_dir()
         if compile_cache:
             # Relaunches of a preempted gang warm-start from here: the
             # env rides every worker env (and every supervised
@@ -885,6 +922,7 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                 control_secret=server.secret,
                 payload_path=payload_paths[r], job_dir=job_dir,
                 platform=platform, placement=gang_placement,
+                tpu_chip_bounds=tpu_chip_bounds,
             )
             for pk, pv in profile_env.items():
                 env.setdefault(pk, pv)

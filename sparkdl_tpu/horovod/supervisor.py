@@ -274,7 +274,7 @@ def _is_infra_traceback(tb_text):
 def classify_failure(exc):
     """(verdict, cause): *permanent* failures are never retried.
 
-    Taxonomy (ISSUE: preemption-aware supervision):
+    Failure classes (ISSUE: preemption-aware supervision):
 
     - Typed launcher errors (slot exhaustion/probe/wait, remote
       transport) and bad arguments → permanent; the launcher already
@@ -646,10 +646,10 @@ def supervise(launch, policy, _sleep=time.sleep):
             # point BEFORE the backoff sleep, not after.
             resume = _resume_step(policy)
             from sparkdl_tpu.parallel.compile import (
-                COMPILE_CACHE_DIR_ENV,
+                persistent_cache_dir,
             )
 
-            warm = os.environ.get(COMPILE_CACHE_DIR_ENV)
+            warm = persistent_cache_dir()
             if not planned:
                 logger.warning(
                     "HorovodRunner gang failed transiently (attempt "

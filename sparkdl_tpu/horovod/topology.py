@@ -22,6 +22,7 @@ infos instead of an env spec (executors already know their hosts).
 """
 
 import functools
+import math
 import os
 
 HOSTS_ENV = "SPARKDL_TPU_HOSTS"
@@ -90,10 +91,13 @@ class Placement:
     def local_size(self, rank):
         return self.hosts[self._host_of[rank]][1]
 
-    def env_for_rank(self, rank, *, tpu=False):
+    def env_for_rank(self, rank, *, tpu=False, chip_bounds=None):
         """The per-process env for ``rank``: horovod LOCAL_* values,
         plus TPU pod-slice layout when ``tpu`` (one process per chip;
-        process grid = hosts x slots-per-host on the ICI mesh)."""
+        process grid = hosts x slots-per-host on the ICI mesh).
+        ``chip_bounds``: the chip grid of a single host as its slot
+        probe saw it (``(2, 2, 1)`` on a four-chip v5e host); a gang
+        that fills the host lays its processes out on that grid."""
         if not 0 <= rank < self.total_slots:
             raise ValueError(
                 f"rank {rank} outside gang of {self.total_slots}"
@@ -104,16 +108,32 @@ class Placement:
         }
         if tpu and self.total_slots > 1:
             # One task <-> one chip (reference runner_base.py:44-45,
-            # GPU -> TPU): restrict each worker to its own chip.
+            # GPU -> TPU): restrict each worker to its own chip
+            # (TPU_VISIBLE_CHIPS; TPU_VISIBLE_DEVICES is the same
+            # switch under its older name).
+            env["TPU_VISIBLE_CHIPS"] = str(self.local_rank(rank))
             env["TPU_VISIBLE_DEVICES"] = str(self.local_rank(rank))
             env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
-            if len(self.hosts) == 1:
-                # Single host: isolated single-chip runtimes; the gang
-                # coordinates via jax.distributed only (matches the
-                # launcher's long-standing behavior on multi-chip VMs).
-                env.setdefault("TPU_PROCESS_BOUNDS", "1,1,1")
-                return env
             slots = self.hosts[0][1]
+            if len(self.hosts) == 1:
+                # Single host, one process per chip: the processes are
+                # ONE slice over the host's chips, joined by the TPU
+                # runtime over loopback ports. Isolated 1,1,1 runtimes
+                # would each report process 0 of 1 — jax.distributed
+                # does not stitch TPU runtimes together — and every
+                # hvd collective would return its own input.
+                grid = (tuple(chip_bounds) if chip_bounds
+                        and math.prod(chip_bounds) == slots
+                        else (slots, 1, 1))
+                env.update({
+                    "TPU_PROCESS_BOUNDS": ",".join(map(str, grid)),
+                    "CLOUD_TPU_TASK_ID": str(rank),
+                    "TPU_PROCESS_PORT": str(TPU_PORT_BASE + rank),
+                    "TPU_PROCESS_ADDRESSES": ",".join(
+                        f"localhost:{TPU_PORT_BASE + r}"
+                        for r in range(slots)),
+                })
+                return env
             if any(n != slots for _, n in self.hosts):
                 raise ValueError(
                     "TPU pod slices need a uniform chips-per-host "

@@ -46,6 +46,9 @@ def is_initialized():
 
 
 def rank():
+    """This process's rank: the one the launcher gave it. It is not
+    ``jax.process_index()`` — a TPU runtime numbers a slice's
+    processes by where their chips sit."""
     _state.require_initialized()
     return _state.state().rank
 

@@ -30,13 +30,6 @@ MIN = "min"
 MAX = "max"
 
 
-# The engine must not die on an older jax — a gang that cannot build
-# its collectives takes every fault-tolerance guarantee down with it.
-from sparkdl_tpu.utils.jax_compat import (
-    axis_size as _axis_size,
-    shard_map as _shard_map,
-)
-
 
 def _observed(op_name):
     """Per-collective telemetry: op count, payload bytes, a wall-time
@@ -222,12 +215,17 @@ class _CollectiveEngine:
             if self._mesh is not None:
                 return
             # One participating device per process: rank r contributes
-            # the first addressable device of process r. Remaining local
-            # devices stay free for the user's own data-plane meshes.
+            # the first addressable device of ITS process — which the
+            # runtime need not number r (_state._exchange_ranks).
+            # Remaining local devices stay free for the user's own
+            # data-plane meshes.
             by_proc = {}
             for d in jax.devices():
                 by_proc.setdefault(d.process_index, d)
-            devs = [by_proc[p] for p in sorted(by_proc)]
+            rank_of = _state.state().rank_of_process
+            devs = [None] * len(by_proc)
+            for p in sorted(by_proc):
+                devs[rank_of[p] if rank_of else p] = by_proc[p]
             self._mesh = Mesh(np.array(devs), ("hvd",))
             mine = jax.process_index()
             self._local_device = by_proc[mine]
@@ -253,7 +251,7 @@ class _CollectiveEngine:
             # would allocate + traverse the full tensor again per call
             # (measured ~2x end-to-end allreduce time at 64 MB).
             body = lambda x: (
-                jax.lax.psum(x[0], "hvd") / _axis_size("hvd")
+                jax.lax.psum(x[0], "hvd") / jax.lax.axis_size("hvd")
             )
         elif kind == "min":
             body = lambda x: jax.lax.pmin(x[0], "hvd")
@@ -271,7 +269,7 @@ class _CollectiveEngine:
                     x[0], "hvd", scatter_dimension=0, tiled=True
                 )
                 if kind == "scatter_avg":
-                    out = out / _axis_size("hvd")
+                    out = out / jax.lax.axis_size("hvd")
                 return out
         elif kind[0] == "bcast":
             # True broadcast: binary-tree ppermute — the set of ranks
@@ -308,7 +306,7 @@ class _CollectiveEngine:
             # rank j in one collective (XLA all-to-all over ICI).
             def body(x):
                 blk = x[0]  # (n*chunk, ...)
-                n = _axis_size("hvd")
+                n = jax.lax.axis_size("hvd")
                 parts = blk.reshape((n, blk.shape[0] // n) + blk.shape[1:])
                 out = jax.lax.all_to_all(
                     parts, "hvd", split_axis=0, concat_axis=0, tiled=False
@@ -323,16 +321,13 @@ class _CollectiveEngine:
         # disable for those.
         partitioned = kind in ("alltoall", "scatter_sum", "scatter_avg")
         out_spec = P("hvd") if partitioned else P()
-        check_vma = (
-            False
-            if partitioned or kind == "gather" or kind[0] == "bcast"
-            else None
-        )
+        check_vma = not (
+            partitioned or kind == "gather" or kind[0] == "bcast")
         with self._lock:
             fn = self._fns.get(key)
             if fn is None:
                 fn = jax.jit(
-                    _shard_map(
+                    jax.shard_map(
                         body, mesh=mesh, in_specs=P("hvd"),
                         out_specs=out_spec, check_vma=check_vma,
                     ),

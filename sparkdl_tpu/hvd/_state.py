@@ -30,6 +30,9 @@ class _HvdState:
         self.local_rank = 0
         self.local_size = 1
         self.jax_distributed = False
+        # rank_of_process[p]: the hvd rank of the process the JAX
+        # runtime numbers p (multi-process gangs; see init)
+        self.rank_of_process = None
 
 
 _state = _HvdState()
@@ -40,10 +43,10 @@ def state():
 
 
 def ensure_jax_platform():
-    """Apply the forced platform before any backend initialization.
-
-    Needed because the environment may pin ``jax_platforms`` via config
-    (not env), e.g. test rigs that run gangs on CPU devices.
+    """Apply the platform the launcher forces
+    (``SPARKDL_TPU_WORKER_PLATFORM``, e.g. test rigs that run gangs on
+    CPU devices) before any backend initialization, and with it the
+    gloo collectives a multi-process CPU gang needs.
     """
     import jax
 
@@ -85,11 +88,48 @@ def init():
                         process_id=rank,
                     )
                 _state.jax_distributed = True
-            rank = jax.process_index()
-            size = jax.process_count()
+            _state.rank_of_process = _exchange_ranks(rank, size)
         _state.rank = rank
         _state.size = size
         _state.initialized = True
+
+
+def _exchange_ranks(rank, size):
+    """``hvd.rank()`` is the rank the launcher gave this process — the
+    one its payload, its control-plane connection and its log lines
+    carry — and NOT ``jax.process_index()``: a TPU runtime numbers the
+    processes of a slice by where their chips sit (on a four-chip v5e
+    host the launcher's ranks 0..3 came up as processes 1, 3, 2, 0).
+    Every process publishes its rank under its runtime index in the
+    ``jax.distributed`` key-value store; the list read back orders the
+    ``hvd`` mesh by rank (:mod:`sparkdl_tpu.hvd._collectives`).
+
+    Raises when the runtime's world is not the gang's: runtimes that
+    did not join (each reporting itself process 0 of 1) would make
+    every collective return its own input — a wrong answer, not an
+    error."""
+    import jax
+    from jax._src import distributed as _jd
+
+    if jax.process_count() != size:
+        raise RuntimeError(
+            f"rank {rank}: the JAX runtime reports "
+            f"{jax.process_count()} process(es) with "
+            f"{len(jax.devices())} device(s) for a gang of {size}; "
+            "the workers' runtimes did not join one another")
+    client = _jd.global_state.client
+    client.key_value_set(
+        f"sparkdl_tpu/hvd_rank/{jax.process_index()}", str(rank))
+    ranks = [
+        int(client.blocking_key_value_get(
+            f"sparkdl_tpu/hvd_rank/{p}", 120_000))
+        for p in range(size)
+    ]
+    if sorted(ranks) != list(range(size)):
+        raise RuntimeError(
+            f"rank {rank}: ranks by runtime process index are {ranks}, "
+            f"not a permutation of 0..{size - 1}")
+    return ranks
 
 
 def shutdown():
@@ -99,6 +139,7 @@ def shutdown():
         _state.size = 1
         _state.local_rank = 0
         _state.local_size = 1
+        _state.rank_of_process = None
 
 
 def require_initialized():

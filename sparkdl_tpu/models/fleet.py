@@ -36,7 +36,7 @@ when one replica wedges. This module adds the missing tier:
     engine from the factory — drained and doctored, not mourned.
     Restarts ride ``server_replica_restarts_total{cause=...}``.
 
-Failure taxonomy (same classes as the single frontend, one new cause
+Failure classification (the single frontend's classes, one new cause
 each): 400 = the request's fault; 500 = the engine's or its replica's
 (engine fault, replica death, replica hang); 503 = the fleet's
 lifecycle (admission refusal, no live replicas, shutdown) — "retry

@@ -4,11 +4,10 @@ training path.
 
 TPU-first design: the whole decode loop is ONE jitted program
 (``lax.scan`` over steps) — per-token Python dispatch would pay a
-host→device round trip per generated token (~25 ms on remote-tunnel
-devices, dwarfing the step itself). The jitted programs are cached
-process-wide per (decode-config, temperature), so a serving loop
-compiles on the first request only; jit's own static-argument cache
-covers varying ``max_new_tokens``.
+host→device round trip per generated token. The jitted programs are
+cached process-wide per (decode-config, temperature), so a serving
+loop compiles on the first request only; jit's own static-argument
+cache covers varying ``max_new_tokens``.
 """
 
 import dataclasses

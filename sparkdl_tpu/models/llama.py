@@ -137,8 +137,12 @@ class LlamaConfig:
 
     @classmethod
     def llama3_8b(cls, **kw):
-        return cls(vocab_size=128256, d_model=4096, n_layers=32,
-                   n_heads=32, n_kv_heads=8, d_ff=14336, **kw)
+        """Llama-3-8B's published shape; ``kw`` overrides any field,
+        the ones set here included (``n_layers=4`` cuts depth)."""
+        defaults = dict(vocab_size=128256, d_model=4096, n_layers=32,
+                        n_heads=32, n_kv_heads=8, d_ff=14336)
+        defaults.update(kw)
+        return cls(**defaults)
 
     @classmethod
     def llama31_8b(cls, **kw):

@@ -189,9 +189,8 @@ def expert_parallel_moe(mesh, cfg, *, axis_name="expert"):
             params["w_down"], axis_name=axis_name,
         )
 
-    from sparkdl_tpu.utils.jax_compat import shard_map
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(_expert_param_specs(axis_name), P()), out_specs=P(),
         check_vma=False,
@@ -273,9 +272,8 @@ def expert_parallel_moe_a2a(mesh, cfg, *, axis_name="expert",
         y = jnp.einsum("tec,ecd->td", combine, out_buf)
         return y.reshape(*lead, d)
 
-    from sparkdl_tpu.utils.jax_compat import shard_map
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(_expert_param_specs(axis_name), P(axis_name)),
         out_specs=P(axis_name),

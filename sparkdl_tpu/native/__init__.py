@@ -4,11 +4,16 @@
 drained, drop-oldest log transport that guarantees log pressure never
 blocks a training step (the reference's backpressure clause,
 ``runner_base.py:65-68``). The library is built on first use with the
-in-tree Makefile; absence of a compiler degrades gracefully to the
-pure-Python sender in :mod:`sparkdl_tpu.horovod.control_plane`.
+in-tree Makefile, under a name that carries a hash of its source, so
+only a build of THIS ``ctrl_plane.cc`` is ever loaded (``native/build``
+is git-ignored: a copied tree may carry someone else's artefact);
+absence of a compiler degrades gracefully to the pure-Python sender in
+:mod:`sparkdl_tpu.horovod.control_plane`, whose client says which of
+the two it got (``log_transport``).
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,9 +27,14 @@ _NATIVE_DIR = os.path.join(
 # stale same-named .so could never be replaced by a rebuild within this
 # process — a new ABI must land at a new path.
 _ABI_VERSION = 2
-_LIB_PATH = os.path.join(
-    _NATIVE_DIR, "build", f"libsparkdl_ctrl.v{_ABI_VERSION}.so"
-)
+
+
+def _lib_path():
+    with open(os.path.join(_NATIVE_DIR, "ctrl_plane.cc"), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(
+        _NATIVE_DIR, "build",
+        f"libsparkdl_ctrl.v{_ABI_VERSION}.{digest}.so")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -38,7 +48,11 @@ def load_ctrl_lib():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build_attempted:
+        try:
+            lib_path = _lib_path()
+        except OSError:
+            return None  # installed without the native sources
+        if not os.path.exists(lib_path) and not _build_attempted:
             _build_attempted = True
             # Concurrent first-use builds (e.g. a fresh gang's workers)
             # must not write the same .so: build into a process-unique
@@ -49,11 +63,11 @@ def load_ctrl_lib():
                     ["make", "-C", _NATIVE_DIR, f"BUILD={tmp_build}"],
                     capture_output=True, timeout=120, check=True,
                 )
-                os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+                os.makedirs(os.path.dirname(lib_path), exist_ok=True)
                 os.replace(
                     os.path.join(_NATIVE_DIR, tmp_build,
                                  "libsparkdl_ctrl.so"),
-                    _LIB_PATH,
+                    lib_path,
                 )
             except (OSError, subprocess.SubprocessError):
                 return None
@@ -64,10 +78,10 @@ def load_ctrl_lib():
                     os.path.join(_NATIVE_DIR, tmp_build),
                     ignore_errors=True,
                 )
-        if not os.path.exists(_LIB_PATH):
+        if not os.path.exists(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
         except OSError:
             return None
         if (not hasattr(lib, "sdl_abi_version")

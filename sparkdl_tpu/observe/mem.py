@@ -264,12 +264,14 @@ def static_budget_bytes():
 def largest_buffers(top_n=None):
     """The largest live device buffers aggregated by (shape, dtype):
     ``[{"shape", "dtype", "count", "bytes"}, ...]`` sorted by bytes
-    descending. Empty when jax is absent or exposes no live-array
-    API — never raises, never imports jax."""
+    descending. Empty when no jax backend is up in this process —
+    never raises, never initializes one."""
     if top_n is None:
         top_n = _env_int(TOP_BUFFERS_ENV, DEFAULT_TOP_BUFFERS)
-    jax = sys.modules.get("jax")
-    if jax is None or not hasattr(jax, "live_arrays"):
+    from sparkdl_tpu.utils.jax_compat import initialized_jax
+
+    jax = initialized_jax()
+    if jax is None:
         return []
     agg = {}
     try:

@@ -120,15 +120,12 @@ HBM_BYTES = {
     "cpu": None,
 }
 
-# Unknown accelerator kinds fall back to the v5e figure — the constant
-# every pre-perf.py copy of PEAK_FLOPS hard-coded, kept so MFU
-# trajectories survive the refactor unchanged.
-DEFAULT_KIND = "v5e"
-
-
 def normalize_device_kind(kind):
     """Map a PJRT ``device_kind`` string (``"TPU v5 lite"``,
-    ``"TPU v4"``, ``"cpu"``...) onto a :data:`PEAK_TABLE` key."""
+    ``"TPU v4"``, ``"cpu"``...) onto a :data:`PEAK_TABLE` key. A kind
+    the table does not know is an error, never a default: a peak
+    borrowed from another chip makes every utilization derived from
+    it wrong without saying so."""
     k = (kind or "").lower()
     if "v5p" in k:
         return "v5p"
@@ -138,20 +135,30 @@ def normalize_device_kind(kind):
         return "v4"
     if "cpu" in k:
         return "cpu"
-    return DEFAULT_KIND
+    raise ValueError(
+        f"device kind {kind!r} is not in the peaks table "
+        f"({sorted(PEAK_TABLE)}); add its published peaks to "
+        "sparkdl_tpu.observe.perf.PEAK_TABLE")
+
+
+def _kind_of(device):
+    return getattr(device, "device_kind", "") or device.platform
 
 
 def device_kind():
     """The probed device kind of this process's first jax device, or
-    ``None`` when jax was never imported. Same no-import rule as the
-    heartbeat's memory gauges: a telemetry path must never be the
-    thing that initializes a backend."""
-    jax = sys.modules.get("jax")
+    ``None`` when no jax backend is up in this process. Same rule as
+    the heartbeat's memory gauges: a telemetry path must never be the
+    thing that initializes a backend — in a gang worker that breaks
+    the rendezvous, and in a driver on a TPU host it takes the chip
+    from the workers about to be spawned."""
+    from sparkdl_tpu.utils.jax_compat import initialized_jax
+
+    jax = initialized_jax()
     if jax is None:
         return None
     try:
-        d = jax.devices()[0]
-        return getattr(d, "device_kind", "") or d.platform
+        return _kind_of(jax.devices()[0])
     except Exception:
         return None
 
@@ -188,14 +195,19 @@ def peak_interconnect_bytes_per_sec(kind=None):
 
 def hbm_capacity_bytes(kind=None):
     """Per-chip HBM capacity in bytes for ``kind``, or ``None`` when
-    the kind has no chip budget (cpu). ``SPARKDL_TPU_HBM_BYTES``
+    there is no chip budget to hold a program to: the kind has none
+    (cpu), or no kind was given and this process has no device to ask
+    (a driver that never imported jax). ``SPARKDL_TPU_HBM_BYTES``
     overrides any kind — the knob an operator with a nonstandard
     memory config (or a cpu rig that wants the overcommit pass live)
     pins."""
     env = os.environ.get(HBM_BYTES_ENV)
     if env:
         return float(env)
-    return HBM_BYTES[normalize_device_kind(kind or device_kind())]
+    kind = kind or device_kind()
+    if kind is None:
+        return None
+    return HBM_BYTES[normalize_device_kind(kind)]
 
 
 # -- step-time attribution ---------------------------------------------------
@@ -694,8 +706,11 @@ def register_step_cost(name, executable):
         return None
     # Resolve the device kind and peak denominators ONCE — they are
     # process-lifetime constants, and note_step runs on every
-    # executed step of the instrumented hot path.
-    kind = device_kind()
+    # executed step of the instrumented hot path. An executable in
+    # hand means a backend is up, so asking it directly starts none.
+    import jax
+
+    kind = _kind_of(jax.devices()[0])
     entry["device_kind"] = normalize_device_kind(kind)
     entry["peak_flops"] = peak_flops(kind)
     entry["peak_bytes"] = peak_bytes_per_sec(kind)

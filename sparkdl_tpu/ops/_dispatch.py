@@ -5,11 +5,10 @@ import jax.numpy as jnp
 
 
 def use_pallas():
-    """True when the default backend compiles pallas TPU kernels."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    """True when the default backend compiles pallas TPU kernels. A
+    backend that fails to initialize raises here: answering "use XLA"
+    instead would measure the wrong path on a sick TPU host."""
+    return jax.default_backend() == "tpu"
 
 
 def block_for(size, tile=128, floor=8):

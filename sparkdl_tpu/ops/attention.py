@@ -115,8 +115,15 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
     qt, pad = _pad_to(qt, mult, 2)
     if pad and not causal:
         # padded keys must not receive attention weight: causal masking
-        # excludes them (queries come first); for bidirectional
-        # attention fall back to the reference path.
+        # excludes them (queries come first); the bidirectional kernel
+        # has no key mask. Compiled for the TPU that is an error, not a
+        # quiet switch to the reference path; the interpreted kernel
+        # (tests) keeps the reference answer.
+        if not interpret:
+            raise ValueError(
+                f"bidirectional flash attention needs a sequence that "
+                f"is a multiple of its tiles ({bq}, {bk}), got {s}: "
+                "pad it, or call attention_reference")
         return attention_reference(q, k, v, causal=False, scale=scale)
     kt, _ = _pad_to(kt, mult, 2)
     vt, _ = _pad_to(vt, mult, 2)

@@ -119,7 +119,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=128,
     (trailing singleton: see the tiling note in the kernel).
     """
     from jax.experimental import pallas as pl
-    from sparkdl_tpu.utils.jax_compat import tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
     scale = scale or (d ** -0.5)
@@ -147,7 +147,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=128,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=(q_spec, lse_spec) if return_lse else q_spec,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
@@ -262,7 +262,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
     output-gradient rowsum delta = sum(do * o, -1, keepdims=True); lse
     and delta are (B, H, S, 1) per the forward's tiling note."""
     from jax.experimental import pallas as pl
-    from sparkdl_tpu.utils.jax_compat import tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
     scale = scale or (d ** -0.5)
@@ -280,7 +280,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
     vec_full = pl.BlockSpec(
         (1, 1, s, 1), lambda bi, hi, i: (bi, hi, 0, 0)
     )
-    params = tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel"),
     )
 

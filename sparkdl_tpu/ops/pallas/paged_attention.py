@@ -11,12 +11,13 @@ This kernel instead:
 
 - prefetches the block table and per-row lengths as SCALARS
   (``PrefetchScalarGridSpec``) so each grid step's page index is known
-  before the body runs, and the pipeline DMAs exactly ONE (page, D)
-  K/V tile per (row, kv-head, page) program — pages beyond a row's
-  length are masked out, and rows share nothing;
-- keeps the whole GQA query group (``rep`` query heads per kv head) in
-  VMEM against that one tile — no repeated K/V, the MXU sees a
-  (rep, page) × (page, D) pair per step;
+  before the body runs, and the pipeline DMAs exactly ONE whole page
+  (page, Hkv, D) of K and of V per (row, page) program — pages beyond
+  a row's length are masked out, and rows share nothing;
+- unrolls the kv heads of that page in the body and keeps each head's
+  GQA query group (``rep`` query heads per kv head) in VMEM against
+  it — no repeated K/V, the MXU sees a (rep, page) × (page, D) pair
+  per head;
 - accumulates in the numerically-stable flash form (running max +
   rescaled sums) across the sequential page axis in VMEM scratch.
 
@@ -43,7 +44,7 @@ _DEFAULT_PAGES_PER_BLOCK = int(
     os.environ.get("SPARKDL_TPU_PAGED_PAGES_PER_BLOCK", 1))
 
 
-def _kernel(page, rep, scale, n_grid, ppb):
+def _kernel(page, hkv, scale, n_grid, ppb):
     from jax.experimental import pallas as pl
 
     def kernel(tables_ref, lens_ref, q_ref, *refs):
@@ -52,7 +53,7 @@ def _kernel(page, rep, scale, n_grid, ppb):
         o_ref = refs[2 * ppb]
         acc_ref, m_ref, l_ref = refs[2 * ppb + 1:]
         b = pl.program_id(0)
-        j = pl.program_id(2)
+        j = pl.program_id(1)
 
         @pl.when(j == 0)
         def _init():
@@ -72,35 +73,39 @@ def _kernel(page, rep, scale, n_grid, ppb):
 
             @pl.when(jj * page < length)
             def _attend(t=t, jj=jj):
-                q = q_ref[0, 0]                       # (rep, D)
-                k = k_refs[t][0, :, 0, :]             # (page, D)
-                v = v_refs[t][0, :, 0, :]             # (page, D)
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale                             # (rep, page)
                 pos = jj * page + jax.lax.broadcasted_iota(
                     jnp.int32, (1, page), 1)
-                s = jnp.where(pos < length, s, NEG_INF)
-                m_prev = m_ref[...]                   # (rep, 1)
-                l_prev = l_ref[...]
-                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)                # (rep, page)
-                l_ref[...] = (
-                    l_prev * alpha + p.sum(axis=-1, keepdims=True))
-                m_ref[...] = m_new
-                acc_ref[...] = (
-                    acc_ref[...] * alpha
-                    + jax.lax.dot_general(
-                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                # a page tile carries every kv head; heads unroll here
+                # and not over the grid (see kv_spec)
+                for h in range(hkv):
+                    q = q_ref[0, h]                   # (rep, D)
+                    k = k_refs[t][0, :, h, :]         # (page, D)
+                    v = v_refs[t][0, :, h, :]         # (page, D)
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
+                    ) * scale                         # (rep, page)
+                    s = jnp.where(pos < length, s, NEG_INF)
+                    m_prev = m_ref[h]                 # (rep, 1)
+                    l_prev = l_ref[h]
+                    m_new = jnp.maximum(
+                        m_prev, s.max(axis=-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - m_new)            # (rep, page)
+                    l_ref[h] = (
+                        l_prev * alpha + p.sum(axis=-1, keepdims=True))
+                    m_ref[h] = m_new
+                    acc_ref[h] = (
+                        acc_ref[h] * alpha
+                        + jax.lax.dot_general(
+                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
                     )
-                )
 
         @pl.when(j == n_grid - 1)
         def _finalize():
-            o_ref[0, 0] = (
+            o_ref[0] = (
                 acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
             ).astype(o_ref.dtype)
 
@@ -134,8 +139,6 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lens, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from sparkdl_tpu.utils.jax_compat import tpu_compiler_params
-
     b, h, d = q.shape
     n_pages, page, hkv, dk = k_pool.shape
     assert dk == d and h % hkv == 0, (q.shape, k_pool.shape)
@@ -150,26 +153,29 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lens, *,
     lens = lens.astype(jnp.int32)
 
     n_grid = pl.cdiv(max_pages, ppb)
-    grid = (b, hkv, n_grid)
+    grid = (b, n_grid)
     # index maps see (grid..., *scalar_prefetch_refs)
     q_spec = pl.BlockSpec(
-        (1, 1, rep, d), lambda bi, hi, j, tbl, ln: (bi, hi, 0, 0))
+        (1, hkv, rep, d), lambda bi, j, tbl, ln: (bi, 0, 0, 0))
 
     def kv_spec(t):
         # tile t of a grid step covers logical page j*ppb + t; the
         # ragged final step clamps the table column (the duplicate
-        # reads it causes are masked in-kernel by the lens check)
-        def index(bi, hi, j, tbl, ln, t=t):
+        # reads it causes are masked in-kernel by the lens check).
+        # The tile is the WHOLE page, all kv heads: a one-head block
+        # (1, page, 1, D) breaks the TPU's (8, 128) rule on the pool's
+        # minor (Hkv, D) dims, and a page is contiguous in HBM anyway.
+        def index(bi, j, tbl, ln, t=t):
             jj = jnp.minimum(j * ppb + t, max_pages - 1)
-            return (tbl[bi, jj], 0, hi, 0)
+            return (tbl[bi, jj], 0, 0, 0)
 
-        return pl.BlockSpec((1, page, 1, d), index)
+        return pl.BlockSpec((1, page, hkv, d), index)
 
     out_spec = pl.BlockSpec(
-        (1, 1, rep, d), lambda bi, hi, j, tbl, ln: (bi, hi, 0, 0))
+        (1, hkv, rep, d), lambda bi, j, tbl, ln: (bi, 0, 0, 0))
 
     out = pl.pallas_call(
-        _kernel(page, rep, scale, n_grid, ppb),
+        _kernel(page, hkv, scale, n_grid, ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -178,14 +184,14 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lens, *,
                       + [kv_spec(t) for t in range(ppb)]),
             out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((rep, d), jnp.float32),   # acc
-                pltpu.VMEM((rep, 1), jnp.float32),   # running max
-                pltpu.VMEM((rep, 1), jnp.float32),   # running sum
+                pltpu.VMEM((hkv, rep, d), jnp.float32),   # acc
+                pltpu.VMEM((hkv, rep, 1), jnp.float32),   # running max
+                pltpu.VMEM((hkv, rep, 1), jnp.float32),   # running sum
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(tables, lens, qg, *([k_pool] * ppb), *([v_pool] * ppb))
@@ -213,9 +219,7 @@ def paged_attention_decode_sharded(mesh, *, axis_name="model",
             interpret=interpret, pages_per_block=pages_per_block,
         )
 
-    from sparkdl_tpu.utils.jax_compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, axis_name, None),
                   P(None, None, axis_name, None),

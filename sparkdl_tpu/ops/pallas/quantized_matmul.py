@@ -13,9 +13,11 @@ Dispatch is governed by the ``SPARKDL_TPU_KERNEL_QUANT_MATMUL`` knob
 (``auto`` | ``off`` | ``force_interpret``): ``auto`` runs the kernel
 on TPU and the XLA dequant lowering elsewhere, ``off`` pins the XLA
 lowering everywhere, and ``force_interpret`` emulates the kernel on
-any backend (the CPU equivalence oracle). Shapes the kernel cannot
-serve degrade to the XLA lowering loudly (RuntimeWarning) — never to
-a wrong answer.
+any backend (the CPU equivalence oracle). Inputs the kernel cannot
+serve are an error on a TPU — a path that quietly gave way to XLA
+there would be measured under the kernel's name — and elsewhere
+degrade to the XLA lowering loudly (RuntimeWarning), never to a wrong
+answer.
 """
 
 import functools
@@ -60,7 +62,15 @@ def _kernel_plan(mode):
     return use_pallas(), False
 
 
-def _fallback_warn(reason):
+def _unsupported(reason):
+    """The kernel was asked for and cannot serve this input: an error
+    on a TPU, a loud fallback to the XLA lowering elsewhere."""
+    from sparkdl_tpu.ops._dispatch import use_pallas
+
+    if use_pallas():
+        raise ValueError(
+            f"quant-matmul kernel unsupported ({reason}); pass "
+            "mode='off' to ask for the XLA dequant lowering")
     warnings.warn(
         f"quant-matmul kernel unsupported ({reason}); degrading to the "
         "XLA dequant lowering", RuntimeWarning, stacklevel=3)
@@ -103,7 +113,7 @@ def _qmm_kernel(nk, k, bk, x_ref, wq_ref, scale_ref, o_ref, acc_ref):
         # per-column scales factor out of the K-sum, so one multiply at
         # the end is exact — the int8→fp32 dequant itself happens in
         # the inner loop feeding the MXU
-        o_ref[:] = (acc_ref[:] * scale_ref[:][None, :]).astype(o_ref.dtype)
+        o_ref[:] = (acc_ref[:] * scale_ref[:]).astype(o_ref.dtype)
 
 
 def quantized_matmul_pallas(x, w_q, scales, *, block_m=128, block_n=128,
@@ -115,7 +125,6 @@ def quantized_matmul_pallas(x, w_q, scales, *, block_m=128, block_n=128,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from sparkdl_tpu.ops._dispatch import block_for
-    from sparkdl_tpu.utils.jax_compat import tpu_compiler_params
 
     m, k = x.shape
     _, n = w_q.shape
@@ -130,17 +139,19 @@ def quantized_matmul_pallas(x, w_q, scales, *, block_m=128, block_n=128,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, ki: (i, ki)),
             pl.BlockSpec((bk, bn), lambda i, j, ki: (ki, j)),
-            pl.BlockSpec((bn,), lambda i, j, ki: (j,)),
+            # scales ride as a (1, N) row: a 1-D f32 operand gets XLA's
+            # T(1024) layout on TPU, which Mosaic refuses for a 128 block
+            pl.BlockSpec((1, bn), lambda i, j, ki: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, ki: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # K innermost and sequential: the accumulator carries
             # across k steps of one (i, j) tile
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, w_q, scales)
+    )(x, w_q, scales.reshape(1, n))
 
 
 def quantized_matmul(x, w_q, scales, *, interpret=None, mode=""):
@@ -160,7 +171,7 @@ def quantized_matmul(x, w_q, scales, *, interpret=None, mode=""):
     else:
         use_kernel, interp = _kernel_plan(mode)
     if use_kernel and w_q.dtype != jnp.int8:
-        _fallback_warn(f"w_q dtype {w_q.dtype} is not int8")
+        _unsupported(f"w_q dtype {w_q.dtype} is not int8")
         use_kernel = False
     if not use_kernel:
         w = w_q.astype(jnp.float32) * scales[None, :]
@@ -288,8 +299,8 @@ def _dequant_int4(packed, scales, group):
     return w * jnp.repeat(scales, group, axis=0)
 
 
-def _q4mm_kernel(group, nk, k, bk, x_ref, wq_ref, scale_ref, o_ref,
-                 acc_ref):
+def _q4mm_kernel(group, nk, kh, bkh, xlo_ref, xhi_ref, wq_ref, scale_ref,
+                 o_ref, acc_ref):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(2)
@@ -298,22 +309,39 @@ def _q4mm_kernel(group, nk, k, bk, x_ref, wq_ref, scale_ref, o_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:].astype(jnp.float32)
-    # on-the-fly group dequant of this K tile: unpack nibbles, apply
-    # the (bk // group, bn) scale slice row-repeated to (bk, bn)
-    w = _dequant_int4(wq_ref[:], scale_ref[:], group)
-    if k % bk:
+    # Nibbles unpack in 32-bit arithmetic (Mosaic legalises no shift on
+    # 8-bit vectors) and stay in PACKED row order: packed row i holds
+    # weight rows 2i (low) and 2i+1 (high), both of scale group
+    # i // (group // 2), so the even/odd activation columns the wrapper
+    # split off meet them as two dots and no row interleave is needed.
+    p = wq_ref[:].astype(jnp.int32)
+    low = ((p & 0xF) ^ 8) - 8                         # sign-extend
+    high = p >> 4                                     # arithmetic
+    s = scale_ref[:]
+    reps = group // 2
+    s = jnp.broadcast_to(
+        s[:, None, :], (s.shape[0], reps, s.shape[1])
+    ).reshape(s.shape[0] * reps, s.shape[1])          # (bk // 2, bn)
+    w_lo = low.astype(jnp.float32) * s
+    w_hi = high.astype(jnp.float32) * s
+    x_lo = xlo_ref[:].astype(jnp.float32)
+    x_hi = xhi_ref[:].astype(jnp.float32)
+    if kh % bkh:
         # ragged final K tile: block padding past K may hold anything
         # (the padded fp32 scale rows in particular) — zero BOTH
         # operands so no garbage (or NaN) reaches the accumulator
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-        x = jnp.where(kpos < k, x, 0.0)
-        wpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
-        w = jnp.where(wpos < k, w, 0.0)
-    acc_ref[:] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        kpos = ki * bkh + jax.lax.broadcasted_iota(jnp.int32, x_lo.shape, 1)
+        x_lo = jnp.where(kpos < kh, x_lo, 0.0)
+        x_hi = jnp.where(kpos < kh, x_hi, 0.0)
+        wpos = ki * bkh + jax.lax.broadcasted_iota(jnp.int32, w_lo.shape, 0)
+        w_lo = jnp.where(wpos < kh, w_lo, 0.0)
+        w_hi = jnp.where(wpos < kh, w_hi, 0.0)
+    dims = (((1,), (0,)), ((), ()))
+    acc_ref[:] += (
+        jax.lax.dot_general(x_lo, w_lo, dims,
+                            preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(x_hi, w_hi, dims,
+                              preferred_element_type=jnp.float32))
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -321,53 +349,56 @@ def _q4mm_kernel(group, nk, k, bk, x_ref, wq_ref, scale_ref, o_ref,
 
 
 def quantized_matmul_int4_pallas(x, packed, scales, *, group=INT4_GROUP,
-                                 block_m=128, block_n=128, block_k=512,
+                                 block_m=128, block_n=128, block_k=None,
                                  interpret=False):
     """x (M, K) @ dequant(packed (K//2, N)) with (K//group, N) scales.
 
-    K-blocked like the int8 kernel; the K tile is rounded to a multiple
-    of the scale group (and of 2 for the nibble packing) so each grid
-    step sees whole groups."""
+    K-blocked like the int8 kernel; the K tile is a whole number of
+    scale groups, and by default eight of them at least, so that the
+    scale block meets the (8, 128) tiling of the TPU. ``group`` must be
+    even: a packed row may not straddle two scale groups."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from sparkdl_tpu.ops._dispatch import block_for
-    from sparkdl_tpu.utils.jax_compat import tpu_compiler_params
 
     m, k = x.shape
     kh, n = packed.shape
+    if group % 2:
+        raise ValueError(
+            f"the int4 kernel needs an even scale group, got {group}")
     assert k == 2 * kh, (x.shape, packed.shape)
     assert k == group * scales.shape[0], (k, group, scales.shape)
     bm = block_for(m, tile=block_m)
     bn = block_for(n, tile=block_n, floor=128)
-    # whole groups per K tile: lcm(group, 2) ≤ bk ≤ k, group-aligned
-    unit = group if group % 2 == 0 else 2 * group
-    bk = max(unit, min(block_k, k) // unit * unit)
+    block_k = block_k or max(512, 8 * group)
+    bk = max(group, min(block_k, k) // group * group)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
     return pl.pallas_call(
-        functools.partial(_q4mm_kernel, group, grid[2], k, bk),
+        functools.partial(_q4mm_kernel, group, grid[2], kh, bk // 2),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, ki: (i, ki)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, ki: (i, ki)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, ki: (i, ki)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, ki: (ki, j)),
             pl.BlockSpec((bk // group, bn), lambda i, j, ki: (ki, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, ki: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, packed, scales)
+    )(x[:, 0::2], x[:, 1::2], packed, scales)
 
 
 def quantized_matmul_int4(x, packed, scales, *, group=INT4_GROUP,
                           interpret=None, mode=""):
     """Dispatch like :func:`quantized_matmul`, plus int4-specific
     support checks: a ``group`` that does not cover K with the given
-    scale rows degrades loudly to the XLA lowering under the group the
-    shapes imply (never a wrong answer), and raises when no consistent
-    group exists."""
+    scale rows, or an odd one, degrades loudly to the XLA lowering
+    under the group the shapes imply (never a wrong answer; an error
+    on a TPU), and raises when no consistent group exists."""
     k = x.shape[1]
     s_rows = scales.shape[0]
     if k != 2 * packed.shape[0]:
@@ -384,12 +415,15 @@ def quantized_matmul_int4(x, packed, scales, *, group=INT4_GROUP,
                 f"int4 scales with {s_rows} rows cannot cover K={k} "
                 f"under any group (requested group={group})")
         inferred = k // s_rows
-        _fallback_warn(
+        _unsupported(
             f"group={group} does not cover K={k} with {s_rows} scale "
             f"rows; using inferred group={inferred}")
         group, use_kernel = inferred, False
     if use_kernel and packed.dtype != jnp.int8:
-        _fallback_warn(f"packed dtype {packed.dtype} is not int8")
+        _unsupported(f"packed dtype {packed.dtype} is not int8")
+        use_kernel = False
+    if use_kernel and group % 2:
+        _unsupported(f"odd scale group {group}")
         use_kernel = False
     if not use_kernel:
         w = _dequant_int4(packed, scales, group)

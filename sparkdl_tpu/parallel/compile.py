@@ -10,8 +10,7 @@ persistent compilation cache; this module is that story for
 HorovodRunner gangs, in two layers:
 
 1. :func:`enable_persistent_cache` — turn on JAX's *persistent
-   compilation cache* (``jax_compilation_cache_dir``), version-shimmed
-   via :mod:`sparkdl_tpu.utils.jax_compat`, with sane
+   compilation cache* (``jax_compilation_cache_dir``) with sane
    min-compile-time/min-entry-size knobs. Every ``jit`` in the process
    then reuses on-disk XLA artifacts across process restarts — no code
    changes in user mains.
@@ -30,11 +29,22 @@ HorovodRunner gangs, in two layers:
        analysis.register_preflight(lowered)        # graph lint
        compiled = CompiledStepCache().load_or_compile(lowered)
 
-Gang wiring: set ``SPARKDL_TPU_COMPILE_CACHE_DIR`` on the driver; the
-launcher ships it to every worker (local, remote and supervised
-relaunches alike) and ``_worker.py`` calls
-:func:`enable_persistent_cache` *before* backend init, so a preempted
-rank's replacement warm-starts from its predecessor's cache entries.
+Where the cache lives: ``JAX_COMPILATION_CACHE_DIR``, JAX's own
+variable, decides. When it is set JAX itself reads it, every process
+that inherits the environment uses that directory, and nothing here
+points the cache anywhere else. ``SPARKDL_TPU_COMPILE_CACHE_DIR`` ranks
+below it: it is consulted only when the JAX variable is unset. A
+program that wants a cache with neither set (``chip_smoke.py``,
+``bench.py``) calls :func:`export_cache_dir`, which exports the fixed
+in-checkout :func:`default_cache_dir` under the JAX variable's name —
+the path is part of JAX's cache key, so it is never built from a temp
+name, a uid, a pid or a time.
+
+Gang wiring: the launcher passes the driver's environment to every
+worker (local, remote and supervised relaunches alike) and
+``_worker.py`` calls :func:`enable_persistent_cache` *before* backend
+init, so a preempted rank's replacement warm-starts from its
+predecessor's cache entries.
 
 Degradation contract: a corrupt, truncated, or fingerprint-mismatched
 AOT entry falls back to a cold ``lowered.compile()`` with a WARNING —
@@ -54,11 +64,13 @@ import hashlib
 import logging
 import os
 import pickle
+import sys
 import tempfile
 import time
 
 logger = logging.getLogger("HorovodRunner")
 
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 COMPILE_CACHE_DIR_ENV = "SPARKDL_TPU_COMPILE_CACHE_DIR"
 MIN_COMPILE_S_ENV = "SPARKDL_TPU_COMPILE_CACHE_MIN_COMPILE_S"
 MIN_ENTRY_BYTES_ENV = "SPARKDL_TPU_COMPILE_CACHE_MIN_BYTES"
@@ -82,22 +94,51 @@ DEFAULT_MIN_ENTRY_BYTES = 0
 # Format 2 added `memory_stats` to the entry (recorded at write time —
 # a deserialized executable's memory_analysis drops alias accounting,
 # and the bench's step_peak_bytes contract needs the real figures on
-# warm starts too). Format-1 entries simply cold-recompile once.
-_AOT_FORMAT = 2
+# warm starts too); format 3 `device_ids`, the devices the executable
+# loads onto. Older entries simply cold-recompile once.
+_AOT_FORMAT = 3
 
 _persistent_cache_dir = None  # latched by enable_persistent_cache
 
 
 def persistent_cache_dir(environ=None):
-    """The configured cache root (env), or None when warm-start
+    """The configured cache root — ``JAX_COMPILATION_CACHE_DIR``, else
+    ``SPARKDL_TPU_COMPILE_CACHE_DIR`` — or None when warm-start
     compilation is not opted in."""
     env = os.environ if environ is None else environ
-    return env.get(COMPILE_CACHE_DIR_ENV) or None
+    return env.get(JAX_CACHE_DIR_ENV) or env.get(COMPILE_CACHE_DIR_ENV) \
+        or None
+
+
+def default_cache_dir():
+    """The fixed in-checkout cache directory (git-ignored) of programs
+    that cache without being told where."""
+    import sparkdl_tpu
+
+    return os.path.join(
+        os.path.dirname(os.path.dirname(
+            os.path.abspath(sparkdl_tpu.__file__))),
+        ".jax_cache")
+
+
+def export_cache_dir(environ=None):
+    """Make sure ``JAX_COMPILATION_CACHE_DIR`` is set for this process
+    and the children that inherit ``environ``: left alone when set,
+    else :func:`persistent_cache_dir`'s answer or the fixed
+    :func:`default_cache_dir`. Call before ``jax`` is imported — JAX
+    reads the variable once, at import. Returns the directory."""
+    env = os.environ if environ is None else environ
+    if not env.get(JAX_CACHE_DIR_ENV):
+        env[JAX_CACHE_DIR_ENV] = os.path.abspath(
+            persistent_cache_dir(env) or default_cache_dir())
+    return env[JAX_CACHE_DIR_ENV]
 
 
 def enable_persistent_cache(cache_dir=None):
-    """Turn on JAX's persistent compilation cache under ``cache_dir``
-    (default: ``SPARKDL_TPU_COMPILE_CACHE_DIR``). Returns the resolved
+    """Turn on JAX's persistent compilation cache. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set that directory is the cache
+    and no other is set here; else ``cache_dir`` (default:
+    ``SPARKDL_TPU_COMPILE_CACHE_DIR``). Returns the resolved
     directory, or None when no directory is configured (no-op — the
     opt-out path costs one env read).
 
@@ -106,34 +147,46 @@ def enable_persistent_cache(cache_dir=None):
     again with the same dir is free, with a different dir re-points
     the cache (jax re-reads the config at the next compile).
     """
-    cache_dir = cache_dir or persistent_cache_dir()
+    from_jax_env = os.environ.get(JAX_CACHE_DIR_ENV)
+    cache_dir = from_jax_env or cache_dir or persistent_cache_dir()
     if not cache_dir:
         return None
+    if not from_jax_env:
+        cache_dir = os.path.abspath(cache_dir)
     global _persistent_cache_dir
     # The whole degrade contract applies HERE too: this runs at worker
     # bootstrap before the control plane exists, so an unwritable dir
-    # (a mount one host lacks) or a malformed threshold env must WARN
-    # and continue cold — raising would kill every rank of every
+    # (a mount one host lacks) or a malformed threshold env must say
+    # so and continue cold — raising would kill every rank of every
     # supervised attempt with a boot death the driver can't explain.
+    # A cold compile hides no device, but it must not be silent: the
+    # line also goes to stderr, which a worker's boot log keeps even
+    # before its log tee exists.
     try:
-        cache_dir = os.path.abspath(cache_dir)
-        os.makedirs(cache_dir, exist_ok=True)
-        from sparkdl_tpu.utils import jax_compat
+        import jax
 
-        jax_compat.enable_compilation_cache(
-            cache_dir,
-            min_compile_time_secs=float(
-                os.environ.get(MIN_COMPILE_S_ENV, DEFAULT_MIN_COMPILE_S)),
-            min_entry_size_bytes=int(
-                os.environ.get(MIN_ENTRY_BYTES_ENV,
-                               DEFAULT_MIN_ENTRY_BYTES)),
-        )
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_enable_compilation_cache", True)
+        if not from_jax_env:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs",
+            float(os.environ.get(MIN_COMPILE_S_ENV,
+                                 DEFAULT_MIN_COMPILE_S)))
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes",
+            int(os.environ.get(MIN_ENTRY_BYTES_ENV,
+                               DEFAULT_MIN_ENTRY_BYTES)))
+        # Cache problems (corrupt entry) degrade to a cold compile
+        # with JAX's own warning, never crash the step; pin the
+        # default in case a site config flipped it.
+        jax.config.update("jax_raise_persistent_cache_errors", False)
     except Exception as e:
-        logger.warning(
-            "persistent compile cache unavailable under %s (%s: %s); "
-            "continuing with cold compiles",
-            cache_dir, type(e).__name__, e,
-        )
+        msg = (f"persistent compile cache unavailable under {cache_dir} "
+               f"({type(e).__name__}: {e}); continuing with cold "
+               "compiles")
+        logger.warning(msg)
+        print(f"sparkdl_tpu: {msg}", file=sys.stderr, flush=True)
         return None
     if _persistent_cache_dir != cache_dir:
         _persistent_cache_dir = cache_dir
@@ -172,13 +225,13 @@ def step_fingerprint(stablehlo_text, *, topology=None,
     options, StableHLO module text). The StableHLO hash — not the
     Python function — is the identity, so an edited-but-equivalent
     main still hits and any real graph change misses."""
-    from sparkdl_tpu.utils import jax_compat
+    import jax
 
     if topology is None:
         topology = topology_descriptor()
     h = hashlib.sha256()
     h.update(f"aot{_AOT_FORMAT}".encode())
-    h.update(("." .join(map(str, jax_compat.jax_version()))).encode())
+    h.update(jax.__version__.encode())
     h.update(b"\0" + topology.encode())
     opts = sorted((compiler_options or {}).items())
     h.update(b"\0" + repr(opts).encode())
@@ -240,7 +293,10 @@ class CompiledStepCache:
         missing file, truncated pickle, foreign format, fingerprint
         drift, a deserialize the runtime rejects — degrades to a cold
         compile. Returns a Compiled or None."""
-        from sparkdl_tpu.utils import jax_compat
+        import jax
+        from jax.experimental.serialize_executable import (
+            deserialize_and_load,
+        )
 
         try:
             with open(path, "rb") as f:
@@ -251,8 +307,15 @@ class CompiledStepCache:
                     f"entry format/fingerprint mismatch "
                     f"(format={entry.get('format')!r})"
                 )
-            compiled = jax_compat.deserialize_compiled(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+            # Load onto the devices the program was compiled for: the
+            # loader's default is every device of the backend, and an
+            # executable built for one device of eight then expects
+            # eight shards of every argument.
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[
+                    by_id[i] for i in entry["device_ids"]],
             )
             # Stats recorded at write time (guaranteed present since
             # format 2): the deserialized runtime's own
@@ -270,17 +333,19 @@ class CompiledStepCache:
             return None
 
     def _write(self, path, fingerprint, compiled):
-        from sparkdl_tpu.utils import jax_compat
+        from jax.experimental.serialize_executable import serialize
 
         try:
-            payload, in_tree, out_tree = jax_compat.serialize_compiled(
-                compiled)
+            payload, in_tree, out_tree = serialize(compiled)
             blob = pickle.dumps({
                 "format": _AOT_FORMAT,
                 "fingerprint": fingerprint,
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
+                "device_ids": [
+                    d.id for d in
+                    compiled.runtime_executable().local_devices()],
                 # Kept alongside the executable: deserialization loses
                 # the alias accounting, so a warm start reads the peak
                 # from here instead of a zeroed memory_analysis().

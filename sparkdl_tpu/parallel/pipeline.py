@@ -36,7 +36,6 @@ no pipeline parallelism at all, SURVEY.md §2.3).
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.utils.jax_compat import axis_size
 from jax.sharding import PartitionSpec as P
 
 
@@ -59,7 +58,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, *,
     from sparkdl_tpu.parallel.ring_attention import resolve_overlap
 
     overlap = resolve_overlap(overlap)
-    n_stages = axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     m = microbatches.shape[0]
     params_local = jax.tree.map(lambda x: x[0], stacked_params)
@@ -146,9 +145,8 @@ def make_pipeline(mesh, stage_fn, *, axis_name="stage", overlap=True):
             jax.tree.map(spec_for, stacked_params),
             P(),
         )
-        from sparkdl_tpu.utils.jax_compat import shard_map
 
-        fn = shard_map(
+        fn = jax.shard_map(
             run, mesh=mesh, in_specs=in_specs, out_specs=P(),
             check_vma=False,
         )

@@ -30,7 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.utils.jax_compat import axis_size
 
 NEG_INF = -1e30
 
@@ -102,7 +101,7 @@ def ring_self_attention(q, k, v, *, axis_name, causal=True, scale=None,
     equivalence oracle and the analysis bad-corpus generator.
     """
     overlap = resolve_overlap(overlap)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = scale or (d ** -0.5)
@@ -245,7 +244,7 @@ def _ring_flash_fwd_pass(qt, k0, v0, axis_name, causal, scale, bq, bk,
         flash_attention_bhsd,
     )
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     b, h, s, d = qt.shape
@@ -349,7 +348,7 @@ def _ring_flash_bwd(axis_name, causal, scale, bq, bk, interpret,
     )
 
     q, k, v, out, lse = res
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     qt = q.transpose(0, 2, 1, 3)
@@ -510,9 +509,8 @@ def make_ring_attention(mesh, *, causal=True, impl=None,
         )
     else:
         raise ValueError(f"impl must be 'dense' or 'flash', got {impl!r}")
-    from sparkdl_tpu.utils.jax_compat import shard_map
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     ))

@@ -72,12 +72,11 @@ def default_profile_dir():
     return os.path.join(root, "benchmarks", "profiles")
 
 
-# Raw device-kind tokens we can honestly key a profile by. Deliberately
-# NOT observe.perf.normalize_device_kind: that helper falls back to
-# DEFAULT_KIND ("v5e") for anything unknown — correct for MFU
-# denominators, catastrophic for profiles (a WORKER_PLATFORM=tpu pin
-# on a v4 pod must not load v5e-measured knobs). Unknown = None =
-# no profile.
+# Raw device-kind tokens we can honestly key a profile by. Not
+# observe.perf.normalize_device_kind: that helper raises on an unknown
+# kind, and here unknown is an answer — None, no profile (a
+# WORKER_PLATFORM=tpu pin on a v4 pod must not load v5e-measured
+# knobs).
 _KIND_TOKENS = (("v5p", "v5p"), ("v5e", "v5e"), ("v5 lite", "v5e"),
                 ("v5lite", "v5e"), ("v4", "v4"), ("cpu", "cpu"))
 
@@ -173,22 +172,10 @@ def load_profile(path):
 
 def _initialized_backend_kind():
     """The probed device kind, but ONLY when this process's jax
-    backend is already live. ``operf.device_kind()`` guards against
-    jax never being *imported*, yet ``jax.devices()`` on an imported-
-    but-uninitialized jax would initialize the backend right here —
-    and the launcher pre-flight runs in the DRIVER, where a first-
-    touch TPU init would grab the chip lease out from under the
-    workers it is about to spawn. No live backend = None, never an
-    init."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None
-    try:
-        backends = getattr(jax.lib.xla_bridge, "_backends", None)
-        if not backends:
-            return None
-    except Exception:
-        return None
+    backend is already live (``operf.device_kind()`` never initializes
+    one): the launcher pre-flight runs in the DRIVER, where a
+    first-touch TPU init would take the chip out from under the
+    workers it is about to spawn."""
     from sparkdl_tpu.observe import perf as operf
 
     return strict_device_kind(operf.device_kind())

@@ -1,91 +1,40 @@
-"""Version-portable wrappers for jax APIs that moved between majors.
+"""Accessors over JAX objects that the analysis, observe and compile
+layers share. The package targets the one JAX the container installs
+(``setup.py`` states the floor), so nothing here branches on a version:
+what JAX spells in one line (``jax.shard_map``, ``jax.lax.axis_size``,
+``pltpu.CompilerParams``, ``jax.make_jaxpr``) callers take from JAX.
 
-The package targets current jax (``jax.shard_map``, ``check_vma``,
-``jax.lax.axis_size``), but deployment rigs pin older runtimes — and a
-framework whose collectives, MoE layers, and kernels all die with
-``AttributeError`` on jax 0.4.x has no fault-tolerance story at all.
-Every wrapper prefers the stable modern API and falls back to the
-0.4.x spelling:
-
-- ``shard_map``: ``jax.shard_map`` → ``jax.experimental.shard_map``
-  (where the replication checker kwarg was ``check_rep``, renamed
-  ``check_vma`` at promotion).
-- ``axis_size``: ``jax.lax.axis_size`` → the classic
-  ``psum(1, axis)``, a compile-time constant inside traced code
-  either way.
-
-Also home to the version-stable lowering/jaxpr accessors the static
-analysis subsystem builds on (``lower``, ``lowered_stablehlo``,
-``compiled_hlo``, ``closed_jaxpr``, ``x64_enabled``), the
-warm-start-compilation shims (``enable_compilation_cache``,
-``serialize_compiled``/``deserialize_compiled`` — see
-:mod:`sparkdl_tpu.parallel.compile`), the normalized cost-model
-accessors ``cost_analysis``/``memory_analysis`` (None-never-raise —
-:mod:`sparkdl_tpu.observe.perf` turns them into MFU/roofline gauges),
-and the runtime feature probe
-``old_xla_spmd_partitioner()`` that tier-1 tests gate on instead of
-failing against the jax-0.4.x XLA.
+- lowering accessors the static analysis builds on (``lower``,
+  ``lowered_stablehlo``, ``compiled_hlo``);
+- the normalized cost-model accessors ``cost_analysis`` /
+  ``memory_analysis`` (None-never-raise — backends differ in what they
+  report; :mod:`sparkdl_tpu.observe.perf` turns them into MFU/roofline
+  gauges);
+- telemetry readers that must never be the thing that initializes a
+  backend (``device_memory_stats``, ``live_buffer_bytes``,
+  ``profiler_trace``): a gang worker's samplers start before
+  ``hvd.init()``, and a device touched ahead of
+  ``jax.distributed.initialize()`` breaks the rendezvous — and, on a
+  TPU host, takes the chip.
 """
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    import jax
+def initialized_jax():
+    """The imported ``jax`` module once a backend is up, else None."""
+    import sys
 
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
 
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kwargs)
-
-
-def axis_size(name):
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def jax_version():
-    """(major, minor, patch) of the running jax."""
-    import jax
-
-    parts = []
-    for tok in jax.__version__.split(".")[:3]:
-        digits = "".join(ch for ch in tok if ch.isdigit())
-        parts.append(int(digits or 0))
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
-
-
-def old_xla_spmd_partitioner():
-    """True when the bundled XLA predates the modern SPMD partitioner
-    (jax < 0.5): it rejects ``PartitionId`` inside SPMD programs
-    ("PartitionId instruction is not supported for SPMD partitioning")
-    and keeps boundary-sized activations gathered where the modern
-    partitioner leaves them sharded. Tier-1 tests that exercise either
-    behavior gate on this instead of failing."""
-    return jax_version() < (0, 5, 0)
-
-
-def x64_enabled():
-    """Whether jax_enable_x64 is on (same spelling both lines)."""
-    import jax
-
-    return bool(jax.config.jax_enable_x64)
+    return jax if xla_bridge.backends_are_initialized() else None
 
 
 def lower(fn, *args, **kwargs):
     """``jax.stages.Lowered`` for ``fn(*args, **kwargs)``: uses the
     function's own ``.lower`` when it is already jitted, else wraps it
-    in ``jax.jit`` first (stable across both jax lines)."""
+    in ``jax.jit`` first."""
     import jax
 
     if hasattr(fn, "lower"):
@@ -95,10 +44,7 @@ def lower(fn, *args, **kwargs):
 
 def lowered_stablehlo(lowered):
     """Pre-partitioning StableHLO text of a ``Lowered``."""
-    try:
-        return lowered.as_text(dialect="stablehlo")
-    except TypeError:
-        return lowered.as_text()
+    return lowered.as_text(dialect="stablehlo")
 
 
 def compiled_hlo(lowered_or_compiled):
@@ -111,87 +57,15 @@ def compiled_hlo(lowered_or_compiled):
     return obj.as_text()
 
 
-def closed_jaxpr(fn, *args, **kwargs):
-    """ClosedJaxpr of ``fn(*args, **kwargs)``. A jitted callable
-    yields one pjit eqn wrapping the body — the analysis walker
-    recurses through it, so no unwrapping (unwrapping a shard_map'd
-    fn would trace its body outside the mesh and die on unbound axis
-    names)."""
-    import jax
-
-    return jax.make_jaxpr(fn)(*args, **kwargs)
-
-
-def enable_compilation_cache(path, *, min_compile_time_secs=None,
-                             min_entry_size_bytes=None):
-    """Point JAX's persistent compilation cache at ``path``.
-
-    Modern jax spells every knob as a config option
-    (``jax_compilation_cache_dir`` et al.); older lines predating some
-    of the threshold knobs get the directory via
-    ``jax.experimental.compilation_cache.set_cache_dir`` and whatever
-    threshold options exist. Unknown knobs are skipped per-name, never
-    fatal — a missing tuning option must not disable the cache."""
-    import jax
-
-    def _set(option, value):
-        try:
-            jax.config.update(option, value)
-            return True
-        except (AttributeError, ValueError, KeyError):
-            return False
-
-    _set("jax_enable_compilation_cache", True)
-    if not _set("jax_compilation_cache_dir", path):
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc,
-        )
-
-        cc.set_cache_dir(path)
-    if min_compile_time_secs is not None:
-        _set("jax_persistent_cache_min_compile_time_secs",
-             min_compile_time_secs)
-    if min_entry_size_bytes is not None:
-        _set("jax_persistent_cache_min_entry_size_bytes",
-             min_entry_size_bytes)
-    # Cache problems (corrupt entry, unwritable dir) must degrade to a
-    # cold compile with a warning, never crash the step. This is the
-    # default on both lines; pin it in case a site config flipped it.
-    _set("jax_raise_persistent_cache_errors", False)
-
-
-def serialize_compiled(compiled):
-    """``(payload_bytes, in_tree, out_tree)`` for a
-    ``jax.stages.Compiled``: prefers the object's own ``serialize``
-    (newer jax), else ``jax.experimental.serialize_executable`` (both
-    return the same triple)."""
-    if hasattr(compiled, "serialize"):
-        return compiled.serialize()
-    from jax.experimental.serialize_executable import serialize
-
-    return serialize(compiled)
-
-
-def deserialize_compiled(payload, in_tree, out_tree):
-    """Rebuild a ready-to-call ``Compiled`` from
-    :func:`serialize_compiled` output (stable spelling on both
-    lines)."""
-    from jax.experimental.serialize_executable import (
-        deserialize_and_load,
-    )
-
-    return deserialize_and_load(payload, in_tree, out_tree)
-
-
 def cost_analysis(executable):
     """Normalized XLA cost model for a ``Lowered`` or ``Compiled``
     (or anything duck-typed with a ``cost_analysis()``): a plain dict
     with whichever of ``flops`` / ``bytes_accessed`` /
     ``transcendentals`` the runtime reports, or **None** — never an
-    exception. Jax lines disagree on the return shape (0.4.x
-    ``Compiled`` returns a one-element list of dicts, ``Lowered`` and
-    newer lines a dict; some backends raise ``NotImplementedError``),
-    so every consumer goes through this normalization. The observe
+    exception. Executables disagree on the return shape (a dict, or a
+    one-element list of dicts; some backends raise
+    ``NotImplementedError``), so every consumer goes through this
+    normalization. The observe
     layer divides these by step wall time into achieved-FLOPs/s and
     MFU gauges (:mod:`sparkdl_tpu.observe.perf`)."""
     try:
@@ -214,7 +88,7 @@ def cost_analysis(executable):
 
 def memory_analysis(executable):
     """Normalized compiled-memory stats (``Compiled.memory_analysis``,
-    a ``CompiledMemoryStats`` on both jax lines): plain dict of the
+    a ``CompiledMemoryStats``): plain dict of the
     ``*_size_in_bytes`` fields, or **None** — never an exception
     (``Lowered`` has no memory analysis; neither do deserialized
     executables on some runtimes)."""
@@ -241,15 +115,13 @@ def device_memory_stats(device=None):
 
     Returns a plain dict with whichever of ``bytes_in_use`` /
     ``peak_bytes_in_use`` / ``bytes_limit`` the PJRT client reports
-    (TPU and GPU clients do; CPU returns None/raises on both jax
-    lines). Deliberately refuses to IMPORT jax: this is called from
-    the heartbeat thread of instrumented workers, and a telemetry
-    beat must never be the thing that initializes a backend — if the
-    process hasn't touched jax yet, there is no device memory to
+    (TPU and GPU clients do; CPU returns None/raises). Reads nothing
+    until a backend is up: this is called from the heartbeat thread of
+    instrumented workers, which starts ahead of ``hvd.init()``, and a
+    telemetry beat must never be the thing that initializes a backend
+    — a process that has no backend yet has no device memory to
     report."""
-    import sys
-
-    jax = sys.modules.get("jax")
+    jax = initialized_jax()
     if jax is None:
         return None
     try:
@@ -271,14 +143,13 @@ class profiler_trace:
     region into ``log_dir`` — or doing nothing at all, never raising.
 
     ``__enter__`` returns the log dir when a trace actually started
-    and **None** otherwise (jax not yet imported in this process, a
-    jax line without ``jax.profiler``, another trace already active,
-    an unwritable dir). Same no-import rule as
-    :func:`device_memory_stats`: this runs inside the worker-side
-    forensic capture service (:mod:`sparkdl_tpu.observe.capture`), and
-    an evidence capture must never be the thing that initializes a
-    backend — a process that hasn't touched jax has nothing worth
-    profiling."""
+    and **None** otherwise (no backend up yet in this process,
+    another trace already active, an unwritable dir). Same
+    no-backend-init rule as :func:`device_memory_stats`: this runs
+    inside the worker-side forensic capture service
+    (:mod:`sparkdl_tpu.observe.capture`), and an evidence capture must
+    never be the thing that initializes a backend — a process that
+    hasn't touched a device has nothing worth profiling."""
 
     def __init__(self, log_dir):
         self._log_dir = log_dir
@@ -287,9 +158,8 @@ class profiler_trace:
 
     def __enter__(self):
         import os
-        import sys
 
-        jax = sys.modules.get("jax")
+        jax = initialized_jax()
         if jax is None:
             return None
         try:
@@ -313,10 +183,8 @@ class profiler_trace:
 def live_buffer_bytes():
     """Sum of live jax array bytes in this process — the fallback
     memory gauge where ``memory_stats`` is unimplemented (CPU rigs).
-    Same no-import rule as :func:`device_memory_stats`."""
-    import sys
-
-    jax = sys.modules.get("jax")
+    Same no-backend-init rule as :func:`device_memory_stats`."""
+    jax = initialized_jax()
     if jax is None:
         return None
     try:
@@ -325,15 +193,3 @@ def live_buffer_bytes():
         )
     except Exception:
         return None
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` → pre-rename ``TPUCompilerParams``
-    (same constructor kwargs; ``dimension_semantics`` et al. carried
-    over unchanged at the rename)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -165,18 +165,10 @@ def _build():
           "measure the fixed-shape deviceless CPU-proxy headline"),
         k("SPARKDL_TPU_BENCH_PROBE_TIMEOUT", "int", "150", "bench",
           "per-probe timeout (s)"),
-        k("SPARKDL_TPU_BENCH_PROBE_PAUSE", "str", None, "bench",
-          "single-pause compat spelling of the probe retry schedule"),
-        k("SPARKDL_TPU_BENCH_PROBE_PAUSES", "list", "30,60,120,180",
-          "bench", "escalating probe retry pauses (s)"),
         k("SPARKDL_TPU_BENCH_RUN_TIMEOUT", "int", "1500", "bench",
           "measured-run timeout (s)"),
         k("SPARKDL_TPU_BENCH_CACHE_MAX_AGE", "int", "604800", "bench",
           "stale-fallback headline cache hard cap (s)"),
-        k("SPARKDL_TPU_BENCH_STALE_AGE", "int", "3600", "bench",
-          "age before a repo-owned bench holder is reaped"),
-        k("SPARKDL_TPU_BENCH_PYTEST_STALE_AGE", "int", "1800", "bench",
-          "age before a repo-owned pytest plugin-holder is reaped"),
         k("SPARKDL_TPU_BENCH_PROMOTED", "path", None, "bench",
           "promoted.json override for the headline config"),
         k("SPARKDL_TPU_VARIANTS_FULL", "bool", "0", "bench",
@@ -443,7 +435,8 @@ def _build():
 
         # -- compile cache ------------------------------------------
         k("SPARKDL_TPU_COMPILE_CACHE_DIR", "path", None, "compile",
-          "persistent XLA + AOT step cache root (warm starts)"),
+          "persistent XLA + AOT step cache root (warm starts); ranks "
+          "below JAX_COMPILATION_CACHE_DIR"),
         k("SPARKDL_TPU_COMPILE_CACHE_MAX_AOT", "int", None, "compile",
           "AOT entry count cap"),
         k("SPARKDL_TPU_COMPILE_CACHE_MIN_COMPILE_S", "float", None,

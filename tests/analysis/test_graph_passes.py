@@ -19,7 +19,7 @@ from sparkdl_tpu.analysis import (
 )
 from sparkdl_tpu.analysis.core import GraphContext
 from sparkdl_tpu.parallel.mesh import MeshSpec, make_mesh
-from sparkdl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def by_rule(findings, rule_id):
@@ -55,7 +55,7 @@ class TestCollectiveConsistency:
                 x,
             )
 
-        sm = shard_map(inner, mesh_8, in_specs=P("data"),
+        sm = shard_map(inner, mesh=mesh_8, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
         findings = by_rule(
             lint_fn(sm, jnp.ones((8, 4)), compile=False, mesh=mesh_8),
@@ -78,7 +78,7 @@ class TestCollectiveConsistency:
                 x,
             )
 
-        sm = shard_map(inner, mesh_8, in_specs=P("data"),
+        sm = shard_map(inner, mesh=mesh_8, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
         assert not by_rule(
             lint_fn(sm, jnp.ones((8, 4)), compile=False, mesh=mesh_8),
@@ -98,7 +98,7 @@ class TestCollectiveConsistency:
             return jax.lax.while_loop(
                 lambda c: c[0] < 3, body, (0, x))[1]
 
-        sm = shard_map(inner, mesh_8, in_specs=P("data"),
+        sm = shard_map(inner, mesh=mesh_8, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
         findings = by_rule(
             lint_fn(sm, jnp.ones((8, 4)), compile=False, mesh=mesh_8),
@@ -121,7 +121,7 @@ class TestCollectiveConsistency:
             out, _ = jax.lax.scan(body, x, None, length=4)
             return out
 
-        sm = shard_map(inner, mesh_8, in_specs=P("data"),
+        sm = shard_map(inner, mesh=mesh_8, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
         assert not by_rule(
             lint_fn(sm, jnp.ones((8, 4)), compile=False, mesh=mesh_8),
@@ -141,9 +141,9 @@ class TestCollectiveConsistency:
             y = jax.lax.all_gather(x, "data")
             return jax.lax.psum(y, "data")
 
-        sm_a = shard_map(rank_a, mesh_8, in_specs=P("data"),
+        sm_a = shard_map(rank_a, mesh=mesh_8, in_specs=P("data"),
                          out_specs=P(None, "data"), check_vma=False)
-        sm_b = shard_map(rank_b, mesh_8, in_specs=P("data"),
+        sm_b = shard_map(rank_b, mesh=mesh_8, in_specs=P("data"),
                          out_specs=P(None, "data"), check_vma=False)
         x = jnp.ones((8, 4))
         with mesh_8:
@@ -158,7 +158,7 @@ class TestCollectiveConsistency:
         def rank(x):
             return jax.lax.psum(x, "data")
 
-        sm = shard_map(rank, mesh_8, in_specs=P("data"),
+        sm = shard_map(rank, mesh=mesh_8, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
         x = jnp.ones((8, 4))
         with mesh_8:
@@ -220,6 +220,33 @@ class TestFullParamAllgather:
         )
         assert not [f for f in findings
                     if f.severity >= Severity.WARNING], findings
+
+
+    @pytest.mark.parametrize("gather_dim,flagged", [(1, True), (0, False)])
+    def test_gather_must_run_along_a_sharded_dim(self, gather_dim,
+                                                 flagged):
+        """An all-gather whose per-device result has a sharded param's
+        full shape rebuilds that param only if it gathers along a
+        dimension the param is sharded on; along a dimension the param
+        keeps whole it is a same-shaped activation (the graft driver's
+        pipeline input against its pipeline weight on jax 0.9)."""
+        from sparkdl_tpu.analysis.core import ParamInfo, run_passes
+
+        hlo = (
+            "  %ag = f32[16,64]{1,0} all-gather(%x), channel_id=1, "
+            "replica_groups=[4,2]<=[8], dimensions={"
+            + str(gather_dim) + "}, use_global_device_ids=true\n")
+        ctx = GraphContext(
+            hlo_text=hlo,
+            param_info=[ParamInfo(
+                path="['w']", shape=(16, 64), dtype="float32",
+                sharded_axes=("model",), spec=((), ("model",)),
+                mesh_axes=(("data", 4), ("model", 2)))],
+        )
+        errors = [f for f in run_passes(
+            ctx, passes=["full-param-allgather"])
+            if f.severity == Severity.ERROR]
+        assert bool(errors) == flagged, errors
 
 
 # ---------------------------------------------------------------------------

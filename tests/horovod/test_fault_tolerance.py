@@ -15,7 +15,7 @@ on CPU gangs:
    with its classified cause.
 
 Unit-level classification/backoff/codec checks ride along so the
-taxonomy itself is pinned without spawning gangs.
+classification itself is pinned without spawning gangs.
 """
 
 import os
@@ -38,7 +38,7 @@ from sparkdl_tpu.horovod.supervisor import (
 pytestmark = pytest.mark.chaos
 
 
-# -- classification taxonomy (no gangs spawned) -----------------------------
+# -- failure classification (no gangs spawned) ------------------------------
 
 
 def test_signal_death_is_transient():

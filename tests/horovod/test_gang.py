@@ -202,7 +202,7 @@ def test_alltoall_rank_divergent_splits():
 @pytest.mark.gang
 def test_orphaned_workers_exit_when_driver_dies():
     """Regression: SIGKILLing the driver must not leave gang workers
-    running (observed pinning device leases)."""
+    running (observed: they kept their devices)."""
     import os
     import signal
     import subprocess

@@ -53,14 +53,24 @@ def test_tpu_pod_env_multi_host():
     assert addrs[0].startswith("h0:") and addrs[3].startswith("h1:")
 
 
-def test_tpu_single_host_stays_isolated():
-    """Single-host multi-chip gangs keep the per-chip isolation env
-    (no pod addresses), matching the long-standing launcher behavior."""
+@pytest.mark.parametrize("chip_bounds,expect", [
+    ((2, 2, 1), "2,2,1"),   # the probe's grid, when the gang fills it
+    (None, "4,1,1"),        # no probe: a row
+    ((2, 4, 1), "4,1,1"),   # gang smaller than the host: a row
+])
+def test_tpu_single_host_is_one_slice_over_its_chips(chip_bounds, expect):
+    """Single-host multi-chip gangs are one process per chip of ONE
+    slice: isolated 1,1,1 runtimes would each be process 0 of 1 and
+    the hvd mesh would hold one device."""
     p = Placement.single_host(4)
-    env = p.env_for_rank(2, tpu=True)
+    env = p.env_for_rank(2, tpu=True, chip_bounds=chip_bounds)
     assert env["TPU_VISIBLE_DEVICES"] == "2"
-    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
-    assert "TPU_PROCESS_ADDRESSES" not in env
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == expect
+    assert env["CLOUD_TPU_TASK_ID"] == "2"
+    addrs = env["TPU_PROCESS_ADDRESSES"].split(",")
+    assert len(set(addrs)) == 4
+    assert addrs[2] == f"localhost:{env['TPU_PROCESS_PORT']}"
 
 
 def test_tpu_pod_env_requires_uniform_layout():

@@ -135,6 +135,20 @@ def test_llama_causality():
     assert not np.allclose(np.asarray(out1[0, -1]), np.asarray(out2[0, -1]))
 
 
+@pytest.mark.parametrize("preset,rope", [
+    ("llama3_8b", None), ("llama31_8b", ("llama3", 8.0, 1.0, 4.0, 8192))])
+def test_llama_8b_presets_take_overrides(preset, rope):
+    """The published-shape presets accept overrides of the fields they
+    set themselves: a depth cut is ``n_layers=``, widths untouched."""
+    from sparkdl_tpu.models import LlamaConfig
+
+    cfg = getattr(LlamaConfig, preset)(n_layers=4, lora_rank=8)
+    assert (cfg.n_layers, cfg.lora_rank, cfg.rope_scaling) == (4, 8, rope)
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
+            cfg.vocab_size) == (4096, 32, 8, 14336, 128256)
+    assert getattr(LlamaConfig, preset)().n_layers == 32
+
+
 def test_lora_merge_equivalence():
     """merge_lora_with folds adapters: merged plain forward == LoRA
     forward."""

@@ -217,7 +217,7 @@ def _post_raw(fe, payload):
 
 
 def test_status_classification_400_500_then_recovery():
-    """The full fault taxonomy on one server: validation 400, engine
+    """Every fault class on one server: validation 400, engine
     fault 500, then the same server serves 200 (fault recovery)."""
     # multi-line fault text: send_error puts the message on the HTTP
     # status line, so the server must collapse it or the 500 would

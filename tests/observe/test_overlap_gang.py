@@ -38,7 +38,6 @@ def _overlap_gang_main(n_steps):
     import sparkdl_tpu.hvd as hvd
     from sparkdl_tpu.parallel.ring_attention import ring_self_attention
     from sparkdl_tpu.parallel.train import instrument_step
-    from sparkdl_tpu.utils.jax_compat import shard_map
 
     hvd.init()
     # The ring spans the GANG: one device per process on the "seq"
@@ -57,7 +56,7 @@ def _overlap_gang_main(n_steps):
     mine = by_proc[jax.process_index()]
 
     def ring(overlap):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             partial(ring_self_attention, axis_name="seq", causal=True,
                     overlap=overlap),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -219,8 +219,9 @@ def test_peak_table_keys_off_device_kind():
     assert perf.peak_flops("TPU v5 lite") == 197e12
     assert perf.peak_flops("TPU v5p") == 459e12
     assert perf.peak_flops("cpu") == perf.PEAK_TABLE["cpu"][0]
-    # unknown accelerators keep the historical v5e constant
-    assert perf.peak_flops("TPU v9 hypothetical") == 197e12
+    # an unknown accelerator is an error, never another chip's peak
+    with pytest.raises(ValueError, match="not in the peaks table"):
+        perf.peak_flops("TPU v9 hypothetical")
     assert perf.peak_bytes_per_sec("TPU v5p") == 2.77e12
 
 

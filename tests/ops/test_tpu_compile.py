@@ -1,0 +1,171 @@
+"""The kernels of the main path, compiled ahead of time for a v5e chip
+that is described and not attached, at Llama-3-8B's widths.
+
+Interpret mode cannot see what the chip's compiler refuses: a block
+that breaks the (8, 128) tiling, an operand layout Mosaic does not
+accept, an operation it cannot legalise. Before this file three of the
+four kernel families of the serving path passed every interpret-mode
+test and were refused here. Nothing runs, so these tests say nothing
+about results or times.
+
+The topology is described inside a fixture, never while a module is
+imported: one process at a time may load the TPU's library, and every
+pytest-xdist worker imports every test file. Keep these tests in this
+one file for the same reason.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# LlamaConfig.llama3_8b: 4096 wide, 32 query / 8 kv heads of 128,
+# d_ff 14336, vocabulary 128256
+N_HEADS, N_KV, HEAD_DIM = 32, 8, 128
+# (K, N) of the decoder's projections: gate/up, down, lm_head
+PROJECTIONS = [(4096, 14336), (14336, 4096), (4096, 128256)]
+ROWS = [8, 512]   # a decode step's batch, a prefill bucket
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip (the next one warns
+    and compiles again): keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_attention(one_chip, backward):
+    from sparkdl_tpu.ops.attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    qkv = _shape(one_chip, (1, 2048, N_HEADS, HEAD_DIM), jnp.bfloat16)
+    _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("page", [16, 64])
+def test_paged_attention_decode(one_chip, page):
+    from sparkdl_tpu.ops.pallas.paged_attention import (
+        paged_attention_decode,
+    )
+
+    batch, n_pages = 8, 1024
+    pool = _shape(one_chip, (n_pages, page, N_KV, HEAD_DIM), jnp.bfloat16)
+    _compile(
+        paged_attention_decode,
+        _shape(one_chip, (batch, N_HEADS, HEAD_DIM), jnp.bfloat16),
+        pool, pool,
+        _shape(one_chip, (batch, 2048 // page), jnp.int32),
+        _shape(one_chip, (batch,), jnp.int32))
+
+
+@pytest.mark.parametrize("k,n", PROJECTIONS)
+@pytest.mark.parametrize("m", ROWS)
+def test_int8_quant_matmul(one_chip, m, k, n):
+    from sparkdl_tpu.ops.pallas.quantized_matmul import (
+        quantized_matmul_pallas,
+    )
+
+    _compile(
+        quantized_matmul_pallas,
+        _shape(one_chip, (m, k), jnp.bfloat16),
+        _shape(one_chip, (k, n), jnp.int8),
+        _shape(one_chip, (n,), jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", PROJECTIONS)
+@pytest.mark.parametrize("m", ROWS)
+def test_int4_quant_matmul(one_chip, m, k, n):
+    from sparkdl_tpu.ops.pallas.quantized_matmul import (
+        INT4_GROUP,
+        quantized_matmul_int4_pallas,
+    )
+
+    _compile(
+        quantized_matmul_int4_pallas,
+        _shape(one_chip, (m, k), jnp.bfloat16),
+        _shape(one_chip, (k // 2, n), jnp.int8),
+        _shape(one_chip, (k // INT4_GROUP, n), jnp.float32))
+
+
+@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+def test_paged_engine_decode_step(one_chip, monkeypatch, quant):
+    """One decode step of the paged engine's own program at depth 1:
+    the kernels as the model calls them, with everything around them.
+    The program asks the backend whether to use its kernels, and here
+    the backend is the CPU — the test answers for the described chip."""
+    from sparkdl_tpu.models import Llama, LlamaConfig
+    from sparkdl_tpu.models.serving import _engine_programs
+    from sparkdl_tpu.ops import _dispatch
+
+    monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
+    n_slots, page, max_len = 8, 16, 2048
+    max_pages = max_len // page
+    cfg = LlamaConfig.llama3_8b(
+        n_layers=1, dtype=jnp.bfloat16, decode=True, max_cache_len=max_len,
+        page_size=page, n_pages=n_slots * max_pages + 1, quant=quant)
+    model = Llama(cfg)
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    state = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), ints((n_slots, 1)),
+        positions=ints((n_slots, 1)),
+        block_tables=ints((n_slots, max_pages)))
+    on_chip = functools.partial(
+        jax.tree.map,
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip))
+    decode_chunk = _engine_programs(cfg, 0.0)[4]
+    compiled = decode_chunk.lower(
+        on_chip(state["params"]), on_chip(state["cache"]),
+        _shape(one_chip, (n_slots,), jnp.int32),
+        _shape(one_chip, (n_slots,), jnp.int32),
+        _shape(one_chip, (n_slots,), jnp.bool_),
+        _shape(one_chip, (2,), jnp.uint32), 1,
+        tables=_shape(one_chip, (n_slots, max_pages), jnp.int32),
+    ).compile()
+    # the paged kernel, and with quantized weights its seven
+    # projections and the head beside it
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        9 if quant else 1)

@@ -15,6 +15,7 @@ import pytest
 
 from sparkdl_tpu.parallel.compile import (
     COMPILE_CACHE_DIR_ENV,
+    JAX_CACHE_DIR_ENV,
     CompiledStepCache,
     enable_persistent_cache,
     load_or_compile,
@@ -143,8 +144,14 @@ def test_garbage_and_mismatched_entries_degrade(cache, caplog):
     assert cache.misses == 2
 
 
+@pytest.mark.parametrize("jax_var_set", [False, True])
 def test_enable_persistent_cache_points_jax_at_the_dir(tmp_path,
-                                                      monkeypatch):
+                                                      monkeypatch,
+                                                      jax_var_set):
+    """``SPARKDL_TPU_COMPILE_CACHE_DIR`` alone: JAX's config is pointed
+    there. With ``JAX_COMPILATION_CACHE_DIR`` set it outranks the
+    repo's variable and no directory is set in code at all (JAX read
+    its own variable at import)."""
     import sparkdl_tpu.parallel.compile as compile_mod
 
     # enable_persistent_cache mutates process-global jax config;
@@ -162,11 +169,19 @@ def test_enable_persistent_cache_points_jax_at_the_dir(tmp_path,
     }
     saved_latch = compile_mod._persistent_cache_dir
     d = str(tmp_path / "xla-cache")
+    monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
     monkeypatch.setenv(COMPILE_CACHE_DIR_ENV, d)
+    if jax_var_set:
+        monkeypatch.setenv(JAX_CACHE_DIR_ENV, str(tmp_path / "jax-says"))
     try:
         resolved = enable_persistent_cache()
-        assert resolved == d and os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
+        if jax_var_set:
+            assert resolved == str(tmp_path / "jax-says")
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+        else:
+            assert resolved == d and os.path.isdir(d)
+            assert jax.config.jax_compilation_cache_dir == d
         assert jax.config.jax_enable_compilation_cache is True
     finally:
         for name, value in saved.items():
@@ -176,6 +191,7 @@ def test_enable_persistent_cache_points_jax_at_the_dir(tmp_path,
 
 def test_enable_persistent_cache_noop_without_optin(monkeypatch):
     monkeypatch.delenv(COMPILE_CACHE_DIR_ENV, raising=False)
+    monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
     assert enable_persistent_cache() is None
 
 
@@ -183,6 +199,7 @@ def test_module_level_load_or_compile_without_optin(monkeypatch):
     """Library code calls load_or_compile unconditionally; with no
     cache dir configured it must be a plain cold compile."""
     monkeypatch.delenv(COMPILE_CACHE_DIR_ENV, raising=False)
+    monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
     lowered, args = _lowered_train_step()
     compiled = load_or_compile(lowered)
     assert np.isfinite(float(np.asarray(compiled(*args)[2]["loss"])))

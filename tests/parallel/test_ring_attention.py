@@ -5,8 +5,7 @@ reference lacks entirely)."""
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.utils import jax_compat
-from sparkdl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 import numpy as np
 import pytest
 
@@ -84,13 +83,6 @@ class TestRingFlash:
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_forward_matches_dense(self, mesh_2x4, causal):
-        if not causal and jax_compat.old_xla_spmd_partitioner():
-            pytest.skip(
-                "old-XLA SPMD partitioner limit (jax<0.5): the "
-                "non-causal ring-flash schedule lowers a PartitionId "
-                "op the bundled partitioner rejects (\"PartitionId "
-                "instruction is not supported for SPMD partitioning\")"
-            )
         rng = np.random.RandomState(3)
         b, s, h, d = 2, 64, 2, 16
         q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
@@ -147,15 +139,6 @@ class TestRingFlash:
             )
 
 
-@pytest.mark.skipif(
-    jax_compat.old_xla_spmd_partitioner(),
-    reason="old-XLA SPMD partitioner limit (jax<0.5): the ring-flash "
-           "llama composition intermittently lowers through the same "
-           "PartitionId path the bundled partitioner rejects "
-           "(\"PartitionId instruction is not supported for SPMD "
-           "partitioning\"); deterministic-green tier-1 gates it to "
-           "the modern lines",
-)
 def test_llama_trains_with_ring_flash(mesh_2x4):
     """Model-level composition: the flagship Llama with ring-FLASH
     attention injected under shard_map must produce the same loss and
@@ -238,12 +221,6 @@ class TestOverlapEquivalence:
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_flash_forward_bit_exact(self, mesh_2x4, causal):
-        if not causal and jax_compat.old_xla_spmd_partitioner():
-            pytest.skip(
-                "old-XLA SPMD partitioner limit (jax<0.5): non-causal "
-                "ring-flash lowers a PartitionId op the bundled "
-                "partitioner rejects"
-            )
         rng = np.random.RandomState(8)
         b, s, h, d = 2, 64, 2, 16
         q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)

@@ -1,7 +1,7 @@
 """Bench harness machinery tests (cpu, tiny config).
 
 The driver runs ``python bench.py`` and requires exactly one JSON line
-on stdout; round 1 died hanging on a wedged accelerator lease, so the
+on stdout; a backend that never answers must not hang it, so the
 bounded-probe orchestration is contract, not decoration.
 """
 
@@ -104,18 +104,10 @@ def test_bench_cpu_proxy_on_deviceless_host():
     assert "mfu" not in out
 
 
-@pytest.mark.skipif(
-    bool(__import__("glob").glob("/dev/accel*")
-         + __import__("glob").glob("/dev/vfio/*")
-         + __import__("glob").glob("/dev/nvidia*")),
-    reason="host has accelerator devices; probe retries are legitimate")
 def test_bench_probe_fast_fails_without_accel_devices():
-    """No /dev/accel* -> ONE probe attempt, no retry schedule (the
-    multi-minute pause ladder exists for wedged leases, not absent
-    chips). The explicit bogus platform pins the probe failure AND
-    opts out of the cpu-proxy fallback, so the bench must report the
-    error quickly. Deliberately does NOT set the PROBE_PAUSE compat
-    var: with retries the default schedule would burn ~6.5 minutes."""
+    """ONE probe attempt, never a retry schedule. The explicit bogus
+    platform pins the probe failure AND opts out of the cpu-proxy
+    fallback, so the bench must report the error quickly."""
     import time
 
     t0 = time.monotonic()
@@ -128,8 +120,7 @@ def test_bench_probe_fast_fails_without_accel_devices():
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["value"] is None
     assert "unavailable" in out["error"]
-    assert elapsed < 150, f"probe retried despite no /dev/accel* " \
-                          f"({elapsed:.0f}s)"
+    assert elapsed < 150, f"probe retried ({elapsed:.0f}s)"
 
 
 def _load_bench():
@@ -237,152 +228,6 @@ class TestFailPaths:
         assert out["value"] is None
 
 
-class TestKillOwnStale:
-    def test_script_match_is_absolute_to_this_repo(self):
-        b = _load_bench()
-        assert b._is_own_bench_script(BENCH)
-        assert b._is_own_bench_script(
-            os.path.join(REPO, "benchmarks", "allreduce_bench.py"))
-        # the substring trap: an UNRELATED project's benchmarks/ dir
-        assert not b._is_own_bench_script("/home/u/proj/benchmarks/x.py")
-        assert not b._is_own_bench_script("/home/u/proj/bench.py")
-        assert not b._is_own_bench_script("")
-
-    def test_relative_argv_resolved_against_holder_cwd(self, monkeypatch):
-        """A foreign `python bench.py` run from ITS OWN directory must
-        not alias onto this repo's bench.py via OUR cwd."""
-        b = _load_bench()
-        # no pid: cannot resolve, never match
-        assert not b._is_own_bench_script("bench.py")
-        monkeypatch.setattr(
-            b, "_holder_cwd", lambda p: "/home/other/project")
-        assert not b._is_own_bench_script("bench.py", pid="123")
-        # holder genuinely running from this repo: match
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: REPO)
-        assert b._is_own_bench_script("bench.py", pid="123")
-        # unreadable /proc cwd: never kill on a guess
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: None)
-        assert not b._is_own_bench_script("bench.py", pid="123")
-
-    def test_sigterm_before_sigkill_and_age_guard(self, monkeypatch):
-        import signal
-        import time as _time
-
-        b = _load_bench()
-        kills = []
-        monkeypatch.setattr(
-            b.os, "kill",
-            lambda pid, sig: kills.append((pid, sig)) if sig else None)
-        # fake /proc: cmdline names our own bench.py, age is stale
-        monkeypatch.setattr(b, "_proc_age_s", lambda pid: 7200)
-        real_open = open
-
-        def fake_open(path, *a, **kw):
-            if path == "/proc/4242/cmdline":
-                import io
-
-                return io.StringIO(f"{sys.executable}\0{BENCH}\0")
-            return real_open(path, *a, **kw)
-
-        monkeypatch.setattr("builtins.open", fake_open)
-        b._kill_own_stale(["pid 4242: python bench.py"], _sleep=lambda s: None)
-        # SIGTERM first; SIGKILL only because our fake never dies
-        # (os.kill(pid, 0) is recorded but raises nothing)
-        sigs = [s for _, s in kills if s]
-        assert sigs[0] == signal.SIGTERM
-        assert sigs[-1] == signal.SIGKILL
-
-        # young holder: untouched
-        kills.clear()
-        monkeypatch.setattr(b, "_proc_age_s", lambda pid: 60)
-        b._kill_own_stale(["pid 4242: python bench.py"], _sleep=lambda s: None)
-        assert kills == []
-
-    def test_repo_pytest_detection(self, monkeypatch):
-        """The lease window is defended against the repo's own test
-        runners (VERDICT weak #1): pytest tied to THIS repo by cwd or
-        argv path matches; foreign pytest and non-pytest repo
-        processes (user jobs) never do."""
-        b = _load_bench()
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: REPO)
-        assert b._is_repo_pytest(
-            ["/usr/bin/python", "-m", "pytest", "tests/"], "1")
-        assert b._is_repo_pytest(["/usr/local/bin/pytest", "-q"], "1")
-        # repo-internal test path names us even from a foreign cwd
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: "/home/other")
-        assert b._is_repo_pytest(
-            ["python", "-m", "pytest",
-             os.path.join(REPO, "tests", "test_bench.py")], "1")
-        # foreign pytest: no repo tie -> never ours
-        assert not b._is_repo_pytest(
-            ["python", "-m", "pytest", "tests/"], "1")
-        # NOT a test runner: user jobs stay untouchable even from our
-        # cwd (a live HorovodRunner gang also maps the plugin)
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: REPO)
-        assert not b._is_repo_pytest(
-            ["python", "-m", "sparkdl_tpu.horovod._worker"], "1")
-        assert not b._is_repo_pytest(["python", "train.py"], "1")
-
-    def test_repo_pytest_reaped_when_stale_refused_when_live(
-            self, monkeypatch):
-        import signal
-
-        b = _load_bench()
-        kills = []
-        monkeypatch.setattr(
-            b.os, "kill",
-            lambda pid, sig: kills.append((pid, sig)) if sig else None)
-        monkeypatch.setattr(b, "_holder_cwd", lambda p: REPO)
-        real_open = open
-
-        def fake_open(path, *a, **kw):
-            if path == "/proc/5151/cmdline":
-                import io
-
-                return io.StringIO(
-                    f"{sys.executable}\0-m\0pytest\0tests/\0")
-            return real_open(path, *a, **kw)
-
-        monkeypatch.setattr("builtins.open", fake_open)
-        # stale (past the pytest bar, below the bench bar): reaped
-        monkeypatch.setattr(
-            b, "_proc_age_s", lambda pid: b.PYTEST_STALE_AGE_S + 60)
-        live = b._kill_own_stale(
-            ["pid 5151: python -m pytest tests/"], _sleep=lambda s: None)
-        assert live == []
-        assert [s for _, s in kills if s][0] == signal.SIGTERM
-        # live (young): refused, returned for the orchestrator's
-        # fail-fast instead of burning the probe schedule
-        kills.clear()
-        monkeypatch.setattr(b, "_proc_age_s", lambda pid: 120)
-        live = b._kill_own_stale(
-            ["pid 5151: python -m pytest tests/"], _sleep=lambda s: None)
-        assert live == ["5151"]
-        assert kills == []
-
-    def test_foreign_script_never_killed(self, monkeypatch):
-        b = _load_bench()
-        kills = []
-        monkeypatch.setattr(
-            b.os, "kill", lambda pid, sig: kills.append((pid, sig)))
-        monkeypatch.setattr(b, "_proc_age_s", lambda pid: 7200)
-        real_open = open
-
-        def fake_open(path, *a, **kw):
-            if path == "/proc/777/cmdline":
-                import io
-
-                return io.StringIO(
-                    f"{sys.executable}\0/other/benchmarks/train.py\0")
-            return real_open(path, *a, **kw)
-
-        monkeypatch.setattr("builtins.open", fake_open)
-        b._kill_own_stale(["pid 777: python /other/benchmarks/train.py"],
-                          _sleep=lambda s: None)
-        assert kills == []
-
-
-@pytest.mark.gang
 def test_bench_promoted_variant_config(tmp_path):
     """A committed promoted.json redirects the headline measurement
     (fused-CE loss path here) without code changes; the emitted record
@@ -440,7 +285,6 @@ def test_bench_fails_fast_when_backend_unavailable():
         "SPARKDL_TPU_BENCH_PLATFORM": "nosuchplatform",
         "SPARKDL_TPU_BENCH_TINY": "1",
         "SPARKDL_TPU_BENCH_PROBE_TIMEOUT": "60",
-        "SPARKDL_TPU_BENCH_PROBE_PAUSE": "1",
     }, timeout=200)
     assert r.returncode != 0
     out = json.loads(r.stdout.strip().splitlines()[-1])

@@ -97,7 +97,6 @@ def test_multichip_hlo_never_allgathers_a_full_tp_param():
 
     from sparkdl_tpu.analysis import Severity, lint_compiled
     from sparkdl_tpu.parallel.train import lower_train_step
-    from sparkdl_tpu.utils import jax_compat
 
     compiled = lower_train_step(
         step, params, opt_state, batch, mesh=mesh).compile()
@@ -113,18 +112,12 @@ def test_multichip_hlo_never_allgathers_a_full_tp_param():
     )
     errors = [f for f in findings if f.severity == Severity.ERROR]
     assert not errors, "\n".join(map(str, errors))
-    # The size-bound WARNINGs must also be silent on the modern
-    # partitioner (grep parity). The old XLA bundled with jax 0.4.x
-    # gathers a boundary-sized f32[2,8,256] logits ACTIVATION (4096
-    # elements — exactly the bound); that is the known old-XLA
-    # partitioner boundary, not a param regather, so the strict bound
-    # applies only to the modern lines.
-    if not jax_compat.old_xla_spmd_partitioner():
-        size_warnings = [
-            f for f in findings
-            if f.severity == Severity.WARNING and "bound" in f.message
-        ]
-        assert not size_warnings, "\n".join(map(str, size_warnings))
+    # The size-bound WARNINGs must also be silent (grep parity).
+    size_warnings = [
+        f for f in findings
+        if f.severity == Severity.WARNING and "bound" in f.message
+    ]
+    assert not size_warnings, "\n".join(map(str, size_warnings))
 
 
 def test_multichip_updated_params_keep_their_shardings():

@@ -125,20 +125,3 @@ def test_real_memory_analysis_never_raises(lowered_and_compiled):
         assert all(isinstance(v, int) for v in out.values())
     # Lowered has no memory_analysis on any line -> None, not a raise
     assert jax_compat.memory_analysis(lowered) is None
-
-
-@pytest.mark.skipif(jax_compat.jax_version() >= (0, 5, 0),
-                    reason="0.4.x list-of-dicts shape only")
-def test_old_jax_compiled_cost_shape_is_normalized(lowered_and_compiled):
-    """On the container's jax 0.4.37 the raw ``Compiled.cost_analysis``
-    IS a list — pin that the shim flattens exactly that shape, so this
-    test starts failing (and gets deleted) if a jax upgrade changes
-    the raw contract the shim exists for."""
-    _, compiled = lowered_and_compiled
-    raw = compiled.cost_analysis()
-    if raw is None:
-        pytest.skip("this backend reports no cost model")
-    assert isinstance(raw, (list, dict))
-    if isinstance(raw, list):
-        norm = jax_compat.cost_analysis(compiled)
-        assert norm is None or isinstance(norm, dict)
