@@ -28,9 +28,9 @@ accelerator touch is bounded):
 Warm-start compilation: ``--run`` enables the persistent XLA compile
 cache and serves the measured program through
 :class:`sparkdl_tpu.parallel.compile.CompiledStepCache`. The cache
-lives where ``JAX_COMPILATION_CACHE_DIR`` says; unset, at
-``SPARKDL_TPU_COMPILE_CACHE_DIR`` or else the fixed in-checkout
-``.jax_cache`` (:func:`sparkdl_tpu.parallel.compile.export_cache_dir`),
+lives where ``JAX_COMPILATION_CACHE_DIR`` says; unset, at the fixed
+in-checkout ``.jax_cache``
+(:func:`sparkdl_tpu.parallel.compile.export_cache_dir`),
 so a rerun deserializes the step executable instead of recompiling. The
 JSON line carries ``compile_seconds`` (wall time to a ready
 executable) and ``warm_start`` (True when it came from the AOT cache),

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """CI compile-cache smoke (ISSUE: warm-start compilation satellite):
 launch the same tiny instrumented gang TWICE against one fresh
-``SPARKDL_TPU_COMPILE_CACHE_DIR`` and FAIL the build unless the second
+``JAX_COMPILATION_CACHE_DIR`` and FAIL the build unless the second
 launch's merged ``metrics.prom`` shows ``compile_cache_hits_total >=
 1`` — the end-to-end proof that the launcher ships the cache dir, the
 worker bootstrap enables it before backend init, and
@@ -9,7 +9,7 @@ worker bootstrap enables it before backend init, and
 
 Usage::
 
-    SPARKDL_TPU_COMPILE_CACHE_DIR=<dir> \\
+    JAX_COMPILATION_CACHE_DIR=<dir> \\
     SPARKDL_TPU_TELEMETRY_DIR=<dir> python ci/compile_cache_smoke.py
 
 (defaults: ``./compile-cache`` and ``./compile-cache-telemetry``).
@@ -77,7 +77,7 @@ def _hits_total(prom_path):
 
 def main():
     cache_dir = os.environ.setdefault(
-        "SPARKDL_TPU_COMPILE_CACHE_DIR",
+        "JAX_COMPILATION_CACHE_DIR",
         os.path.join(os.getcwd(), "compile-cache"),
     )
     out_dir = os.environ.setdefault(

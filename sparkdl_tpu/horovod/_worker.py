@@ -251,7 +251,7 @@ def main():
     # at the gang-wide dir BEFORE backend init, so this worker — a
     # fresh attempt's relaunch included — reuses every XLA artifact a
     # previous incarnation paid for. No-op unless the launcher shipped
-    # SPARKDL_TPU_COMPILE_CACHE_DIR (see sparkdl_tpu/parallel/compile).
+    # JAX_COMPILATION_CACHE_DIR (see sparkdl_tpu/parallel/compile).
     from sparkdl_tpu.parallel.compile import enable_persistent_cache
 
     enable_persistent_cache()

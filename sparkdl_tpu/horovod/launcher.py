@@ -14,7 +14,7 @@ Responsibilities (each clause cites the contract it implements):
   timeout or the whole gang is killed.
 - bind one task to one TPU chip — the TPU replacement for the
   reference's one-GPU-per-slot rule (reference ``runner_base.py:44-45``)
-  — via ``TPU_VISIBLE_DEVICES`` when multiple workers share a host.
+  — via ``TPU_VISIBLE_CHIPS`` when multiple workers share a host.
 - route worker logs per ``driver_log_verbosity`` and return rank 0's
   cloudpickled result (reference ``runner_base.py:62-72``, ``:93-95``).
 
@@ -39,14 +39,13 @@ from sparkdl_tpu.hvd._state import COORD_ENV
 
 COORD_PORT_ENV = "SPARKDL_TPU_COORDINATOR_PORT"
 # Warm-start compilation: when the driver's environment names a
-# compile cache (JAX_COMPILATION_CACHE_DIR, or below it
-# SPARKDL_TPU_COMPILE_CACHE_DIR), every worker env carries it (local
-# Popen children inherit it via _worker_env's base_env copy; remote
-# ranks ride the forward in _remote_worker_cmd), every supervised
-# relaunch re-ships it, and _worker.py turns JAX's persistent compile
-# cache on there before backend init. The module is import-light (jax
-# only inside functions), so the launcher can take its names from
-# their canonical home.
+# compile cache (JAX_COMPILATION_CACHE_DIR), every worker env carries
+# it (local Popen children inherit it via _worker_env's base_env copy;
+# remote ranks ride the forward in _remote_worker_cmd), every
+# supervised relaunch re-ships it, and _worker.py turns JAX's
+# persistent compile cache on there before backend init. The module is
+# import-light (jax only inside functions), so the launcher can take
+# its names from their canonical home.
 from sparkdl_tpu.parallel.compile import (
     JAX_CACHE_DIR_ENV,
     persistent_cache_dir,
@@ -56,8 +55,10 @@ logger = logging.getLogger("HorovodRunner")
 
 
 class SlotExhaustionError(RuntimeError):
-    """np exceeds TOTAL task slots (reference runner_base.py:56-58).
-    Never retried — more restarts cannot create slots."""
+    """np cannot be placed on the task slots there are: it exceeds
+    their TOTAL (reference runner_base.py:56-58), or it is a part of a
+    TPU host's chips that the runtime starts no slice on. Never
+    retried — more restarts cannot create slots."""
 
 
 class SlotProbeError(RuntimeError):
@@ -85,16 +86,31 @@ DEFAULT_SLOT_WAIT_TIMEOUT = 600.0
 LARGE_PAYLOAD_BYTES = 10 << 20
 
 
+def _free_ports(n):
+    """``n`` distinct loopback ports that are free now (held open
+    together, so that no two are the same)."""
+    socks = [socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+             for _ in range(n)]
+    try:
+        for s in socks:
+            s.bind(("127.0.0.1", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
+
+
 def _free_port():
-    s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+    return _free_ports(1)[0]
 
 
 LocalDevices = collections.namedtuple(
     "LocalDevices", "count platform chip_bounds")
+
+# SPARKDL_TPU_NUM_SLOTS is no way round a probe that fails: it gives
+# the count of slots, and what they are is still asked of the host.
+_NO_PROBE_HINT = (f"(only {WORKER_PLATFORM_ENV}=cpu, a gang on CPU "
+                  "devices, is launched without this probe)")
 
 
 @functools.lru_cache(maxsize=None)
@@ -130,8 +146,8 @@ def probe_local_devices(platform):
     except subprocess.TimeoutExpired:
         raise SlotProbeError(
             "slot discovery timed out after 120s probing local "
-            "accelerator devices — the backend may be wedged (set "
-            f"{NUM_SLOTS_ENV} to bypass discovery)"
+            "accelerator devices — the backend may be wedged "
+            f"{_NO_PROBE_HINT}"
         )
     except Exception as e:
         detail = ""
@@ -140,8 +156,39 @@ def probe_local_devices(platform):
             detail = f"; probe stderr tail: {out.stderr.strip()[-400:]}"
         raise SlotProbeError(
             f"slot discovery failed ({type(e).__name__}: {e}){detail} "
-            f"(set {NUM_SLOTS_ENV} to bypass discovery)"
+            f"{_NO_PROBE_HINT}"
         )
+
+
+def _local_tpu(platform, spec_placement, num_workers):
+    """``(chip_bounds, ports)`` for a gang whose ranks each take one
+    TPU chip of THIS host, else ``(None, None)``. With no hosts spec
+    every rank runs here, and what is attached decides whether a rank
+    is bound to a chip — in local mode and under SPARKDL_TPU_NUM_SLOTS
+    too, which overrides the COUNT of slots, not what they are. The
+    probe is cached: in cluster mode this is the answer
+    ``_resolve_num_workers`` already has. Only workers forced onto the
+    CPU are not asked about.
+
+    Same-host ranks are ONE slice, and the TPU runtime starts a slice
+    only on a whole topology: on a four-chip v5e host four ranks join
+    (``2,2,1``) and two (``2,1,1`` over chips 0 and 1) die in the
+    runtime's start-up (chip runs, PR 21). So a multi-rank gang fills
+    the host or is refused here, by name, before anything is spawned."""
+    if spec_placement is not None or platform == "cpu":
+        return None, None
+    local = probe_local_devices(platform)
+    if local.platform != "tpu":
+        return None, None
+    if 1 < num_workers != local.count:
+        raise SlotExhaustionError(
+            f"HorovodRunner requested {num_workers} ranks on a host of "
+            f"{local.count} TPU chips (grid "
+            f"{','.join(map(str, local.chip_bounds))}): same-host ranks "
+            "are one TPU slice, and the runtime starts none on a part "
+            f"of the host's chips. Use np={local.count}, one rank a "
+            "chip, or np=1, one process over all of them.")
+    return local.chip_bounds, _free_ports(num_workers)
 
 
 def available_slots():
@@ -312,7 +359,7 @@ def _resolve_num_workers(np_arg, placement=None):
 
 def _worker_env(base_env, *, rank, size, coordinator, control_addr,
                 control_secret, payload_path, job_dir, platform,
-                placement=None, tpu_chip_bounds=None):
+                placement=None, tpu_chip_bounds=None, tpu_ports=None):
     from sparkdl_tpu.horovod.topology import Placement
 
     env = dict(base_env)
@@ -343,7 +390,7 @@ def _worker_env(base_env, *, rank, size, coordinator, control_addr,
     # every worker take every chip.
     for k, v in placement.env_for_rank(
             rank, tpu=platform == "tpu" or tpu_chip_bounds is not None,
-            chip_bounds=tpu_chip_bounds).items():
+            chip_bounds=tpu_chip_bounds, ports=tpu_ports).items():
         if (k in ("TPU_PROCESS_BOUNDS", "TPU_CHIPS_PER_PROCESS_BOUNDS")
                 and base_env.get(k)):
             # An operator-exported slice layout (e.g. a 2D "2,2,1"
@@ -831,14 +878,8 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
             "all" if mode == "local" else driver_log_verbosity
         )
         platform = os.environ.get(WORKER_PLATFORM_ENV)
-        tpu_chip_bounds = None
-        if (mode == "cluster" and spec_placement is None
-                and not os.environ.get(NUM_SLOTS_ENV)):
-            # what the slot probe of _resolve_num_workers saw (cached);
-            # SPARKDL_TPU_NUM_SLOTS bypasses discovery here as there
-            local = probe_local_devices(platform)
-            if local.platform == "tpu":
-                tpu_chip_bounds = local.chip_bounds
+        tpu_chip_bounds, tpu_ports = _local_tpu(
+            platform, spec_placement, num_workers)
         server = ControlPlaneServer(
             num_workers,
             verbosity=effective_verbosity,
@@ -922,7 +963,7 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                 control_secret=server.secret,
                 payload_path=payload_paths[r], job_dir=job_dir,
                 platform=platform, placement=gang_placement,
-                tpu_chip_bounds=tpu_chip_bounds,
+                tpu_chip_bounds=tpu_chip_bounds, tpu_ports=tpu_ports,
             )
             for pk, pv in profile_env.items():
                 env.setdefault(pk, pv)

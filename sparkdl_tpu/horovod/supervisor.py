@@ -60,7 +60,7 @@ untouched; see ``docs/fault_tolerance.rst``):
   targets are shipped to the relaunched workers through the same env
   var. With no registered tree the relaunch proceeds unchecked
   (nothing provable).
-- ``SPARKDL_TPU_COMPILE_CACHE_DIR`` (read by the launcher/worker, not
+- ``JAX_COMPILATION_CACHE_DIR`` (read by JAX and the worker, not
   here, but load-bearing for this loop): the warm-start compile cache
   (:mod:`sparkdl_tpu.parallel.compile`). It rides the inherited
   environment into every relaunched attempt, so a replacement rank

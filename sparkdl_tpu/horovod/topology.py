@@ -91,13 +91,21 @@ class Placement:
     def local_size(self, rank):
         return self.hosts[self._host_of[rank]][1]
 
-    def env_for_rank(self, rank, *, tpu=False, chip_bounds=None):
+    def env_for_rank(self, rank, *, tpu=False, chip_bounds=None,
+                     ports=None):
         """The per-process env for ``rank``: horovod LOCAL_* values,
         plus TPU pod-slice layout when ``tpu`` (one process per chip;
         process grid = hosts x slots-per-host on the ICI mesh).
         ``chip_bounds``: the chip grid of a single host as its slot
         probe saw it (``(2, 2, 1)`` on a four-chip v5e host); a gang
-        that fills the host lays its processes out on that grid."""
+        that fills the host lays its processes out on that grid.
+        ``ports``: one loopback port a rank for a single host's TPU
+        runtimes to find one another on, the same list for every rank
+        (the launcher picks free ones, so a relaunched gang never
+        waits for its predecessor's). Without it — ranks that each
+        compute their own env, as Spark barrier tasks do — the ports
+        are ``TPU_PORT_BASE + rank``. Either way a host runs one
+        multi-rank TPU gang at a time: chips are bound by local rank."""
         if not 0 <= rank < self.total_slots:
             raise ValueError(
                 f"rank {rank} outside gang of {self.total_slots}"
@@ -108,11 +116,8 @@ class Placement:
         }
         if tpu and self.total_slots > 1:
             # One task <-> one chip (reference runner_base.py:44-45,
-            # GPU -> TPU): restrict each worker to its own chip
-            # (TPU_VISIBLE_CHIPS; TPU_VISIBLE_DEVICES is the same
-            # switch under its older name).
+            # GPU -> TPU): restrict each worker to its own chip.
             env["TPU_VISIBLE_CHIPS"] = str(self.local_rank(rank))
-            env["TPU_VISIBLE_DEVICES"] = str(self.local_rank(rank))
             env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
             slots = self.hosts[0][1]
             if len(self.hosts) == 1:
@@ -125,13 +130,16 @@ class Placement:
                 grid = (tuple(chip_bounds) if chip_bounds
                         and math.prod(chip_bounds) == slots
                         else (slots, 1, 1))
+                ports = ports or [TPU_PORT_BASE + r for r in range(slots)]
+                if len(ports) != slots:
+                    raise ValueError(
+                        f"{len(ports)} ports for a gang of {slots}")
                 env.update({
                     "TPU_PROCESS_BOUNDS": ",".join(map(str, grid)),
                     "CLOUD_TPU_TASK_ID": str(rank),
-                    "TPU_PROCESS_PORT": str(TPU_PORT_BASE + rank),
+                    "TPU_PROCESS_PORT": str(ports[rank]),
                     "TPU_PROCESS_ADDRESSES": ",".join(
-                        f"localhost:{TPU_PORT_BASE + r}"
-                        for r in range(slots)),
+                        f"localhost:{port}" for port in ports),
                 })
                 return env
             if any(n != slots for _, n in self.hosts):

@@ -31,7 +31,9 @@ class _HvdState:
         self.local_size = 1
         self.jax_distributed = False
         # rank_of_process[p]: the hvd rank of the process the JAX
-        # runtime numbers p (multi-process gangs; see init)
+        # runtime numbers p (multi-process gangs; see init). Like
+        # jax_distributed it belongs to the jax.distributed client,
+        # which outlives shutdown(): exchanged once, kept after.
         self.rank_of_process = None
 
 
@@ -88,7 +90,8 @@ def init():
                         process_id=rank,
                     )
                 _state.jax_distributed = True
-            _state.rank_of_process = _exchange_ranks(rank, size)
+            if _state.rank_of_process is None:
+                _state.rank_of_process = _exchange_ranks(rank, size)
         _state.rank = rank
         _state.size = size
         _state.initialized = True
@@ -139,7 +142,6 @@ def shutdown():
         _state.size = 1
         _state.local_rank = 0
         _state.local_size = 1
-        _state.rank_of_process = None
 
 
 def require_initialized():

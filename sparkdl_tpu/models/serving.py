@@ -501,21 +501,33 @@ class ContinuousBatchingEngine:
     def _copy_pages_fn(self):
         return self._programs[5]
 
+    def _decode_chunk_call(self, active, n):
+        """``(args, kwargs)`` of one decode-program call over this
+        engine's state: what the decode loop runs and what
+        :meth:`lower_decode_chunk` shows are built here, once."""
+        return (
+            (self.params, self._cache, self._token, self._pos,
+             jnp.asarray(active), self._rng, n),
+            dict(
+                # non-active rows masked to the dump page: a
+                # mid-prefill slot's junk writes must not corrupt the
+                # rows it has already prefilled
+                tables=(jnp.asarray(
+                    np.where(active[:, None], self._tables, 0))
+                        if self.page_size else None),
+                adapter_ids=(jnp.asarray(self._adapter_ids)
+                             if self.cfg.multi_lora else None),
+            ))
 
     def lower_decode_chunk(self, n=None):
         """The ``jax.stages.Lowered`` of this engine's decode program
-        (``n`` tokens a chunk, default ``chunk``) over its own state —
-        for a caller that has to SEE which path the engine compiled:
-        a pallas kernel shows in its text as ``tpu_custom_call``."""
-        return self._decode_chunk_fn.lower(
-            self.params, self._cache, self._token, self._pos,
-            jnp.ones((self.n_slots,), bool), self._rng,
-            int(n or self.chunk),
-            tables=(jnp.asarray(self._tables)
-                    if self.page_size else None),
-            adapter_ids=(jnp.asarray(self._adapter_ids)
-                         if self.cfg.multi_lora else None),
-        )
+        (``n`` tokens a chunk, default ``chunk``, every slot active)
+        over its own state — for a caller that has to SEE which path
+        the engine compiled: a pallas kernel shows in its text as
+        ``tpu_custom_call``."""
+        args, kwargs = self._decode_chunk_call(
+            np.ones((self.n_slots,), bool), int(n or self.chunk))
+        return self._decode_chunk_fn.lower(*args, **kwargs)
 
     def _adapter_arg(self, adapter_id):
         """adapter_ids argument for a batch-1 program call — None on
@@ -905,19 +917,9 @@ class ContinuousBatchingEngine:
             while n < need and n < self.chunk:
                 n *= 2
             n = min(n, self.chunk)
+            args, kwargs = self._decode_chunk_call(active, n)
             (self._cache, self._token, self._pos, self._rng,
-             toks, lps) = self._decode_chunk_fn(
-                self.params, self._cache, self._token, self._pos,
-                jnp.asarray(active), self._rng, n,
-                # non-active rows masked to the dump page: a
-                # mid-prefill slot's junk writes must not corrupt the
-                # rows it has already prefilled
-                tables=(jnp.asarray(
-                    np.where(active[:, None], self._tables, 0))
-                        if self.page_size else None),
-                adapter_ids=(jnp.asarray(self._adapter_ids)
-                             if self.cfg.multi_lora else None),
-            )
+             toks, lps) = self._decode_chunk_fn(*args, **kwargs)
             toks = np.asarray(toks)                 # (n, n_slots)
             lps = np.asarray(lps)
             self.stats["steps"] += n

@@ -30,15 +30,14 @@ HorovodRunner gangs, in two layers:
        compiled = CompiledStepCache().load_or_compile(lowered)
 
 Where the cache lives: ``JAX_COMPILATION_CACHE_DIR``, JAX's own
-variable, decides. When it is set JAX itself reads it, every process
-that inherits the environment uses that directory, and nothing here
-points the cache anywhere else. ``SPARKDL_TPU_COMPILE_CACHE_DIR`` ranks
-below it: it is consulted only when the JAX variable is unset. A
-program that wants a cache with neither set (``chip_smoke.py``,
-``bench.py``) calls :func:`export_cache_dir`, which exports the fixed
-in-checkout :func:`default_cache_dir` under the JAX variable's name —
-the path is part of JAX's cache key, so it is never built from a temp
-name, a uid, a pid or a time.
+variable, and nothing else (:func:`persistent_cache_dir` reads it).
+JAX itself reads it, every process that inherits the environment uses
+that directory, and nothing here points the cache anywhere else;
+unset, warm-start compilation is off. A program that wants a cache
+without being told where (``chip_smoke.py``, ``bench.py``) calls
+:func:`export_cache_dir`, which exports the fixed in-checkout
+``.jax_cache`` under that same name — the path is part of JAX's cache
+key, so it is never built from a temp name, a uid, a pid or a time.
 
 Gang wiring: the launcher passes the driver's environment to every
 worker (local, remote and supervised relaunches alike) and
@@ -71,7 +70,6 @@ import time
 logger = logging.getLogger("HorovodRunner")
 
 JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
-COMPILE_CACHE_DIR_ENV = "SPARKDL_TPU_COMPILE_CACHE_DIR"
 MIN_COMPILE_S_ENV = "SPARKDL_TPU_COMPILE_CACHE_MIN_COMPILE_S"
 MIN_ENTRY_BYTES_ENV = "SPARKDL_TPU_COMPILE_CACHE_MIN_BYTES"
 MAX_AOT_ENTRIES_ENV = "SPARKDL_TPU_COMPILE_CACHE_MAX_AOT"
@@ -102,57 +100,43 @@ _persistent_cache_dir = None  # latched by enable_persistent_cache
 
 
 def persistent_cache_dir(environ=None):
-    """The configured cache root — ``JAX_COMPILATION_CACHE_DIR``, else
-    ``SPARKDL_TPU_COMPILE_CACHE_DIR`` — or None when warm-start
-    compilation is not opted in."""
+    """The cache root, ``JAX_COMPILATION_CACHE_DIR``, or None when
+    warm-start compilation is not opted in."""
     env = os.environ if environ is None else environ
-    return env.get(JAX_CACHE_DIR_ENV) or env.get(COMPILE_CACHE_DIR_ENV) \
-        or None
-
-
-def default_cache_dir():
-    """The fixed in-checkout cache directory (git-ignored) of programs
-    that cache without being told where."""
-    import sparkdl_tpu
-
-    return os.path.join(
-        os.path.dirname(os.path.dirname(
-            os.path.abspath(sparkdl_tpu.__file__))),
-        ".jax_cache")
+    return env.get(JAX_CACHE_DIR_ENV) or None
 
 
 def export_cache_dir(environ=None):
     """Make sure ``JAX_COMPILATION_CACHE_DIR`` is set for this process
     and the children that inherit ``environ``: left alone when set,
-    else :func:`persistent_cache_dir`'s answer or the fixed
-    :func:`default_cache_dir`. Call before ``jax`` is imported — JAX
-    reads the variable once, at import. Returns the directory."""
+    else the fixed, git-ignored ``<checkout>/.jax_cache``. Call before
+    ``jax`` is imported — JAX reads the variable once, at import.
+    Returns the directory."""
     env = os.environ if environ is None else environ
     if not env.get(JAX_CACHE_DIR_ENV):
-        env[JAX_CACHE_DIR_ENV] = os.path.abspath(
-            persistent_cache_dir(env) or default_cache_dir())
+        import sparkdl_tpu
+
+        env[JAX_CACHE_DIR_ENV] = os.path.join(
+            os.path.dirname(os.path.dirname(
+                os.path.abspath(sparkdl_tpu.__file__))),
+            ".jax_cache")
     return env[JAX_CACHE_DIR_ENV]
 
 
-def enable_persistent_cache(cache_dir=None):
-    """Turn on JAX's persistent compilation cache. Where
-    ``JAX_COMPILATION_CACHE_DIR`` is set that directory is the cache
-    and no other is set here; else ``cache_dir`` (default:
-    ``SPARKDL_TPU_COMPILE_CACHE_DIR``). Returns the resolved
-    directory, or None when no directory is configured (no-op — the
-    opt-out path costs one env read).
+def enable_persistent_cache():
+    """Turn on JAX's persistent compilation cache in the directory
+    :func:`persistent_cache_dir` names, with this package's thresholds.
+    Returns that directory, or None when none is configured (no-op —
+    the opt-out path costs one env read). No other directory is ever
+    set: JAX read the variable itself at import, and is told of it
+    here only where the variable was set after that.
 
     Must run before the first compilation to be effective; the gang
-    worker bootstrap calls it before backend init. Idempotent: calling
-    again with the same dir is free, with a different dir re-points
-    the cache (jax re-reads the config at the next compile).
+    worker bootstrap calls it before backend init. Idempotent.
     """
-    from_jax_env = os.environ.get(JAX_CACHE_DIR_ENV)
-    cache_dir = from_jax_env or cache_dir or persistent_cache_dir()
+    cache_dir = persistent_cache_dir()
     if not cache_dir:
         return None
-    if not from_jax_env:
-        cache_dir = os.path.abspath(cache_dir)
     global _persistent_cache_dir
     # The whole degrade contract applies HERE too: this runs at worker
     # bootstrap before the control plane exists, so an unwritable dir
@@ -167,7 +151,7 @@ def enable_persistent_cache(cache_dir=None):
 
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_enable_compilation_cache", True)
-        if not from_jax_env:
+        if jax.config.jax_compilation_cache_dir != cache_dir:
             jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs",
@@ -254,7 +238,7 @@ class CompiledStepCache:
         if not cache_dir:
             raise ValueError(
                 "CompiledStepCache needs a cache directory: pass one or "
-                f"set {COMPILE_CACHE_DIR_ENV}"
+                f"set {JAX_CACHE_DIR_ENV}"
             )
         self.cache_dir = os.path.abspath(cache_dir)
         os.makedirs(self.cache_dir, exist_ok=True)

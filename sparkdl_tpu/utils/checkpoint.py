@@ -7,11 +7,14 @@ Works with sharded (GSPMD) params: orbax restores to the same
 shardings when given an abstract target. Two distributed regimes are
 handled distinctly (see ``TrainCheckpointer.__init__``):
 
-- **HorovodRunner gangs** (``hvd.init()`` called): one jax world where
-  ``process_index == rank`` and state is replicated per rank. Rank 0
-  persists (:func:`should_save`); each rank's manager is pinned
-  process-local so orbax's cross-process barriers don't deadlock when
-  non-primary ranks skip the write.
+- **HorovodRunner gangs** (``hvd.init()`` called): one jax world with
+  state replicated per rank. ``hvd.rank()`` is the launcher's rank and
+  need NOT equal ``jax.process_index()`` (a TPU runtime numbers the
+  processes by where their chips sit), so who writes is decided by the
+  hvd rank alone: rank 0 persists (:func:`should_save`). Each rank's
+  manager is pinned process-local — by its runtime index, which is what
+  orbax knows — so orbax's cross-process barriers don't deadlock when
+  the other ranks skip the write.
 - **Multihost GSPMD pjit jobs** (multi-process world, no hvd gang):
   arrays are sharded across processes, so ALL processes must
   participate in each save; orbax's default cross-process coordination
@@ -53,7 +56,9 @@ class ReshardRestoreError(RuntimeError):
 
 
 def _process_index():
-    """This process's index in the jax world (0 when not distributed)."""
+    """This process's index in the jax world (0 when not distributed).
+    It is what orbax pins a gang rank's manager to, and who writes the
+    sidecar in a GSPMD job; it is never compared with an hvd rank."""
     try:
         import jax
 
@@ -68,6 +73,16 @@ def should_save():
 
     st = _state.state()
     return (not st.initialized) or st.rank == 0
+
+
+def _writes_sidecar():
+    """The one process that writes the files orbax does not: the
+    gang's rank 0 — whatever index the runtime gave it — or process 0
+    of a GSPMD job, where every process saves."""
+    from sparkdl_tpu.hvd import _state
+
+    st = _state.state()
+    return st.rank == 0 if st.initialized else _process_index() == 0
 
 
 def latest_complete_step(directory):
@@ -155,15 +170,15 @@ class TrainCheckpointer:
         first.
 
         Gang semantics: a HorovodRunner gang is one jax world
-        (``hvd.init()`` calls ``jax.distributed.initialize``, so
-        ``process_index == rank``) with state REPLICATED per rank, so
-        each rank's manager is pinned process-local (orbax's
-        cross-process barriers would otherwise deadlock: the
-        non-primary rank skips the write without entering the barrier
-        the primary waits in). Rank 0 persists (:func:`should_save`
-        gates :meth:`save`); any rank may :meth:`restore`, ordered by
-        the caller (``hvd.barrier()`` between a save and a dependent
-        restore).
+        (``hvd.init()`` calls ``jax.distributed.initialize``; the
+        runtime's process index need not be the hvd rank) with state
+        REPLICATED per rank, so each rank's manager is pinned to its
+        own process (orbax's cross-process barriers would otherwise
+        deadlock: the non-primary rank skips the write without
+        entering the barrier the primary waits in). Rank 0 persists
+        (:func:`should_save` gates :meth:`save`); any rank may
+        :meth:`restore`, ordered by the caller (``hvd.barrier()``
+        between a save and a dependent restore).
 
         Multihost GSPMD pjit jobs (multi-process world WITHOUT an hvd
         gang) keep orbax's default cross-process coordination: arrays
@@ -355,7 +370,7 @@ class TrainCheckpointer:
         state = self._gather_cross_process(state)
         if not should_save():
             return False
-        if _process_index() == 0:
+        if _writes_sidecar():
             self._write_sidecar(step, sidecar)
         t0 = time.perf_counter()
         if self._async:
@@ -403,7 +418,7 @@ class TrainCheckpointer:
         write and a dependent read is the caller's barrier. (GSPMD
         jobs write from every process — orbax keeps them in sync.)"""
         mgr = self._mgr  # materialize first (decides the regime)
-        if self._gang and _process_index() != 0:
+        if self._gang and not should_save():
             mgr.reload()
 
     def restore(self, step=None, target=None, *, target_mesh=None,

@@ -204,7 +204,8 @@ def _build():
         k("SPARKDL_TPU_HOSTS", "str", None, "gang",
           "hosts x slots topology spec"),
         k("SPARKDL_TPU_NUM_SLOTS", "int", None, "gang",
-          "task-slot override (bypasses device discovery)"),
+          "task-slot count override (what the slots are is still "
+          "probed)"),
         k("SPARKDL_TPU_SLOT_DIR", "path", None, "gang",
           "slot claim-file registry dir"),
         k("SPARKDL_TPU_SLOT_WAIT_TIMEOUT", "float", "600", "gang",
@@ -434,9 +435,6 @@ def _build():
           "progress (dormant unless set)"),
 
         # -- compile cache ------------------------------------------
-        k("SPARKDL_TPU_COMPILE_CACHE_DIR", "path", None, "compile",
-          "persistent XLA + AOT step cache root (warm starts); ranks "
-          "below JAX_COMPILATION_CACHE_DIR"),
         k("SPARKDL_TPU_COMPILE_CACHE_MAX_AOT", "int", None, "compile",
           "AOT entry count cap"),
         k("SPARKDL_TPU_COMPILE_CACHE_MIN_COMPILE_S", "float", None,

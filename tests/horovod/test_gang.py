@@ -110,8 +110,15 @@ def test_fast_fail_when_worker_dies_during_rendezvous(monkeypatch):
     after the full start timeout) and surface its traceback."""
     import time
 
+    from sparkdl_tpu.horovod import launcher
+
     monkeypatch.setenv("SPARKDL_TPU_WORKER_PLATFORM", "bogus-platform")
     monkeypatch.setenv("SPARKDL_TPU_START_TIMEOUT", "300")
+    # the driver's slot probe would refuse this platform before any
+    # worker starts; this test is about the workers themselves dying
+    monkeypatch.setattr(
+        launcher, "probe_local_devices",
+        lambda platform: launcher.LocalDevices(2, "cpu", None))
     t0 = time.monotonic()
     with pytest.raises(RuntimeError, match="rendezvous"):
         HorovodRunner(np=-2).run(lambda: None)

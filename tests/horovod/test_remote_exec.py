@@ -86,7 +86,7 @@ class TestRemoteCommand:
         env = dict(base)
         env["SPARKDL_TPU_RANK"] = "3"
         env["SPARKDL_TPU_PAYLOAD"] = "/tmp/job/payload-3.pkl"
-        env["TPU_VISIBLE_DEVICES"] = "1"
+        env["TPU_VISIBLE_CHIPS"] = "1"
         cmd = _remote_worker_cmd(
             ["ssh", "-o", "BatchMode=yes"], "hostB", env, base, "python3"
         )
@@ -105,7 +105,7 @@ class TestRemoteCommand:
         # the whole gang-config namespace crosses, including values
         # EQUAL to the driver's env (operator-exported TPU layout)
         assert "TPU_PROCESS_BOUNDS=2,2,1" in pairs
-        assert "TPU_VISIBLE_DEVICES=1" in pairs
+        assert "TPU_VISIBLE_CHIPS=1" in pairs
 
     def test_secret_never_on_the_command_line(self):
         """argv is world-readable in /proc on both machines while the

@@ -10,6 +10,7 @@ import time
 import pytest
 
 from sparkdl_tpu.horovod.launcher import (
+    SlotExhaustionError,
     SlotProbeError,
     available_slots,
     claim_slots,
@@ -87,8 +88,46 @@ def test_corrupt_claim_files_are_ignored(slot_dir):
 def test_probe_failure_surfaces_as_typed_error(monkeypatch):
     monkeypatch.delenv("SPARKDL_TPU_NUM_SLOTS", raising=False)
     monkeypatch.setenv("SPARKDL_TPU_WORKER_PLATFORM", "bogus-platform")
-    with pytest.raises(SlotProbeError, match="bypass"):
+    with pytest.raises(SlotProbeError, match="without this probe"):
         available_slots()
+
+
+@pytest.mark.parametrize("platform,hosts,seen,bound", [
+    (None, None, "tpu", True),     # a chip host that sets nothing
+    ("tpu", None, "tpu", True),
+    (None, None, "cpu", False),    # nothing attached: nothing to bind
+    ("cpu", None, None, False),    # forced to the CPU: never probed
+    (None, "h0:2,h1:2", None, False),  # a hosts spec: not this host's
+])
+def test_chip_binding_follows_what_is_attached(
+        monkeypatch, platform, hosts, seen, bound):
+    """One chip a rank wherever the host's probe saw TPU chips — also
+    under SPARKDL_TPU_NUM_SLOTS, which gives only the count, and in
+    local mode (the caller asks the same question for every mode)."""
+    from sparkdl_tpu.horovod import launcher
+    from sparkdl_tpu.horovod.topology import Placement, parse_hosts
+
+    monkeypatch.setenv("SPARKDL_TPU_NUM_SLOTS", "4")
+    asked = []
+
+    def probe(p):
+        asked.append(p)
+        return launcher.LocalDevices(4, seen, (2, 2, 1))
+
+    monkeypatch.setattr(launcher, "probe_local_devices", probe)
+    spec = Placement(parse_hosts(hosts)) if hosts else None
+    bounds, ports = launcher._local_tpu(platform, spec, 4)
+    assert asked == ([platform] if seen else [])
+    if bound:
+        assert bounds == (2, 2, 1) and len(set(ports)) == 4
+        # two ranks on the four chips: the runtime starts no such
+        # slice (seen on the chip), so it is refused by name
+        with pytest.raises(SlotExhaustionError, match="np=4.*or np=1"):
+            launcher._local_tpu(platform, spec, 2)
+        assert launcher._local_tpu(platform, spec, 1)[0] == (2, 2, 1)
+    else:
+        assert (bounds, ports) == (None, None)
+        assert launcher._local_tpu(platform, spec, 2) == (None, None)
 
 
 @pytest.mark.gang

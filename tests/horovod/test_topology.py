@@ -43,7 +43,8 @@ def test_tpu_pod_env_multi_host():
     p = Placement(parse_hosts("h0:2,h1:2"))
     env = p.env_for_rank(3, tpu=True)
     assert env["SPARKDL_TPU_LOCAL_RANK"] == "1"
-    assert env["TPU_VISIBLE_DEVICES"] == "1"
+    assert env["TPU_VISIBLE_CHIPS"] == "1"
+    assert "TPU_VISIBLE_DEVICES" not in env   # one switch, one name
     assert env["TPU_PROCESS_BOUNDS"] == "4,1,1"
     assert env["CLOUD_TPU_TASK_ID"] == "3"
     # Same-host processes must get distinct ports.
@@ -64,13 +65,29 @@ def test_tpu_single_host_is_one_slice_over_its_chips(chip_bounds, expect):
     the hvd mesh would hold one device."""
     p = Placement.single_host(4)
     env = p.env_for_rank(2, tpu=True, chip_bounds=chip_bounds)
-    assert env["TPU_VISIBLE_DEVICES"] == "2"
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
     assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
     assert env["TPU_PROCESS_BOUNDS"] == expect
     assert env["CLOUD_TPU_TASK_ID"] == "2"
     addrs = env["TPU_PROCESS_ADDRESSES"].split(",")
     assert len(set(addrs)) == 4
     assert addrs[2] == f"localhost:{env['TPU_PROCESS_PORT']}"
+
+
+def test_tpu_single_host_ranks_meet_on_the_launchers_ports():
+    """The launcher hands every rank the same list of free ports: a
+    relaunched gang does not meet its predecessor's runtimes."""
+    from sparkdl_tpu.horovod.launcher import _free_ports
+
+    ports = _free_ports(4)
+    assert len(set(ports)) == 4
+    p = Placement.single_host(4)
+    envs = [p.env_for_rank(r, tpu=True, ports=ports) for r in range(4)]
+    assert [int(e["TPU_PROCESS_PORT"]) for e in envs] == ports
+    assert {e["TPU_PROCESS_ADDRESSES"] for e in envs} == {
+        ",".join(f"localhost:{port}" for port in ports)}
+    with pytest.raises(ValueError, match="ports"):
+        p.env_for_rank(0, tpu=True, ports=ports[:2])
 
 
 def test_tpu_pod_env_requires_uniform_layout():

@@ -31,7 +31,7 @@ def _warm_start_main(ckpt_dir, total_steps):
     """A checkpointed train loop whose jitted step is heavy enough
     that XLA compile time dwarfs deserialize time, served through
     CompiledStepCache. The worker bootstrap already pointed the
-    persistent cache at SPARKDL_TPU_COMPILE_CACHE_DIR; this main uses
+    persistent cache at JAX_COMPILATION_CACHE_DIR; this main uses
     the AOT layer on top, exactly as a production main would."""
     import time
 
@@ -113,7 +113,7 @@ def test_relaunched_gang_warm_starts_from_compile_cache(monkeypatch,
     monkeypatch.setenv(observe.TELEMETRY_DIR_ENV,
                        str(tmp_path / "telemetry"))
     observe._reset_for_tests()
-    monkeypatch.setenv("SPARKDL_TPU_COMPILE_CACHE_DIR",
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                        str(tmp_path / "compile-cache"))
     monkeypatch.setenv("SPARKDL_TPU_GANG_MAX_RETRIES", "2")
     monkeypatch.setenv("SPARKDL_TPU_GANG_BACKOFF_BASE", "0.1")

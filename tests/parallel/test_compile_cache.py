@@ -14,7 +14,6 @@ import optax
 import pytest
 
 from sparkdl_tpu.parallel.compile import (
-    COMPILE_CACHE_DIR_ENV,
     JAX_CACHE_DIR_ENV,
     CompiledStepCache,
     enable_persistent_cache,
@@ -144,14 +143,12 @@ def test_garbage_and_mismatched_entries_degrade(cache, caplog):
     assert cache.misses == 2
 
 
-@pytest.mark.parametrize("jax_var_set", [False, True])
-def test_enable_persistent_cache_points_jax_at_the_dir(tmp_path,
-                                                      monkeypatch,
-                                                      jax_var_set):
-    """``SPARKDL_TPU_COMPILE_CACHE_DIR`` alone: JAX's config is pointed
-    there. With ``JAX_COMPILATION_CACHE_DIR`` set it outranks the
-    repo's variable and no directory is set in code at all (JAX read
-    its own variable at import)."""
+@pytest.mark.parametrize("read_at_import", [False, True])
+def test_enable_persistent_cache_sets_no_other_directory(
+        tmp_path, monkeypatch, read_at_import):
+    """``JAX_COMPILATION_CACHE_DIR`` is the one place the cache lives.
+    Where JAX read it at import, no directory is set in code at all;
+    where the variable came later, JAX is told of that same one."""
     import sparkdl_tpu.parallel.compile as compile_mod
 
     # enable_persistent_cache mutates process-global jax config;
@@ -169,28 +166,29 @@ def test_enable_persistent_cache_points_jax_at_the_dir(tmp_path,
     }
     saved_latch = compile_mod._persistent_cache_dir
     d = str(tmp_path / "xla-cache")
-    monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
-    monkeypatch.setenv(COMPILE_CACHE_DIR_ENV, d)
-    if jax_var_set:
-        monkeypatch.setenv(JAX_CACHE_DIR_ENV, str(tmp_path / "jax-says"))
+    monkeypatch.setenv(JAX_CACHE_DIR_ENV, d)
     try:
-        resolved = enable_persistent_cache()
-        if jax_var_set:
-            assert resolved == str(tmp_path / "jax-says")
-            assert jax.config.jax_compilation_cache_dir == \
-                saved["jax_compilation_cache_dir"]
-        else:
-            assert resolved == d and os.path.isdir(d)
-            assert jax.config.jax_compilation_cache_dir == d
+        if read_at_import:
+            jax.config.update("jax_compilation_cache_dir", d)
+        updated = []
+        update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, value: (updated.append(name),
+                                 update(name, value))[1])
+        assert enable_persistent_cache() == d and os.path.isdir(d)
+        assert ("jax_compilation_cache_dir" in updated) \
+            is (not read_at_import)
+        assert jax.config.jax_compilation_cache_dir == d
         assert jax.config.jax_enable_compilation_cache is True
     finally:
+        monkeypatch.undo()
         for name, value in saved.items():
             jax.config.update(name, value)
         compile_mod._persistent_cache_dir = saved_latch
 
 
 def test_enable_persistent_cache_noop_without_optin(monkeypatch):
-    monkeypatch.delenv(COMPILE_CACHE_DIR_ENV, raising=False)
     monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
     assert enable_persistent_cache() is None
 
@@ -198,7 +196,6 @@ def test_enable_persistent_cache_noop_without_optin(monkeypatch):
 def test_module_level_load_or_compile_without_optin(monkeypatch):
     """Library code calls load_or_compile unconditionally; with no
     cache dir configured it must be a plain cold compile."""
-    monkeypatch.delenv(COMPILE_CACHE_DIR_ENV, raising=False)
     monkeypatch.delenv(JAX_CACHE_DIR_ENV, raising=False)
     lowered, args = _lowered_train_step()
     compiled = load_or_compile(lowered)

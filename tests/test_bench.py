@@ -66,7 +66,7 @@ def test_bench_second_run_warm_starts(tmp_path):
     env = {
         "SPARKDL_TPU_BENCH_PLATFORM": "cpu",
         "SPARKDL_TPU_BENCH_TINY": "1",
-        "SPARKDL_TPU_COMPILE_CACHE_DIR": str(tmp_path / "cc"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc"),
     }
     cold = json.loads(_run(env).stdout.strip().splitlines()[-1])
     warm = json.loads(_run(env).stdout.strip().splitlines()[-1])

@@ -94,40 +94,111 @@ def test_serve_phase_behind_the_http_frontend(capsys, quants):
     for rec in lines[:-1]:
         assert rec["ok"] and rec["requests"] == 4
         assert rec["near_ties"] == []
+        # after every prompt, against every reference (bf16 has two)
+        assert len(rec["logit_checks"]) == (8 if "prompt_lens" in rec else 4)
+        assert all(c["max_logit_diff"] <= c["bound"]
+                   for c in rec["logit_checks"])
 
 
-def test_a_divergence_that_is_no_near_tie_fails():
-    """The comparison accepts unequal tokens only as a near-tie of the
-    reference's logits: a wrong token in an answer must fail it."""
+def test_the_oracles_logits_choose_the_oracles_tokens():
+    """``_oracle_logits`` stands for ``generate()`` where tokens
+    diverge: its argmax after a prefix is the token generate() emits
+    there, and the paged engine's decode program agrees with it."""
     import dataclasses
 
     import numpy as np
 
     from sparkdl_tpu.models import Llama
+    from sparkdl_tpu.models.generate import generate
     from sparkdl_tpu.models.serving import ContinuousBatchingEngine
 
     spec = _tiny()
     cfg = chip_smoke._config(spec, max_cache_len=spec.max_cache_len)
     params = chip_smoke._init_params(cfg, 1)
+    prompt = np.arange(1, 20, dtype=np.int32)
+    want = np.asarray(generate(
+        Llama(cfg), params, prompt[None], max_new_tokens=3))[0, len(prompt):]
     eng = ContinuousBatchingEngine(
         Llama(dataclasses.replace(cfg, paged_kernel="off")), params,
         n_slots=1, page_size=spec.page_size)
+    for i in range(3):
+        prefix = np.concatenate([prompt, want[:i]])
+        oracle = chip_smoke._oracle_logits(cfg, params, prefix)
+        assert int(oracle.argmax()) == int(want[i])
+        np.testing.assert_allclose(
+            chip_smoke._next_logits(eng, prefix), oracle,
+            atol=chip_smoke.LOGIT_TOL * float(np.abs(oracle).max()))
+
+
+def _ref_logits(prefix):
+    """A reference whose two best tokens, 1 and 2, are 0.01 apart at
+    every prefix (largest logit 4: the bound is 0.125)."""
+    import numpy as np
+
+    out = np.zeros(64, np.float32)
+    out[1], out[2] = 4.0, 3.99
+    return out
+
+
+def _shifted(**by_token):
+    import numpy as np
+
+    def logits(prefix):
+        out = _ref_logits(prefix)
+        for token, shift in by_token.items():
+            if token == "all":
+                out += np.float32(shift)
+            else:
+                out[int(token[1:])] += np.float32(shift)
+        return out
+
+    return logits
+
+
+@pytest.mark.parametrize("got,kern_logits,error", [
+    # equal tokens, equal logits
+    ([1, 1, 1], _ref_logits, None),
+    # token 2 for 1 where the kernel's logits turn a 0.01 margin
+    ([1, 2, 1], _shifted(t1=-0.006, t2=0.006), None),
+    # the same flip with logits that cannot explain it
+    ([1, 2, 1], _ref_logits, "no near-tie"),
+    ([1, 2, 1], _shifted(t1=-0.002, t2=0.002), "no near-tie"),
+    # a token the reference holds far below its own
+    ([1, 3, 1], _shifted(t3=0.1), "no near-tie"),
+    # logits off by more than bf16 rounding: no margin is looked at,
+    # whether the tokens differ or not
+    ([1, 2, 1], _shifted(t1=-2.0, t2=2.0), "computes something else"),
+    ([1, 1, 1], _shifted(t5=0.2), "computes something else"),
+    ([1, 1, 1], _shifted(all=float("nan")), "computes something else"),
+])
+def test_only_a_near_tie_of_bf16_accurate_logits_excuses_a_token(
+        got, kern_logits, error):
+    """The comparison holds the side under test to its reference's
+    logits first, to bf16 accuracy over the whole vocabulary, and
+    accepts an unequal token only where that small difference can turn
+    the reference's margin between the two tokens: being more wrong
+    never buys the waiver."""
+    import numpy as np
+
     prompt = np.arange(1, 8, dtype=np.int32)
-    rid = eng.submit(prompt, 4)
-    good = list(eng.run()[rid])
-    assert chip_smoke._compare(
-        "same", [prompt], [good], [good], eng, eng) == []
-    bad = list(good)
-    bad[2] = (bad[2] + 1) % cfg.vocab_size
-    with pytest.raises(RuntimeError, match="no near-tie"):
-        chip_smoke._compare("wrong", [prompt], [bad], [good], eng, eng)
+    args = ("case", [prompt], [got], [[1, 1, 1]], kern_logits, _ref_logits)
+    if error:
+        with pytest.raises(RuntimeError, match=error):
+            chip_smoke._compare(*args)
+        return
+    seen = chip_smoke._compare(*args)
+    assert [c["position"] for c in seen["logit_checks"]] == (
+        [0] if got == [1, 1, 1] else [0, 1])
+    assert [t["tokens"] for t in seen["near_ties"]] == (
+        [] if got == [1, 1, 1] else [[2, 1]])
 
 
 @pytest.mark.gang
 def test_four_chip_phase_gang_against_mesh(monkeypatch, capsys):
     """``--chips 4`` on the CPU: four gloo ranks of one device each
-    (collective values, two steps through ``hvd.grouped_allreduce``)
-    against one process over four of the rig's virtual devices."""
+    (collective values, a checkpoint written by rank 0 and read by all,
+    two steps through ``hvd.grouped_allreduce``) against one process
+    over four of the rig's virtual devices."""
     import jax
 
     from sparkdl_tpu.parallel import mesh as mesh_mod
@@ -138,6 +209,7 @@ def test_four_chip_phase_gang_against_mesh(monkeypatch, capsys):
     chip_smoke.phase_four_chips(_tiny())
     (gang,) = _phase_lines(capsys, "gang")
     assert len(set(gang["device_ids"])) == 4
+    assert sorted(gang["process_index_by_rank"]) == [0, 1, 2, 3]
     assert gang["losses"][1] < gang["losses"][0]
     assert all(n > 0 for n in gang["grad_norms"])
 
@@ -172,16 +244,13 @@ def _cache_dirs(env):
 
 @pytest.mark.parametrize("given", ["/some/dir", None])
 def test_compile_cache_directory_rule(given):
-    """``JAX_COMPILATION_CACHE_DIR`` set: that directory, untouched, even
-    with the repo's own variable set beside it. Unset: one fixed path
-    inside the checkout, the same in two processes — and in both cases
-    it is what JAX itself reads."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               SPARKDL_TPU_COMPILE_CACHE_DIR="")
+    """``JAX_COMPILATION_CACHE_DIR`` set: that directory, untouched.
+    Unset: one fixed path inside the checkout, the same in two
+    processes — and in both cases it is what JAX itself reads."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     if given:
-        env.update(JAX_COMPILATION_CACHE_DIR=given,
-                   SPARKDL_TPU_COMPILE_CACHE_DIR="/ranks/below")
+        env.update(JAX_COMPILATION_CACHE_DIR=given)
     first, second = _cache_dirs(env), _cache_dirs(env)
     expect = given or os.path.join(REPO, ".jax_cache")
     assert first == second == [expect, expect]
