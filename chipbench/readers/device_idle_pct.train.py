@@ -1,0 +1,7 @@
+"""1 - the union of device-operation intervals over the traced steps."""
+
+from chipbench.trace_reduce import idle_pct
+
+
+def read(run):
+    return idle_pct(run["trace"]) if run.get("trace") else None
