@@ -17,7 +17,12 @@ import contextlib
 import io
 import os
 import sys
+import time
 import traceback
+
+# where a worker's boot span starts when the kernel's record of the
+# process start cannot be read (observe.launch.process_start_time)
+_IMPORTED = time.time()
 
 
 class _NullFile:
@@ -98,7 +103,13 @@ def worker_io(rank, local_log_path=None):
     from sparkdl_tpu import observe
     from sparkdl_tpu.horovod.control_plane import get_worker_client
 
-    client = get_worker_client()
+    # Launch record (always on, no thread): the control-plane connect
+    # and, from here on, every compile or cache load JAX reports are
+    # lifecycle spans; they ride to the driver in LAUNCH frames, one
+    # before READY (main) and one before BYE (below).
+    observe.watch_compiles()
+    with observe.span("worker.connect", cat="launch"):
+        client = get_worker_client()
     if client is not None:
         # Fail-fast failure detection in BOTH directions: the launcher
         # reaps dead workers; this reaps workers whose DRIVER died
@@ -210,6 +221,7 @@ def worker_io(rank, local_log_path=None):
                 if flightrec is not None:
                     observe.set_flight_recorder(None)
                     flightrec.close()
+            client.send_launch_spans(observe.launch_record().drain())
             client.send_bye(exit_code)
             client.close()
         local_log.close()
@@ -256,6 +268,14 @@ def main():
 
     enable_persistent_cache()
 
+    from sparkdl_tpu import observe
+    from sparkdl_tpu.observe.launch import process_start_time
+
+    # everything up to here is boot: the interpreter, this package's
+    # imports, JAX's (ensure_jax_platform)
+    boot = process_start_time() or _IMPORTED
+    observe.complete("worker.boot", boot, time.time() - boot, cat="launch")
+
     exit_code = 0
     try:
         # 2. Control plane + log tee (before anything can print).
@@ -279,9 +299,10 @@ def main():
             # driver side — fail-fast if any worker never arrives,
             # reference runner_base.py:54-58).
             if client is not None:
+                # the spans so far first: the driver holds this rank's
+                # boot, connect and hvd.init when it counts it ready
+                client.send_launch_spans(observe.launch_record().drain())
                 client.send_ready()
-            from sparkdl_tpu import observe
-
             observe.instant("worker.ready", cat="worker", rank=rank)
             if observe.enabled():
                 # Build-info correlation (ISSUE 14 satellite): stamp
@@ -306,7 +327,8 @@ def main():
             else:
                 with open(payload_path, "rb") as f:
                     user_main, kwargs = cloudpickle.load(f)
-            with maybe_trace_worker(rank):
+            with maybe_trace_worker(rank), \
+                    observe.span("worker.job", cat="launch"):
                 result = user_main(**kwargs)
 
             # 6. Rank 0's return value goes back to the driver.

@@ -48,6 +48,7 @@ MSG_DUMP_REQ = 12     # driver→worker: send an all-thread stack dump
 MSG_STACK_DUMP = 13   # worker→driver: the faulthandler dump text
 MSG_PROFILE_REQ = 14  # driver→worker: capture a perf-forensics window
 MSG_PROFILE_DONE = 15  # worker→driver: capture finished (report meta)
+MSG_LAUNCH = 16       # worker→driver: its launch-record spans (always on)
 
 _HEADER = struct.Struct(">IBI")  # length (of type+rank+payload), type, rank
 
@@ -59,7 +60,7 @@ _MSG_NAMES = {
     MSG_AUTH: "AUTH", MSG_RESULT_PART: "RESULT", MSG_RESULT_END: "RESULT",
     MSG_TELEMETRY: "TELEMETRY", MSG_HEARTBEAT: "HEARTBEAT",
     MSG_STACK_DUMP: "STACK_DUMP", MSG_PROFILE_REQ: "PROFILE_REQ",
-    MSG_PROFILE_DONE: "PROFILE_DONE",
+    MSG_PROFILE_DONE: "PROFILE_DONE", MSG_LAUNCH: "LAUNCH",
 }
 
 CONTROL_ADDR_ENV = "SPARKDL_TPU_CONTROL_ADDR"
@@ -150,9 +151,14 @@ class ControlPlaneServer:
 
     def __init__(self, num_workers, verbosity="log_callback_only", log_path=None,
                  bind_host="127.0.0.1", advertise_host=None, secret=None,
-                 telemetry=None, health=None):
+                 telemetry=None, health=None, on_launch_spans=None):
         self.num_workers = num_workers
         self.verbosity = verbosity
+        # Where LAUNCH frames go: callable(rank, [span dict, ...]) —
+        # the launcher hands each worker's lifecycle spans to the
+        # driver's launch record (sparkdl_tpu.observe.launch), which
+        # shape-checks them; without one they are dropped.
+        self._on_launch_spans = on_launch_spans
         # Optional observability sink (sparkdl_tpu.observe.aggregate.
         # GangTelemetry): TELEMETRY frames are decoded and handed to
         # it; without one they are dropped (telemetry is opt-in).
@@ -390,6 +396,10 @@ class ControlPlaneServer:
                 self._telemetry.ingest(
                     rank, json.loads(payload.decode("utf-8", "replace"))
                 )
+        elif mtype == MSG_LAUNCH:
+            if self._on_launch_spans is not None:
+                self._on_launch_spans(
+                    rank, json.loads(payload.decode("utf-8", "replace")))
         elif mtype == MSG_HEARTBEAT:
             if self._health is not None:
                 self._health.observe_beat(
@@ -681,6 +691,15 @@ class ControlPlaneClient:
         # on an interval, so volume stays bounded regardless of how
         # hot the instrumented paths run.
         self._send_json(MSG_TELEMETRY, payload_obj)
+
+    def send_launch_spans(self, spans):
+        # The worker's launch-record spans (boot, connect, hvd.init,
+        # the job, compiles): a few dozen small dicts, sent once
+        # before READY and once before BYE on the guaranteed socket,
+        # so that the driver holds them when it counts this rank in.
+        if spans:
+            self._send(MSG_LAUNCH,
+                       json.dumps(spans, default=str).encode("utf-8"))
 
     def send_heartbeat(self, payload_obj):
         # Gang-health beacon (sparkdl_tpu.observe.health): tiny JSON at

@@ -113,7 +113,6 @@ _NO_PROBE_HINT = (f"(only {WORKER_PLATFORM_ENV}=cpu, a gang on CPU "
                   "devices, is launched without this probe)")
 
 
-@functools.lru_cache(maxsize=None)
 def probe_local_devices(platform):
     """Count local accelerator devices WITHOUT initializing a backend in
     the driver process (a driver that claims the TPU would starve its
@@ -123,8 +122,24 @@ def probe_local_devices(platform):
     has exited — ``subprocess.run`` reaps it — before any worker is
     spawned. Cached: a host's devices do not change under a driver,
     and a supervised relaunch need not pay the child's backend
-    start-up again (failures are not cached)."""
-    if platform == "cpu":
+    start-up again (failures are not cached).
+
+    Every call is a ``gang.slot_probe`` launch span: the child's
+    lifetime (one reach of the chip), or zero-length with
+    ``cached=True`` when the cache answered."""
+    from sparkdl_tpu import observe
+
+    before = _probe_local_devices.cache_info().misses
+    with observe.span("gang.slot_probe", cat="launch") as probe:
+        found = _probe_local_devices(platform)
+        probe.args["cached"] = (
+            _probe_local_devices.cache_info().misses == before)
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _probe_local_devices(platform):
+    if platform == "cpu":   # a gang on CPU devices: no child to ask
         return LocalDevices(os.cpu_count() or 1, "cpu", None)
     code = (
         "import jax\n"
@@ -656,12 +671,7 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                       per_rank_kwargs=None, extra_env=None,
                       telemetry=None, alert_engine=None,
                       controller=None, forensics=None):
-    import cloudpickle
-
     from sparkdl_tpu import observe
-    from sparkdl_tpu.horovod.control_plane import ControlPlaneServer
-    from sparkdl_tpu.horovod.supervisor import GangFailure
-    from sparkdl_tpu.horovod.topology import Placement, is_local_host
 
     if np == 0:
         # warned HERE, once, whichever backend ends up hosting the gang
@@ -696,6 +706,32 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
             )
             if spark_result is not None:
                 return spark_result.value
+
+    # One launch of the always-on launch record (observe.launch): this
+    # spawn's lifecycle spans, the driver's and (over the control
+    # plane) each worker's, share its id. Opened before the first slot
+    # probe, closed with the attempt; a supervised relaunch is another.
+    launch_id = observe.launch_record().open()
+    try:
+        return _launch_process_gang(
+            np, main, kwargs, driver_log_verbosity, per_rank_kwargs,
+            extra_env, telemetry, alert_engine, controller, forensics,
+            launch_id)
+    finally:
+        observe.launch_record().close(launch_id)
+
+
+def _launch_process_gang(np, main, kwargs, driver_log_verbosity,
+                         per_rank_kwargs, extra_env, telemetry,
+                         alert_engine, controller, forensics, launch_id):
+    """The local process gang of :func:`_launch_gang_once` (every
+    backend but Spark's barrier job), as launch `launch_id`."""
+    import cloudpickle
+
+    from sparkdl_tpu import observe
+    from sparkdl_tpu.horovod.control_plane import ControlPlaneServer
+    from sparkdl_tpu.horovod.supervisor import GangFailure
+    from sparkdl_tpu.horovod.topology import Placement, is_local_host
 
     spec_placement = Placement.from_env(os.environ)
     num_workers, mode, total_slots = _resolve_num_workers(np, spec_placement)
@@ -889,6 +925,8 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
             bind_host="0.0.0.0" if remote_hosts else "127.0.0.1",
             telemetry=telemetry,
             health=detector,
+            on_launch_spans=functools.partial(
+                observe.launch_record().ingest, launch_id),
         )
         if forensics is not None:
             # PROFILE_REQ frames go out through THIS attempt's control
@@ -941,10 +979,7 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                 "warm-start compile cache for this gang: %s",
                 compile_cache,
             )
-        observe.instant("gang.spawn", cat="launch",
-                        num_workers=num_workers, mode=mode,
-                        job_dir=job_dir,
-                        compile_cache=compile_cache or "")
+        spawn_started = time.time()
         # Autotuned perf profile pre-flight (ISSUE 12): resolve the
         # committed per-device-kind profile and ship its knobs in
         # every worker env, UNDER the operator (an env var already set
@@ -1020,6 +1055,12 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                         stderr=subprocess.STDOUT,
                     ))
 
+        # first Popen to last: each rank's worker.boot starts inside
+        observe.complete(
+            "gang.spawn", spawn_started, time.time() - spawn_started,
+            cat="launch", num_workers=num_workers, mode=mode,
+            job_dir=job_dir, compile_cache=compile_cache or "")
+
         # The spawned children hold their own fds on the boot streams:
         # unlink the secret-bearing files NOW, before the (possibly
         # hours-long) job runs — the finally's unlink is only the
@@ -1090,6 +1131,9 @@ def _launch_gang_once(np, main, kwargs, driver_log_verbosity,
                 time.sleep(0.05)
         observe.instant("gang.ready", cat="launch",
                         num_workers=num_workers)
+        from sparkdl_tpu.observe.launch import summary_line
+
+        logger.info("%s", summary_line(observe.launch_report(launch_id)))
 
         # Monitor the running gang. If one rank dies while others are
         # blocked in a collective (which has no timeout on ICI), give the

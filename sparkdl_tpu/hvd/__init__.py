@@ -177,10 +177,20 @@ def grouped_allreduce(tensors, average=None, name=None, op=None):
     (Horovod tensor-fusion semantics) instead of one per tensor.
 
     All-jax input lists stay on device: the concat/split bookkeeping
-    runs as XLA ops and the collective takes the zero-host-copy path."""
+    runs as XLA ops and the collective takes the zero-host-copy path.
+
+    One span over the whole call (``sparkdl.grouped_allreduce`` in a
+    profiler trace): between a gradient program and an update program
+    it names the gap the device waits out."""
     del name
     _state.require_initialized()
-    kind = _resolve_op(average, op)
+    from sparkdl_tpu import observe
+
+    with observe.span("grouped_allreduce", cat="collective"):
+        return _grouped_allreduce(tensors, _resolve_op(average, op))
+
+
+def _grouped_allreduce(tensors, kind):
     if tensors and all(_concrete_single_device_jax(t) for t in tensors):
         import jax.numpy as jnp
 

@@ -35,17 +35,20 @@ def _observed(op_name):
     """Per-collective telemetry: op count, payload bytes, a wall-time
     histogram under ``op=<name>`` labels (the engine-level view an
     allreduce slowdown shows up in first), and a ``cat="collective"``
-    timeline span — the raw material ``observe.perf`` attributes step
-    time from (a span on the step's own thread is serialized collective
-    time; one on another thread is overlapped with compute). The hot
-    path pays one cached-boolean check when telemetry is off — the
-    decorator never touches the argument otherwise."""
+    span — the raw material ``observe.perf`` attributes step time
+    from (a span on the step's own thread is serialized collective
+    time; one on another thread is overlapped with compute). With
+    telemetry off the span is only its annotation on the profiler's
+    clock (``sparkdl.<op>``: a host collective BETWEEN two device
+    programs shows beside the gap it makes) and the decorator never
+    touches the argument."""
 
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(self, x, *args, **kwargs):
             if not observe.enabled():
-                return fn(self, x, *args, **kwargs)
+                with observe.span(op_name, cat="collective"):
+                    return fn(self, x, *args, **kwargs)
             from sparkdl_tpu.observe import health
 
             # Gang-health markers: the ENTRY records "last entered
@@ -55,17 +58,16 @@ def _observed(op_name):
             # looping fast on tiny collectives still reads as live.
             health.note_collective(op_name)
             nbytes = int(getattr(x, "nbytes", 0) or 0)
-            wall0 = time.time()
             t0 = time.perf_counter()
-            out = fn(self, x, *args, **kwargs)
+            with observe.span(op_name, cat="collective", op=op_name,
+                              bytes=nbytes):
+                out = fn(self, x, *args, **kwargs)
             dt = time.perf_counter() - t0
             health.note_collective(op_name, done=True)
             observe.inc("collective_ops_total", op=op_name)
             observe.inc("collective_bytes_total", value=nbytes,
                         op=op_name)
             observe.observe_value("collective_seconds", dt, op=op_name)
-            observe.complete(op_name, wall0, dt, cat="collective",
-                             op=op_name, bytes=nbytes)
             return out
 
         return wrapper
@@ -182,7 +184,11 @@ class _CollectiveEngine:
         finish = start()
         pool = self._ensure_async_pool()
         if not observe.enabled():
-            return AsyncCollective(pool.submit(finish), op_name)
+            def finish_spanned():
+                with observe.span(op_name, cat="collective"):
+                    return finish()
+
+            return AsyncCollective(pool.submit(finish_spanned), op_name)
         from sparkdl_tpu.observe import health
 
         def finish_observed():
@@ -190,17 +196,16 @@ class _CollectiveEngine:
             # for the hang detector, per-op metrics, and the timeline
             # span perf.py attributes as overlapped collective time.
             health.note_collective(op_name)
-            wall0 = time.time()
             t0 = time.perf_counter()
-            out = finish()
+            with observe.span(op_name, cat="collective", op=op_name,
+                              bytes=int(nbytes)):
+                out = finish()
             dt = time.perf_counter() - t0
             health.note_collective(op_name, done=True)
             observe.inc("collective_ops_total", op=op_name)
             observe.inc("collective_bytes_total", value=int(nbytes),
                         op=op_name)
             observe.observe_value("collective_seconds", dt, op=op_name)
-            observe.complete(op_name, wall0, dt, cat="collective",
-                             op=op_name, bytes=int(nbytes))
             return out
 
         return AsyncCollective(pool.submit(finish_observed), op_name)

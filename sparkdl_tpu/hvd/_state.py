@@ -70,31 +70,41 @@ def init():
     MPI rendezvous, per the north star in BASELINE.json)."""
     import jax
 
+    from sparkdl_tpu import observe
+
     with _state.lock:
         if _state.initialized:
             return
-        size = int(os.environ.get(SIZE_ENV, "1"))
-        rank = int(os.environ.get(RANK_ENV, "0"))
-        _state.local_rank = int(os.environ.get(LOCAL_RANK_ENV, str(rank)))
-        _state.local_size = int(os.environ.get(LOCAL_SIZE_ENV, str(size)))
-        coord = os.environ.get(COORD_ENV)
-        if size > 1 and coord:
-            ensure_jax_platform()
-            if not _state.jax_distributed:
-                from jax._src import distributed as _jd
+        # a launch span: for a gang it holds jax.distributed.initialize
+        # and the rank exchange (where the runtimes of a TPU slice
+        # come up); a repeated init() returned above and records none
+        with observe.span("hvd.init", cat="launch"):
+            _init_locked(jax)
 
-                if _jd.global_state.client is None:
-                    jax.distributed.initialize(
-                        coordinator_address=coord,
-                        num_processes=size,
-                        process_id=rank,
-                    )
-                _state.jax_distributed = True
-            if _state.rank_of_process is None:
-                _state.rank_of_process = _exchange_ranks(rank, size)
-        _state.rank = rank
-        _state.size = size
-        _state.initialized = True
+
+def _init_locked(jax):
+    size = int(os.environ.get(SIZE_ENV, "1"))
+    rank = int(os.environ.get(RANK_ENV, "0"))
+    _state.local_rank = int(os.environ.get(LOCAL_RANK_ENV, str(rank)))
+    _state.local_size = int(os.environ.get(LOCAL_SIZE_ENV, str(size)))
+    coord = os.environ.get(COORD_ENV)
+    if size > 1 and coord:
+        ensure_jax_platform()
+        if not _state.jax_distributed:
+            from jax._src import distributed as _jd
+
+            if _jd.global_state.client is None:
+                jax.distributed.initialize(
+                    coordinator_address=coord,
+                    num_processes=size,
+                    process_id=rank,
+                )
+            _state.jax_distributed = True
+        if _state.rank_of_process is None:
+            _state.rank_of_process = _exchange_ranks(rank, size)
+    _state.rank = rank
+    _state.size = size
+    _state.initialized = True
 
 
 def _exchange_ranks(rank, size):
@@ -114,10 +124,17 @@ def _exchange_ranks(rank, size):
     import jax
     from jax._src import distributed as _jd
 
-    if jax.process_count() != size:
+    from sparkdl_tpu import observe
+
+    # the first question asked of the devices starts the backend: in
+    # a gang this is each rank's reach of its chip. (JAX reports no
+    # backend start of its own; a single worker's falls in its job.)
+    with observe.span("worker.backend", cat="launch"):
+        processes = jax.process_count()
+    if processes != size:
         raise RuntimeError(
             f"rank {rank}: the JAX runtime reports "
-            f"{jax.process_count()} process(es) with "
+            f"{processes} process(es) with "
             f"{len(jax.devices())} device(s) for a gang of {size}; "
             "the workers' runtimes did not join one another")
     client = _jd.global_state.client

@@ -509,11 +509,16 @@ class Block(nn.Module):
     def __call__(self, x, cos, sin, positions, block_tables=None,
                  adapter_ids=None):
         cfg = self.cfg
-        h = x + Attention(cfg, self.attention_fn,
-                          self.paged_attention_fn, name="attn")(
-            RMSNorm(cfg.rms_eps, name="attn_norm")(x), cos, sin, positions,
-            block_tables=block_tables, adapter_ids=adapter_ids,
-        )
+        # Fixed scope names (not flax paths, no layer index): what the
+        # device trace is grouped by. Backward and remat show in JAX's
+        # own name stack around them (transpose(jvp(sparkdl.attn))).
+        with jax.named_scope("sparkdl.attn"):
+            h = x + Attention(cfg, self.attention_fn,
+                              self.paged_attention_fn, name="attn")(
+                RMSNorm(cfg.rms_eps, name="attn_norm")(x), cos, sin,
+                positions, block_tables=block_tables,
+                adapter_ids=adapter_ids,
+            )
         if self.use_moe:
             from sparkdl_tpu.models.moe import MoEConfig, MoEMLP
 
@@ -525,7 +530,9 @@ class Block(nn.Module):
             )
         else:
             mlp = MLP(cfg, name="mlp")
-        return h + mlp(RMSNorm(cfg.rms_eps, name="mlp_norm")(h))
+        with jax.named_scope(
+                "sparkdl.moe" if self.use_moe else "sparkdl.mlp"):
+            return h + mlp(RMSNorm(cfg.rms_eps, name="mlp_norm")(h))
 
 
 class Llama(nn.Module):
@@ -571,24 +578,25 @@ class Llama(nn.Module):
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         if return_hidden:
             return x
-        if cfg.quant:
-            from sparkdl_tpu.models.quant import QuantDense, QuantDense4
+        with jax.named_scope("sparkdl.lm_head_loss"):
+            if cfg.quant:
+                from sparkdl_tpu.models.quant import QuantDense, QuantDense4
 
-            if cfg.quant == "int4":
-                return QuantDense4(cfg.vocab_size, dtype=jnp.float32,
-                                   group=cfg.quant_group,
-                                   kernel=cfg.quant_kernel,
-                                   name="lm_head")(
-                    x.astype(jnp.float32))
-            return QuantDense(cfg.vocab_size, dtype=jnp.float32,
-                              kernel=cfg.quant_kernel,
-                              name="lm_head")(x.astype(jnp.float32))
-        # fp32 head: stability for the softmax/sampling path. (A bf16
-        # head was measured on v5e and did NOT beat this — XLA already
-        # runs the fp32 matmul as bf16x3 passes and the extra output
-        # cast costs more than the passes save at d_model 1024.)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=jnp.float32, name="lm_head")(
-            x.astype(jnp.float32)
-        )
-        return logits
+                if cfg.quant == "int4":
+                    return QuantDense4(cfg.vocab_size, dtype=jnp.float32,
+                                       group=cfg.quant_group,
+                                       kernel=cfg.quant_kernel,
+                                       name="lm_head")(
+                        x.astype(jnp.float32))
+                return QuantDense(cfg.vocab_size, dtype=jnp.float32,
+                                  kernel=cfg.quant_kernel,
+                                  name="lm_head")(x.astype(jnp.float32))
+            # fp32 head: stability for the softmax/sampling path. (A bf16
+            # head was measured on v5e and did NOT beat this — XLA already
+            # runs the fp32 matmul as bf16x3 passes and the extra output
+            # cast costs more than the passes save at d_model 1024.)
+            logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                              dtype=jnp.float32, name="lm_head")(
+                x.astype(jnp.float32)
+            )
+            return logits

@@ -36,8 +36,9 @@ class LoRADense(nn.Module):
             "lora_b", nn.initializers.zeros, (self.rank, self.features)
         ).astype(self.dtype)
         base = x @ kernel
-        delta = (x @ lora_a) @ lora_b
-        return base + (self.alpha / self.rank) * delta
+        with jax.named_scope("sparkdl.lora"):
+            delta = (self.alpha / self.rank) * ((x @ lora_a) @ lora_b)
+        return base + delta
 
 
 class MultiLoRADense(nn.Module):
@@ -69,11 +70,13 @@ class MultiLoRADense(nn.Module):
         ).astype(self.dtype)
         base = x @ kernel
         # gather each row's adapter, then two skinny batched matmuls
-        a_sel = lora_a[ids]                       # (b, d_in, r)
-        b_sel = lora_b[ids]                       # (b, r, f)
-        delta = jnp.einsum("bsd,bdr->bsr", x, a_sel)
-        delta = jnp.einsum("bsr,brf->bsf", delta, b_sel)
-        return base + (self.alpha / self.rank) * delta
+        with jax.named_scope("sparkdl.lora"):
+            a_sel = lora_a[ids]                       # (b, d_in, r)
+            b_sel = lora_b[ids]                       # (b, r, f)
+            delta = jnp.einsum("bsd,bdr->bsr", x, a_sel)
+            delta = jnp.einsum("bsr,brf->bsf", delta, b_sel)
+            delta = (self.alpha / self.rank) * delta
+        return base + delta
 
 
 def stack_lora_adapters(param_trees):

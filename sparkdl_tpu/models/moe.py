@@ -128,6 +128,11 @@ def moe_apply(x, gates, w_gate, w_up, w_down, axis_name=None):
     """Gate-weighted expert combine. With ``axis_name`` (under
     shard_map), the stacked expert weights hold only LOCAL experts and
     partial outputs are psum'd over the expert axis."""
+    with jax.named_scope("sparkdl.moe"):
+        return _moe_apply(x, gates, w_gate, w_up, w_down, axis_name)
+
+
+def _moe_apply(x, gates, w_gate, w_up, w_down, axis_name):
     h_gate = jnp.einsum("...d,edf->e...f", x, w_gate)
     h_up = jnp.einsum("...d,edf->e...f", x, w_up)
     h = nn.silu(h_gate) * h_up

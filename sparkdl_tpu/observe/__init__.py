@@ -13,13 +13,22 @@ signal you can alert on, not log lines). Three pieces:
 
 This module is the instrumentation facade the rest of the package
 calls. **Off by default**: unless ``SPARKDL_TPU_TELEMETRY_DIR`` is set
-(latched at first use, like the chaos harness), every helper here is a
-no-op behind one cached boolean — production gangs that didn't opt in
-pay a single ``if`` per call site and allocate nothing. The
+(latched at first use, like the chaos harness), the metric and
+timeline helpers here record nothing behind one cached boolean. The
 :class:`~sparkdl_tpu.observe.metrics.Registry` class itself is always
 live when instantiated explicitly (the serving frontend's ``/metrics``
 endpoint owns one; its request metrics are part of its API, not
 gang telemetry).
+
+Two things do not wait for that latch. :func:`span` is the one way
+the program marks a host interval, and wherever JAX is already
+imported it also enters ``jax.profiler.TraceAnnotation("sparkdl." +
+name)``, so the interval shows on the profiler's clock beside the
+device's operations whenever a profiler session is open (and costs a
+no-op inside JAX when none is). And the lifecycle spans of a gang
+launch (``cat="launch"``: a few dozen per job, none inside a step) go
+into a bounded in-memory record (:mod:`~sparkdl_tpu.observe.launch`),
+read back with :func:`launch_report`.
 
 Worker→driver transport: inside a gang worker, the worker bootstrap
 registers the control-plane client as the telemetry *sink*
@@ -38,8 +47,11 @@ See ``docs/observability.rst`` for the metric catalog and env knobs.
 import itertools
 import os
 import socket
+import sys
 import threading
+import time
 
+from sparkdl_tpu.observe import launch as _launch
 from sparkdl_tpu.observe.metrics import Registry
 from sparkdl_tpu.observe.timeline import Timeline
 
@@ -53,8 +65,12 @@ __all__ = [
     "instant", "complete",
     "set_sink", "flush", "start_flusher", "stop_flusher",
     "snapshot_payload", "new_run_dir", "Registry", "Timeline",
-    "set_flight_recorder",
+    "set_flight_recorder", "launch_record", "launch_report",
+    "watch_compiles", "TRACE_PREFIX",
 ]
+
+# every span's name in the profiler's trace (xprof) starts with this
+TRACE_PREFIX = "sparkdl."
 
 # Latched like the chaos harness: gangs ship env at spawn, so one
 # check at first call suffices and the disabled path stays a single
@@ -63,6 +79,9 @@ _enabled = None
 
 _registry = Registry()
 _timeline = Timeline()
+_launches = _launch.LaunchRecord()
+_open_spans = threading.local()    # .stack: this thread's open _Span
+_compile_watch = None              # (event, duration) listeners, once
 _sink = None                       # callable(payload_dict) or None
 _sink_lock = threading.Lock()      # serializes flush() payloads
 _flusher = None
@@ -123,8 +142,8 @@ def observe_value(name, value, buckets=None, **labels):
 
 
 class _NoopSpan:
-    """Shared do-nothing context manager: the disabled `span()` path
-    allocates nothing (the zero-overhead contract's visible half)."""
+    """Shared do-nothing context manager: what :func:`span` returns
+    with telemetry off in a process that has not imported JAX."""
 
     __slots__ = ()
 
@@ -138,10 +157,90 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
-def span(name, cat="", **args):
-    if not enabled():
-        return _NOOP_SPAN
-    return _timeline.span(name, cat=cat, **args)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where JAX is ALREADY imported
+    in this process, else None. Never an import of its own: the driver
+    stays JAX-free (a driver that touched the TPU would starve its own
+    workers)."""
+    return getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+
+
+def _stack():
+    try:
+        return _open_spans.stack
+    except AttributeError:
+        _open_spans.stack = stack = []
+        return stack
+
+
+def _record(name, cat, start, dur, cause, ident, args):
+    """One finished span, to whatever keeps it: launch spans always,
+    to the launch record; everything, to the timeline, under the
+    telemetry latch."""
+    if cat == _launch.CAT:
+        _launches.add(name, start, start + dur, cause=cause, **args)
+    if enabled():
+        links = {k: v for k, v in (("cause", cause), ("ident", ident))
+                 if v is not None}
+        _timeline.complete(name, start, dur, cat=cat, **args, **links)
+
+
+class _Span:
+    """A recorded span: name, start, end, the span that caused it (the
+    enclosing recorded span on this thread unless ``cause=`` names
+    one) and the identifier the spans of one launch or one step share
+    (``ident=``, inherited from the enclosing span). Lives as long as
+    its ``with`` block."""
+
+    __slots__ = ("name", "cat", "cause", "ident", "args",
+                 "_annotation", "_wall", "_perf")
+
+    def __init__(self, name, cat, cause, ident, args):
+        self.name, self.cat, self.args = name, cat, args
+        self.cause, self.ident = cause, ident
+        self._annotation = None
+
+    def __enter__(self):
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._annotation = annotation(TRACE_PREFIX + self.name)
+            self._annotation.__enter__()
+        stack = _stack()
+        if stack:
+            if self.cause is None:
+                self.cause = stack[-1].name
+            if self.ident is None:
+                self.ident = stack[-1].ident
+        stack.append(self)
+        self._wall, self._perf = time.time(), time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self._perf
+        _stack().remove(self)
+        _record(self.name, self.cat, self._wall, dur, self.cause,
+                self.ident, self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+
+def span(name, cat="", cause=None, ident=None, **args):
+    """THE way the program marks a host interval: ``with
+    observe.span("checkpoint.save", cat="checkpoint", step=3):``.
+
+    On the profiler's clock always: where JAX is already imported the
+    block runs under ``TraceAnnotation("sparkdl.<name>")``, whether or
+    not telemetry is on. With telemetry off (and ``cat`` not
+    ``"launch"``) that annotation is all it does: nothing is recorded
+    and nothing outlives the call. With telemetry on the span lands in
+    the gang timeline with its cause and identifier (:class:`_Span`);
+    ``cat="launch"`` spans land in the launch record regardless."""
+    if cat != _launch.CAT and not enabled():
+        annotation = _trace_annotation()
+        return (_NOOP_SPAN if annotation is None
+                else annotation(TRACE_PREFIX + name))
+    return _Span(name, cat, cause, ident, args)
 
 
 def host_span(name, **args):
@@ -151,12 +250,22 @@ def host_span(name, **args):
     ``host_callback`` component of the ``observe.perf`` step
     attribution — wrap the Python body of a callback (or any host
     detour inside the step window) and the time lands there instead
-    of being misread as compute. No-op (shared singleton) with
-    telemetry off, like :func:`span`."""
+    of being misread as compute."""
     return span(name, cat="host", **args)
 
 
+def _enclosing():
+    """Name of the recorded span open on this thread, if any."""
+    stack = _stack()
+    return stack[-1].name if stack else None
+
+
 def instant(name, cat="", **args):
+    """A point event; of ``cat="launch"`` a zero-length span of the
+    launch record too."""
+    if cat == _launch.CAT:
+        now = time.time()
+        _launches.add(name, now, now, cause=_enclosing(), **args)
     if enabled():
         _timeline.instant(name, cat=cat, **args)
 
@@ -164,10 +273,73 @@ def instant(name, cat="", **args):
 def complete(name, start, dur, cat="", tid=None, **args):
     """Record a complete event with explicit wall-clock start and
     duration (seconds) — for blocks whose endpoints the caller already
-    timed (the collective wrappers measure with ``perf_counter`` and
-    report here once)."""
+    timed (a worker's boot, JAX's own report of a compile)."""
+    if cat == _launch.CAT:
+        _launches.add(name, start, start + dur, cause=_enclosing(), **args)
     if enabled():
         _timeline.complete(name, start, dur, cat=cat, tid=tid, **args)
+
+
+# -- the launch record (always on) -------------------------------------------
+
+
+def launch_record():
+    """This process's :class:`~sparkdl_tpu.observe.launch.LaunchRecord`."""
+    return _launches
+
+
+def launch_report(launch_id=None):
+    """The last launch's spans (or `launch_id`'s) as plain dicts,
+    sorted by start: the driver's own (``gang.slot_probe``,
+    ``gang.slot_claim``, ``gang.spawn``, ``gang.rendezvous``,
+    ``gang.ready``) and, per rank, the workers' (``worker.boot``,
+    ``worker.connect``, ``hvd.init``, ``worker.job``, ``xla.compile``),
+    each with ``launch_id``, ``rank``, ``cause``, ``start``, ``end``.
+    Call it after ``HorovodRunner.run()`` returns."""
+    return _launches.report(launch_id)
+
+
+def watch_compiles():
+    """Record every backend compile, or load from the persistent
+    cache, that JAX reports in this process as an ``xla.compile``
+    launch span: when, how long, which program (``program``) and,
+    where JAX says so, whether the persistent cache answered
+    (``cache``: ``"hit"`` or ``"miss"``). Registers two
+    ``jax.monitoring`` listeners, once; a no-op returning False where
+    JAX is not imported yet."""
+    global _compile_watch
+    jax = sys.modules.get("jax")
+    if jax is None or _compile_watch is not None:
+        return _compile_watch is not None
+    verdicts = {"/jax/compilation_cache/cache_hits": "hit",
+                "/jax/compilation_cache/cache_misses": "miss"}
+    seen = threading.local()    # the verdict precedes the duration
+
+    def on_event(event, **_):
+        if event in verdicts:
+            seen.cache = verdicts[event]
+
+    def on_duration(event, duration, fun_name=None, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            complete("xla.compile", time.time() - duration, duration,
+                     cat=_launch.CAT, program=fun_name,
+                     cache=getattr(seen, "cache", None))
+            seen.cache = None
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    _compile_watch = (on_event, on_duration)
+    return True
+
+
+def _unwatch_compiles():
+    global _compile_watch
+    if _compile_watch is not None:
+        from jax._src import monitoring
+
+        monitoring.unregister_event_listener(_compile_watch[0])
+        monitoring.unregister_event_duration_listener(_compile_watch[1])
+        _compile_watch = None
 
 
 # -- worker flush machinery --------------------------------------------------
@@ -264,14 +436,16 @@ def stop_flusher():
 
 
 def _reset_for_tests():
-    """Fresh state: re-latch the enabled flag, empty registry and
-    timeline (dropping any flight-recorder mirror), no sink/flusher,
-    health counters zeroed."""
-    global _enabled, _registry, _timeline, _sink
+    """Fresh state: re-latch the enabled flag, empty registry,
+    timeline (dropping any flight-recorder mirror) and launch record,
+    no sink/flusher, no compile listeners, health counters zeroed."""
+    global _enabled, _registry, _timeline, _sink, _launches
     stop_flusher()
+    _unwatch_compiles()
     _enabled = None
     _registry = Registry()
     _timeline = Timeline()
+    _launches = _launch.LaunchRecord()
     _sink = None
     from sparkdl_tpu.observe import health, mem, perf
 

@@ -7,8 +7,8 @@ control plane, where :mod:`sparkdl_tpu.observe.aggregate` merges every
 rank into ONE Chrome trace (``timeline.json``) that opens directly in
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``, alongside
 the per-rank xprof traces from :mod:`sparkdl_tpu.utils.profiler`
-(:func:`~sparkdl_tpu.utils.profiler.annotate` emits the SAME region
-name into both, so the two views correlate 1:1).
+(:func:`sparkdl_tpu.observe.span` puts every span into both: here
+under its name, there as ``sparkdl.<name>`` on the profiler's clock).
 
 Event shape (Chrome trace-event format, the subset Perfetto renders):
 

@@ -151,6 +151,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=128,
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
+        name="sparkdl_flash_fwd",
     )(q, k, v)
     return out
 
@@ -292,6 +293,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
         out_specs=q_tile,
         compiler_params=params,
         interpret=interpret,
+        name="sparkdl_flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -305,5 +307,6 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
         out_specs=(k_tile, k_tile),
         compiler_params=params,
         interpret=interpret,
+        name="sparkdl_flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

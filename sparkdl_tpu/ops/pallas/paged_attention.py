@@ -194,6 +194,7 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lens, *,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="sparkdl_paged_decode",
     )(tables, lens, qg, *([k_pool] * ppb), *([v_pool] * ppb))
     return out.reshape(b, h, d)
 

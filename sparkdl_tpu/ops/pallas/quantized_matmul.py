@@ -151,6 +151,7 @@ def quantized_matmul_pallas(x, w_q, scales, *, block_m=128, block_n=128,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="sparkdl_qmm_int8",
     )(x, w_q, scales.reshape(1, n))
 
 
@@ -389,6 +390,7 @@ def quantized_matmul_int4_pallas(x, packed, scales, *, group=INT4_GROUP,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="sparkdl_qmm_int4",
     )(x[:, 0::2], x[:, 1::2], packed, scales)
 
 

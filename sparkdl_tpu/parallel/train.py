@@ -58,12 +58,16 @@ def make_train_step(loss_fn, optimizer, *, grad_accum=1, remat=False,
             lambda g, m: g if m else jnp.zeros_like(g), tree, param_mask
         )
 
-    def single(params, opt_state, batch, *extra):
-        loss, grads = grad_fn(params, batch, *extra)
+    @jax.named_scope("sparkdl.optimizer")
+    def update(params, opt_state, grads):
         grads = apply_mask(grads)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         updates = apply_mask(updates)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        return jax.tree.map(lambda p, u: p + u, params, updates), opt_state
+
+    def single(params, opt_state, batch, *extra):
+        loss, grads = grad_fn(params, batch, *extra)
+        params, opt_state = update(params, opt_state, grads)
         return params, opt_state, {"loss": loss}
 
     if grad_accum == 1:
@@ -85,10 +89,7 @@ def make_train_step(loss_fn, optimizer, *, grad_accum=1, remat=False,
         zeros = jax.tree.map(jnp.zeros_like, params)
         (g_sum, l_sum), _ = jax.lax.scan(acc_step, (zeros, 0.0), micro)
         grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
-        grads = apply_mask(grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        updates = apply_mask(updates)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        params, opt_state = update(params, opt_state, grads)
         return params, opt_state, {"loss": l_sum / grad_accum}
 
     return accumulated
@@ -100,8 +101,10 @@ def instrument_step(step_fn, name="train_step"):
     wall-time histogram split ``phase="compile"`` (first call — under
     jit that call pays trace + XLA compile) vs ``phase="execute"``,
     a call counter, and a running ``<name>_per_second`` gauge over the
-    execute calls. Telemetry off (the default): one cached-boolean
-    check, then straight through to ``step_fn``.
+    execute calls. Telemetry off (the default): the call runs under
+    its ``observe.span`` alone, which is then only the annotation
+    ``sparkdl.<name>`` on the profiler's clock (a no-op inside JAX
+    when no profiler session is open).
 
     Timing is dispatch wall-time, deliberately: blocking on the result
     every step would serialize the async dispatch pipeline the whole
@@ -124,7 +127,8 @@ def instrument_step(step_fn, name="train_step"):
     @functools.wraps(step_fn)
     def stepped(*args, **kwargs):
         if not observe.enabled():
-            return step_fn(*args, **kwargs)
+            with observe.span(name, cat="train"):
+                return step_fn(*args, **kwargs)
         from sparkdl_tpu.observe import health
 
         # Step ENTRY is the gang-health progress marker: a rank that
@@ -142,6 +146,7 @@ def instrument_step(step_fn, name="train_step"):
         # hints) before the exception unwinds the worker.
         with mem.oom_guard(phase="step"), \
                 observe.span(name, cat="train", step=state["calls"],
+                             ident=f"{name}-{state['calls']}",
                              phase=phase):
             out = step_fn(*args, **kwargs)
         dt = time.perf_counter() - t0
@@ -243,6 +248,7 @@ def cross_entropy_loss(logits, labels, *, ignore_index=None):
     return nll.mean()
 
 
+@jax.named_scope("sparkdl.lm_head_loss")
 def fused_cross_entropy(hidden, w_head, labels, *, chunk_size=256,
                         ignore_index=None, matmul_dtype=None,
                         freeze_head=False):
@@ -345,7 +351,8 @@ def make_lm_loss_fn(model, *, loss="logits", chunk=512, ce_bf16=False):
 
     def loss_fn(p, b):
         logits = model.apply({"params": p}, b["inputs"])
-        return cross_entropy_loss(logits, b["targets"])
+        with jax.named_scope("sparkdl.lm_head_loss"):
+            return cross_entropy_loss(logits, b["targets"])
     return loss_fn
 
 

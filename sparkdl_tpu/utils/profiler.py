@@ -38,27 +38,12 @@ def maybe_trace_worker(rank):
         yield d
 
 
-@contextlib.contextmanager
 def annotate(name):
-    """Named region in BOTH trace timelines under the SAME name:
-
-    - the xprof trace (``jax.profiler.TraceAnnotation``) captured by
-      :func:`trace`/:func:`maybe_trace_worker`, viewable per rank in
-      TensorBoard/Perfetto; and
-    - the gang event timeline (:func:`sparkdl_tpu.observe.span`,
-      ``cat="xprof"``), merged across ranks into
-      ``SPARKDL_TPU_TELEMETRY_DIR/run-*/timeline.json``.
-
-    The shared name is the correlation key: find a region in the
-    merged gang timeline, then open that rank's xprof trace and search
-    the same name to drill from gang-level wall time into per-op
-    device time (``docs/observability.rst``). The observe side is a
-    no-op when telemetry is off; the xprof side is a no-op outside a
-    capture, so ``annotate`` is always safe to leave in."""
-    import jax
-
+    """A named region: :func:`sparkdl_tpu.observe.span` with
+    ``cat="xprof"``. It shows as ``sparkdl.<name>`` in the xprof trace
+    captured by :func:`trace`/:func:`maybe_trace_worker` and, with
+    telemetry on, as ``<name>`` in the merged gang timeline
+    (``docs/observability.rst``)."""
     from sparkdl_tpu import observe
 
-    with jax.profiler.TraceAnnotation(name):
-        with observe.span(name, cat="xprof"):
-            yield
+    return observe.span(name, cat="xprof")

@@ -1,9 +1,12 @@
 """Timeline events, Chrome trace validity, the observe facade's
-off-by-default zero-overhead contract, and the profiler.annotate ↔
-timeline span-name pairing (ISSUE: observability tentpole +
-satellite)."""
+off-by-default contract (nothing recorded, nothing that outlives the
+call), and profiler.annotate as an observe.span."""
 
+import glob
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -75,22 +78,29 @@ def test_chrome_trace_is_valid_and_lane_labeled():
 # -- facade: off by default, zero overhead -----------------------------------
 
 
-def test_disabled_facade_records_nothing_and_allocates_no_span():
+def test_disabled_facade_records_nothing_and_keeps_nothing():
+    import gc
+    import weakref
+
     assert not observe.enabled()
     observe.inc("ops_total")
     observe.set_gauge("g", 1)
     observe.observe_value("h", 0.5)
     observe.instant("i")
-    # The disabled span is THE shared no-op singleton: nothing is
-    # allocated per call, nothing is buffered.
+    # The disabled span is only its annotation on the profiler's
+    # clock (JAX is imported in this process): nothing is recorded,
+    # and nothing the call made outlives it.
     s1 = observe.span("a", step=1)
-    s2 = observe.span("b", other=2)
-    assert s1 is s2 is observe._NOOP_SPAN
+    gone = weakref.ref(s1)
     with s1:
-        pass
+        assert not getattr(observe._open_spans, "stack", [])
+    del s1
+    gc.collect()
+    assert gone() is None
     snap = observe.metrics().snapshot()
     assert snap["counters"] == snap["gauges"] == snap["histograms"] == []
     assert len(observe.timeline()) == 0
+    assert len(observe.launch_record()) == 0
     # flush() without a sink (and disabled) is a no-op returning False
     assert observe.flush() is False
 
@@ -167,11 +177,10 @@ def test_new_run_dir_unique(monkeypatch, tmp_path):
 # -- profiler.annotate pairing ----------------------------------------------
 
 
-def test_annotate_names_pair_xprof_and_gang_timeline(monkeypatch, tmp_path):
-    """The satellite contract: an annotate() region shows under the
-    SAME name in the xprof trace and the gang timeline, so the two
-    views correlate. (TraceAnnotation outside a capture is a no-op;
-    the observe span is what we can assert on.)"""
+def test_annotate_is_an_observe_span_of_cat_xprof(monkeypatch, tmp_path):
+    """annotate() has no implementation of its own: with telemetry on
+    the region lands in the gang timeline under its name (what lands
+    in the xprof trace is test_span_lands_on_the_profilers_clock's)."""
     monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path))
     observe._reset_for_tests()
     from sparkdl_tpu.utils.profiler import annotate
@@ -189,6 +198,84 @@ def test_annotate_is_inert_without_telemetry():
     with annotate("region"):
         pass
     assert len(observe.timeline()) == 0
+
+
+# -- one span call, on both clocks -------------------------------------------
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return {e.name for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events}
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_span_lands_on_the_profilers_clock(telemetry, monkeypatch, tmp_path):
+    """Under a profiler session a span is an event ``sparkdl.<name>``
+    of the trace, whether or not the telemetry latch is set."""
+    import jax
+    import jax.numpy as jnp
+
+    if telemetry:
+        monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path / "t"))
+        observe._reset_for_tests()
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with observe.span("probe.outer", cat="train"):
+            with observe.span("probe.inner", cat="collective"):
+                jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(trace_dir)
+    assert {"sparkdl.probe.outer", "sparkdl.probe.inner"} <= names
+    assert len(observe.timeline()) == (2 if telemetry else 0)
+
+
+def test_span_imports_no_jax_and_starts_no_thread():
+    """In a process that has not imported JAX (the driver), a span
+    imports nothing and starts nothing, recorded or not."""
+    code = (
+        "import sys, threading\n"
+        "from sparkdl_tpu import observe\n"
+        "with observe.span('a', cat='train'):\n"
+        "    pass\n"
+        "with observe.span('gang.x', cat='launch'):\n"
+        "    observe.instant('gang.y', cat='launch')\n"
+        "assert observe.watch_compiles() is False\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert threading.active_count() == 1\n"
+        "assert [s['name'] for s in observe.launch_report()] == "
+        "['gang.x', 'gang.y']\n"
+        "print('ok')\n")
+    env = {k: v for k, v in os.environ.items()
+           if k != observe.TELEMETRY_DIR_ENV}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_recorded_span_names_its_cause_and_shares_its_identifier(
+        monkeypatch, tmp_path):
+    """What choosing-metrics asks of a span: name, start, end, the
+    span that caused it (enclosing, or named) and an identifier the
+    spans of one step share."""
+    monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path))
+    observe._reset_for_tests()
+    with observe.span("train_step", cat="train", ident="train_step-7"):
+        with observe.span("reduce", cat="collective"):
+            pass
+        with observe.span("late", cat="data", cause="input.next"):
+            pass
+    reduce, late, step = observe.timeline().drain()
+    assert step["name"] == "train_step" and "cause" not in step["args"]
+    assert reduce["args"] == {"cause": "train_step", "ident": "train_step-7"}
+    assert late["args"] == {"cause": "input.next", "ident": "train_step-7"}
+    assert step["ts"] <= reduce["ts"]
+    assert reduce["ts"] + reduce["dur"] <= step["ts"] + step["dur"] + 1
 
 
 def test_restart_context_emits_one_resume_marker(monkeypatch, tmp_path):

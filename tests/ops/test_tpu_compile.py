@@ -169,3 +169,45 @@ def test_paged_engine_decode_step(one_chip, monkeypatch, quant):
     # projections and the head beside it
     assert compiled.as_text().count("tpu_custom_call") >= (
         9 if quant else 1)
+
+
+def test_train_step_carries_the_kernel_names(one_chip, monkeypatch):
+    """One LoRA train step at depth 1 with remat: in the compiled
+    program each flash kernel is an instruction named after its
+    ``pallas_call(name=...)``, which is the name its events take in a
+    device trace (``%sparkdl_flash_fwd.1 = ...``): forward (twice, the
+    second under remat), dq and dk/dv are told apart by name alone."""
+    import re
+
+    import optax
+
+    from sparkdl_tpu.models import Llama, LlamaConfig, lora_mask
+    from sparkdl_tpu.ops import attention
+    from sparkdl_tpu.parallel.train import make_lm_loss_fn, make_train_step
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    cfg = LlamaConfig.llama3_8b(
+        n_layers=1, vocab_size=4096, dtype=jnp.bfloat16, attention="flash",
+        remat=True, lora_rank=8, lora_targets=("q_proj", "v_proj"))
+    model = Llama(cfg)
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    mask = lora_mask(params)
+    opt = optax.masked(optax.adamw(1e-4), mask)
+    step = make_train_step(
+        make_lm_loss_fn(model, loss="fused", chunk=256, ce_bf16=True),
+        opt, param_mask=mask)
+    on_chip = functools.partial(
+        jax.tree.map,
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip))
+    tokens = _shape(one_chip, (1, 2048), jnp.int32)
+    compiled = jax.jit(step).lower(
+        on_chip(params), on_chip(jax.eval_shape(opt.init, params)),
+        {"inputs": tokens, "targets": tokens}).compile()
+    kernels = re.findall(
+        r"%(\w+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())
+    assert sorted(kernels) == [
+        "sparkdl_flash_dkv", "sparkdl_flash_dq", "sparkdl_flash_fwd",
+        "sparkdl_flash_fwd"]
