@@ -41,8 +41,10 @@ class LlamaConfig:
     remat: bool = False
     attention: str = "reference"  # "reference" (train) | "flash" (serve)
     # flash tile size; 0 = library default (SPARKDL_TPU_FLASH_BLOCK read
-    # once at import, else 128). Part of the config so sweeps retune the
-    # kernel through the jit cache key instead of a trace-time env read.
+    # once at import, else chosen from the shape by
+    # ops.pallas.flash_attention.flash_tiles). Part of the config so
+    # sweeps retune the kernel through the jit cache key instead of a
+    # trace-time env read.
     flash_block: int = 0
     decode: bool = False          # KV-cache autoregressive mode
     max_cache_len: int = 2048     # KV-cache capacity for decoding
@@ -459,13 +461,12 @@ class Attention(nn.Module):
         if rep > 1:
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        # Attention policy (cfg.attention): "reference" = XLA fused
-        # attention — best for TRAINING (native autodiff; the flash
-        # kernel's backward currently recomputes densely). "flash" =
-        # pallas kernel — 1.81x train step at seq 4096 in the round-2
-        # TPU sweep (BASELINE.md; pre-bf16-operand-fix, re-measure),
-        # the inference/serving path. Injectable attention_fn
-        # overrides both (ring attention under sequence parallelism).
+        # Attention policy (cfg.attention): "reference" = XLA
+        # attention with native autodiff (scores in HBM). "flash" =
+        # the pallas kernels, forward and fused backward, O(S·D)
+        # memory: what the on-chip benchmark's train cell runs
+        # (PERF.md). Injectable attention_fn overrides both (ring
+        # attention under sequence parallelism).
         if self.attention_fn is not None:
             attend = self.attention_fn
         elif cfg.attention == "flash":

@@ -17,12 +17,12 @@ import jax
 from sparkdl_tpu.ops._dispatch import block_for, pad_to as _pad_to, use_pallas as _use_pallas
 from sparkdl_tpu.parallel.ring_attention import attention_reference
 
-# Process-level default tiles, read ONCE at import (see
+# Process-level tile overrides, read ONCE at import (see
 # flash_attention's docstring for why a trace-time env read would be a
-# footgun). The per-dimension q/kv tiles are the autotuner's targets
-# (registered tunable knobs); unset they inherit the legacy square
-# block.
-_DEFAULT_FLASH_BLOCK = int(os.environ.get("SPARKDL_TPU_FLASH_BLOCK", 128))
+# footgun). Unset (0), the tiles are chosen from the shape by
+# ``pallas.flash_attention.flash_tiles``; the per-dimension q/kv
+# variables win over the legacy square block.
+_DEFAULT_FLASH_BLOCK = int(os.environ.get("SPARKDL_TPU_FLASH_BLOCK", 0))
 _DEFAULT_FLASH_BLOCK_Q = int(
     os.environ.get("SPARKDL_TPU_FLASH_BLOCK_Q", 0)) or _DEFAULT_FLASH_BLOCK
 _DEFAULT_FLASH_BLOCK_KV = int(
@@ -84,17 +84,17 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
     pallas forward AND backward on TPU (or ``interpret=True`` for
     tests); XLA reference elsewhere.
 
-    ``block``: square q/k tile size (larger tiles amortize K/V
-    streaming and widen the per-program matmuls at short seq).
-    ``block_q`` / ``block_kv`` override the q and kv tiles
-    independently — the shapes the autotuner searches via the
-    ``SPARKDL_TPU_FLASH_BLOCK_Q`` / ``SPARKDL_TPU_FLASH_BLOCK_KV``
-    knobs. All tile defaults are read ONCE at import — callers are
-    jitted and env vars are not part of the jit cache key, so a
-    mid-process env change must never silently retune (or fail to
-    retune) an already-traced program. Sweeps pass tiles explicitly
-    (via ``LlamaConfig.flash_block``), which changes the traced call
-    and therefore the cache key.
+    Left alone, each of the three kernels takes the tiles
+    ``flash_tiles`` chooses from (seq, head_dim, dtype). ``block``
+    forces one square q/k tile on all three; ``block_q`` /
+    ``block_kv`` force the q and kv tiles independently — the shapes
+    the autotuner searches via the ``SPARKDL_TPU_FLASH_BLOCK_Q`` /
+    ``SPARKDL_TPU_FLASH_BLOCK_KV`` knobs. Those variables are read
+    ONCE at import — callers are jitted and env vars are not part of
+    the jit cache key, so a mid-process env change must never silently
+    retune (or fail to retune) an already-traced program. Sweeps pass
+    tiles explicitly (via ``LlamaConfig.flash_block``), which changes
+    the traced call and therefore the cache key.
     """
     if interpret is None:
         if not _use_pallas():
@@ -104,14 +104,13 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     s = qt.shape[2]
-    tile_q = int(block_q) if block_q else (
-        int(block) if block else _DEFAULT_FLASH_BLOCK_Q)
-    tile_kv = int(block_kv) if block_kv else (
-        int(block) if block else _DEFAULT_FLASH_BLOCK_KV)
-    bq = block_for(s, tile=tile_q)
-    bk = block_for(s, tile=tile_kv)
-    # the kernel needs the (padded) seq divisible by BOTH tiles
-    mult = bq * bk // math.gcd(bq, bk)
+    bq = int(block_q or block or _DEFAULT_FLASH_BLOCK_Q) or None
+    bk = int(block_kv or block or _DEFAULT_FLASH_BLOCK_KV) or None
+    # the kernels need the (padded) seq divisible by every tile: by a
+    # forced one as given, and by 128 where the rule is to choose (its
+    # tiles are power-of-two multiples of that which divide the seq).
+    # A seq under 128 is one tile whatever was asked for.
+    mult = block_for(s) if s < 128 else math.lcm(bq or 128, bk or 128)
     qt, pad = _pad_to(qt, mult, 2)
     if pad and not causal:
         # padded keys must not receive attention weight: causal masking
@@ -122,7 +121,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
         if not interpret:
             raise ValueError(
                 f"bidirectional flash attention needs a sequence that "
-                f"is a multiple of its tiles ({bq}, {bk}), got {s}: "
+                f"is a multiple of its tiles ({mult}), got {s}: "
                 "pad it, or call attention_reference")
         return attention_reference(q, k, v, causal=False, scale=scale)
     kt, _ = _pad_to(kt, mult, 2)

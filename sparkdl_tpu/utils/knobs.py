@@ -445,7 +445,8 @@ def _build():
         # -- kernels / interop --------------------------------------
         k("SPARKDL_TPU_FLASH_BLOCK", "int", None, "kernels",
           "flash-attention block size override (legacy square tile; "
-          "the per-dimension _Q/_KV knobs win when set)"),
+          "the per-dimension _Q/_KV knobs win when set; unset, the "
+          "kernels' tiles are chosen from the shape)"),
         k("SPARKDL_TPU_FLASH_BLOCK_Q", "int", None, "kernels",
           "flash-attention query tile (rows of scores each grid "
           "program owns); read once at import of ops.attention",

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from sparkdl_tpu.ops.attention import flash_attention
+from sparkdl_tpu.ops.pallas import flash_attention as flash_kernels
 from sparkdl_tpu.parallel.ring_attention import attention_reference
 
 
@@ -149,3 +150,241 @@ def test_fused_backward_padded_seq():
     np.testing.assert_allclose(
         np.asarray(g1), np.asarray(g2), atol=1e-4, rtol=1e-4
     )
+
+
+# -- ISSUE 25: tiles from the shape, masks on the diagonal only -------------
+
+KERNELS = ("fwd", "dq", "dkv")
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [64, 128, 200, 2048, 8192, 32768])
+def test_tile_rule(s, d, kernel):
+    """The rule's tiles, for the sequence as ``flash_attention`` pads
+    it: they divide it, a block's last two dimensions are multiples of
+    (8, 128) or the whole dimension, and the VMEM the rule reckons
+    stays under the scoped limit, at the cell's S and far past it."""
+    from sparkdl_tpu.ops._dispatch import block_for
+
+    padded = -(-s // block_for(s)) * block_for(s)
+    t = flash_kernels.flash_tiles(kernel, padded, d, 2)
+    assert padded % t.bq == 0 and padded % t.bk == 0
+    assert padded % t.major == 0
+    assert t.major % t.bq == 0 and t.major % t.bk == 0
+    assert max(t.bq, t.bk) % min(t.bq, t.bk) == 0      # the tiles nest
+    for rows in (t.bq, t.bk, t.major):      # second-to-last block dim
+        assert rows % 8 == 0 or rows == padded
+    # dk/dv's lse and delta rows are (1, major) blocks: major is lanes
+    assert t.major % 128 == 0 or t.major == padded
+    # v5e scopes 16 MiB of VMEM a kernel
+    assert t.vmem_bytes <= flash_kernels.VMEM_BUDGET < 16 * 2 ** 20
+    assert t == flash_kernels.flash_tiles(kernel, padded, d, 2)   # pure
+    if padded >= 512:
+        # several MXU passes deep, not the 128 of before
+        assert min(t.bq, t.bk) >= 256
+
+
+def test_tile_rule_keeps_explicit_tiles():
+    """An explicit tile wins over the rule, for every kernel."""
+    for kernel in KERNELS:
+        t = flash_kernels.flash_tiles(kernel, 2048, 128, 2, bq=128, bk=256)
+        assert (t.bq, t.bk) == (128, 256)
+        t = flash_kernels.flash_tiles(kernel, 2048, 128, 2, bk=128)
+        assert t.bk == 128 and t.bq >= 256
+    with pytest.raises(ValueError, match="divisible"):
+        flash_kernels.flash_tiles("fwd", 2048, 128, 2, bq=384)
+    with pytest.raises(ValueError, match="nest"):
+        flash_kernels.flash_tiles("fwd", 1536, 128, 2, bq=768, bk=512)
+
+
+def _qkv(rng, b, s, h, d, dtype):
+    return [jnp.asarray(rng.randn(b, s, h, d), dtype) for _ in range(4)]
+
+
+def _all_grads(fn, q, k, v, cot):
+    return jax.grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * cot.astype(jnp.float32)
+                    ).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.fixture
+def tiles_256(monkeypatch):
+    """The rule with its targets cut to 256 (the CPU interprets the
+    kernels): at S = 1024 a q tile then walks up to three tiles wholly
+    below the diagonal and one across it, as a 512 tile does at the
+    cell's 2048."""
+    monkeypatch.setattr(flash_kernels, "_TARGET_TILE", 256)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("s", [1024, 900], ids=["aligned", "padded"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "bidirectional"])
+def test_rule_tiles_interior_and_diagonal(tiles_256, causal, s, dtype):
+    """Forward and all three gradients against the reference where
+    plain and masked tiles both occur, with tiles the rule chose."""
+    rng = np.random.RandomState(11)
+    q, k, v, cot = _qkv(rng, 1, s, 2, 32, dtype)
+    assert flash_kernels.flash_tiles("fwd", 1024, 32, 4)[:2] == (256, 256)
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=causal, interpret=True)
+
+    def ref(q_, k_, v_):
+        return attention_reference(q_, k_, v_, causal=causal)
+
+    f32 = dtype == jnp.float32
+    tol = dict(atol=2e-5, rtol=2e-5) if f32 else dict(atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(ref(q, k, v), np.float32), **tol)
+    gtol = dict(atol=1e-4, rtol=1e-4) if f32 else dict(atol=6e-2, rtol=6e-2)
+    for name, got, want in zip(
+            "qkv", _all_grads(flash, q, k, v, cot),
+            _all_grads(ref, q, k, v, cot)):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            err_msg=f"d{name}", **gtol)
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128), (64, 256)])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "bidirectional"])
+def test_major_blocks_carry_the_state(monkeypatch, causal, bq, bk):
+    """With VMEM for one tile a block only, the streamed side comes in
+    several major blocks: the running softmax state and the gradient
+    accumulators cross grid steps in scratch, and blocks past the
+    diagonal are neither fetched nor walked. Same answers."""
+    monkeypatch.setattr(flash_kernels, "VMEM_BUDGET", 1)
+    t = flash_kernels.flash_tiles("fwd", 512, 32, 4, bq, bk)
+    assert t.major == max(bq, bk) < 512 and t.vmem_bytes > 1
+    rng = np.random.RandomState(12)
+    q, k, v, cot = _qkv(rng, 1, 512, 2, 32, jnp.float32)
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=causal, block_q=bq,
+                               block_kv=bk, interpret=True)
+
+    def ref(q_, k_, v_):
+        return attention_reference(q_, k_, v_, causal=causal)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)),
+        atol=2e-5, rtol=2e-5)
+    for name, got, want in zip(
+            "qkv", _all_grads(flash, q, k, v, cot),
+            _all_grads(ref, q, k, v, cot)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4,
+            err_msg=f"d{name}")
+
+
+def test_scale_is_folded_not_dropped():
+    """A scale that is no power of two, through forward and backward:
+    folded into q (forward, dq), k (dk/dv) and the final dq / dk."""
+    rng = np.random.RandomState(13)
+    q, k, v, cot = _qkv(rng, 1, 256, 2, 32, jnp.float32)
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=True, scale=0.3,
+                               block=128, interpret=True)
+
+    def ref(q_, k_, v_):
+        return attention_reference(q_, k_, v_, causal=True, scale=0.3)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)),
+        atol=2e-5, rtol=2e-5)
+    for got, want in zip(_all_grads(flash, q, k, v, cot),
+                         _all_grads(ref, q, k, v, cot)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+
+
+def test_causal_keep_is_one_definition():
+    """The transposed mask of dk/dv is the forward's, transposed."""
+    keep = flash_kernels._causal_keep(64, 32, 16, 48)
+    keep_t = flash_kernels._causal_keep(64, 32, 16, 48, transposed=True)
+    assert keep.shape == (16, 48) and keep_t.shape == (48, 16)
+    np.testing.assert_array_equal(np.asarray(keep).T, np.asarray(keep_t))
+    rows, cols = np.nonzero(~np.asarray(keep))
+    assert (64 + rows < 32 + cols).all() and len(rows)
+
+
+@pytest.fixture
+def telemetry(monkeypatch, tmp_path):
+    from sparkdl_tpu import observe
+
+    monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path))
+    observe._reset_for_tests()
+    yield observe
+    observe._reset_for_tests()
+
+
+def _tile_counts(observe):
+    return {
+        (c["labels"]["kernel"], c["labels"]["chosen"]):
+            (c["value"], c["labels"])
+        for c in observe.metrics().snapshot()["counters"]
+        if c["name"] == "flash.tiles"}
+
+
+def test_flash_tiles_counted_once_a_traced_kernel(telemetry):
+    """``flash.tiles`` says which tiles a step was built with and who
+    chose them: once a kernel when the call is traced, not once a run
+    of the compiled program."""
+    q = jnp.ones((1, 256, 1, 16), jnp.float32)
+    step = jax.jit(jax.grad(lambda q_: flash_attention(
+        q_, q_, q_, causal=True, interpret=True).sum()))
+    step(q)
+    step(q)                                  # cached: traces nothing
+    counts = _tile_counts(telemetry)
+    assert sorted(counts) == [
+        ("dkv", "rule"), ("dq", "rule"), ("fwd", "rule")]
+    for value, labels in counts.values():
+        assert value == 1
+        assert (labels["s"], labels["d"]) == ("256", "16")
+        assert (labels["bq"], labels["bk"]) == ("256", "256")
+    jax.jit(lambda q_: flash_attention(
+        q_, q_, q_, causal=True, block_q=64, interpret=True))(q)
+    value, labels = _tile_counts(telemetry)[("fwd", "argument")]
+    assert value == 1 and (labels["bq"], labels["bk"]) == ("64", "256")
+
+
+def test_flash_tiles_not_counted_with_telemetry_off():
+    from sparkdl_tpu import observe
+
+    observe._reset_for_tests()
+    q = jnp.ones((1, 128, 1, 16), jnp.float32)
+    flash_attention(q, q, q, causal=True, interpret=True)
+    assert not _tile_counts(observe)
+
+
+def test_env_tiles_still_override_the_rule(monkeypatch):
+    """``SPARKDL_TPU_FLASH_BLOCK*`` are read once at import; set, they
+    reach all three kernels as explicit tiles."""
+    import importlib
+
+    from sparkdl_tpu.ops import attention
+
+    monkeypatch.setenv("SPARKDL_TPU_FLASH_BLOCK", "128")
+    monkeypatch.setenv("SPARKDL_TPU_FLASH_BLOCK_KV", "64")
+    try:
+        importlib.reload(attention)
+        assert attention._DEFAULT_FLASH_BLOCK_Q == 128
+        assert attention._DEFAULT_FLASH_BLOCK_KV == 64
+        seen = []
+        monkeypatch.setattr(
+            attention, "_flash_core",
+            lambda q, k, v, causal, scale, bq, bk, interpret:
+                seen.append((bq, bk)) or q)
+        q = jnp.ones((1, 256, 1, 16), jnp.float32)
+        attention.flash_attention(q, q, q, interpret=True)
+        attention.flash_attention(q, q, q, block_q=256, interpret=True)
+        assert seen == [(128, 64), (256, 64)]
+    finally:
+        monkeypatch.undo()
+        importlib.reload(attention)
+    assert attention._DEFAULT_FLASH_BLOCK_Q == 0

@@ -71,8 +71,13 @@ def _shape(one_chip, shape, dtype):
 
 
 @pytest.mark.parametrize("backward", [False, True])
-def test_flash_attention(one_chip, backward):
+@pytest.mark.parametrize("batch,seq", [(4, 2048), (1, 8192)])
+def test_flash_attention(one_chip, batch, seq, backward):
+    """The three kernels with the tiles the rule chooses: at the
+    benchmark cell's shape, and at S = 8192, where the streamed side no
+    longer fits VMEM whole and comes in major blocks."""
     from sparkdl_tpu.ops.attention import flash_attention
+    from sparkdl_tpu.ops.pallas.flash_attention import flash_tiles
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, interpret=False)
@@ -82,8 +87,12 @@ def test_flash_attention(one_chip, backward):
             lambda *a: fwd(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    qkv = _shape(one_chip, (1, 2048, N_HEADS, HEAD_DIM), jnp.bfloat16)
-    _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+    assert (flash_tiles("fwd", seq, HEAD_DIM, 2).major < seq) == (
+        seq == 8192)
+    qkv = _shape(one_chip, (batch, seq, N_HEADS, HEAD_DIM), jnp.bfloat16)
+    compiled = _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        3 if backward else 1)
 
 
 @pytest.mark.parametrize("page", [16, 64])
