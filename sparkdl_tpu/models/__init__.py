@@ -1,6 +1,7 @@
 """Model zoo covering the reference's benchmark families
-(BASELINE.json configs): MNIST CNN, ResNet, BERT, and the Llama
-decoder with LoRA — all flax, all written for bf16 MXU math and GSPMD
+(BASELINE.json configs): MNIST CNN, ResNet, BERT, the Llama
+decoder with LoRA, and a patterned (state-space / expert / attention)
+decoder — all flax, all written for bf16 MXU math and GSPMD
 sharding via :mod:`sparkdl_tpu.parallel.sharding`.
 
 Serving-side modules (imported by path, not re-exported — they pull
@@ -18,7 +19,9 @@ decode-only machinery):
   rejection-sampling core
 - :mod:`.quant` — int8/int4 weight-only serving conversions
 - :mod:`.convert` — HuggingFace Llama checkpoint import/export
-- :mod:`.moe` — expert-parallel MoE (psum-combine and a2a dispatch)
+- :mod:`.moe` — expert-parallel MoE (psum-combine and a2a dispatch),
+  and one chip's share of a layer's experts by sorted dispatch
+- :mod:`.mamba2` — the Mamba-2 state-space mixer (training)
 """
 
 from sparkdl_tpu.models.bert import (  # noqa: F401
@@ -26,6 +29,10 @@ from sparkdl_tpu.models.bert import (  # noqa: F401
     BertConfig,
     BertForQuestionAnswering,
     BertForSequenceClassification,
+)
+from sparkdl_tpu.models.hybrid import (  # noqa: F401
+    HybridConfig,
+    HybridDecoder,
 )
 from sparkdl_tpu.models.llama import Llama, LlamaConfig  # noqa: F401
 from sparkdl_tpu.models.lora import lora_mask  # noqa: F401

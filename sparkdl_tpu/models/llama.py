@@ -454,8 +454,11 @@ class Attention(nn.Module):
             ).astype(v.dtype).reshape(b, s, cfg.n_heads * head_dim)
             return _apply_dense(cfg, cfg.d_model, "o_proj", o, adapter_ids)
 
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        # cos=None: attention that takes no positions (a hybrid
+        # decoder's, whose state-space layers carry the order)
+        if cos is not None:
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
         # GQA: repeat kv heads up to n_heads
         rep = cfg.n_heads // cfg.n_kv_heads
         if rep > 1:

@@ -1,27 +1,42 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts layers.
 
 Beyond-reference capability (the reference scales data only, SURVEY.md
-§2.3): a top-k routed expert MLP whose stacked expert weights shard
-over an ``expert`` mesh axis, with TWO execution models behind the
-same routing semantics:
+§2.3). THREE execution models:
 
-- psum-combine (:func:`expert_parallel_moe`): every device computes
-  its LOCAL experts for all replicated tokens; partial outputs psum.
-  Simple, fine at small expert counts — but FLOPs scale with
-  n_experts x all tokens.
+- psum-combine (:func:`expert_parallel_moe`): a top-k routed SwiGLU
+  expert MLP (:class:`MoEMLP`) whose stacked expert weights shard over
+  an ``expert`` mesh axis; every device computes its LOCAL experts for
+  all replicated tokens and partial outputs psum. Simple, fine at
+  small expert counts — but FLOPs scale with n_experts x all tokens.
+  :class:`MoEMLP` alone (``LlamaConfig.n_experts``) is this on one
+  device: still dense, every expert on every token.
 - all_to_all dispatch (:func:`expert_parallel_moe_a2a`): tokens ride
   the ICI to their expert's shard in fixed-capacity buffers
   (Switch/Mixtral execution model) — FLOPs scale with capacity, the
-  sparse-MoE point.
+  sparse-MoE point; a token past an expert's capacity is dropped.
+- sorted dispatch on ONE chip's share (:func:`latent_experts`, the
+  layer :class:`LatentMoE`): the layer is told which experts of the
+  deployment's it holds, routes over all of them, sorts the (token,
+  pick) pairs by expert and runs one grouped product a projection
+  over the pairs that land here. No token is dropped whatever the
+  imbalance, and work goes with the pairs, not with the experts held.
+  This is the one the on-chip benchmark measures
+  (``nemotron3super-lora-train``, PERF.md); the exchange that would
+  bring the other chips' tokens here is not built.
 """
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from sparkdl_tpu import observe
+from sparkdl_tpu.models.llama import _dense
+from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +101,18 @@ def gates_from_probs(probs, top_k):
 def moe_gates(logits, top_k):
     """Top-k softmax gates, renormalized over the selected experts."""
     return gates_from_probs(jax.nn.softmax(logits, axis=-1), top_k)
+
+
+def route_sigmoid(logits, bias, top_k, scale=1.0):
+    """Sigmoid routing with a selection bias (DeepSeek-V3's rule, one
+    group): scores ``s = sigmoid(logits)`` in float32; the ``top_k``
+    experts are chosen by ``s + bias``, and weighted by ``s`` alone,
+    normalised over the chosen and scaled. Returns ``(idx, weights)``,
+    both (..., top_k)."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
 
 
 def load_balance_loss(probs, top_k):
@@ -284,3 +311,136 @@ def expert_parallel_moe_a2a(mesh, cfg, *, axis_name="expert",
         out_specs=P(axis_name),
         check_vma=False,
     )
+
+
+# -- one chip's share of a layer's experts: sorted dispatch -----------------
+#
+# The (token, pick) pairs are sorted by expert, the pairs of experts held
+# elsewhere last. `order[r]` is the pair that lies at row r of the sorted
+# buffer and `inverse` undoes it. Rows past the pairs that land here are
+# never computed by the grouped products, forward or backward: `here`
+# masks them where they come back to their tokens, in both directions.
+# Both directions are gathers: a scatter-add of 180,000 rows is what JAX
+# would otherwise derive, and the TPU runs that a row at a time.
+
+
+@jax.custom_vjp
+def _rows_out(v, here, order, inverse):
+    """(tokens, d) -> (tokens x picks, d): a token's row once a pick,
+    sorted by expert."""
+    return v[order // here.shape[1]]
+
+
+def _rows_out_fwd(v, here, order, inverse):
+    return _rows_out(v, here, order, inverse), (here, order, inverse)
+
+
+def _rows_out_bwd(res, g):
+    return _rows_back(g, *res).sum(1), None, None, None
+
+
+@jax.custom_vjp
+def _rows_back(y, here, order, inverse):
+    """(tokens x picks, d) sorted by expert -> (tokens, picks, d), zero
+    where the pick's expert is not held here."""
+    t, k = here.shape
+    return jnp.where(here[..., None], y[inverse].reshape(t, k, -1), 0)
+
+
+def _rows_back_fwd(y, here, order, inverse):
+    return _rows_back(y, here, order, inverse), (here, order, inverse)
+
+
+def _rows_back_bwd(res, g):
+    here, order, inverse = res
+    g = jnp.where(here[..., None], g, 0)
+    return g.reshape(order.shape[0], -1)[order], None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+
+
+def latent_experts(v, idx, weights, w_up, w_down, held):
+    """The routed experts' part of a layer that THIS chip's experts
+    give: ``sum_k weights_k * e_k(v)`` over the picks whose expert lies
+    in ``held``, with ``e(v) = relu(v @ w_up)^2 @ w_down``.
+
+    :param v: (tokens, latent) tokens in the experts' latent width.
+    :param idx, weights: (tokens, picks) of :func:`route_sigmoid` over
+        ALL the deployment's experts.
+    :param w_up, w_down: (count, latent, d_ff) and (count, d_ff,
+        latent): the experts held.
+    :param held: ``(first, count)``: experts ``first .. first + count
+        - 1`` live here.
+    :returns: ``(out (tokens, latent), counts (count,))``, the rows
+        each held expert received. The buffer has tokens x picks rows
+        whatever the routing, so no pair is ever dropped; the grouped
+        products visit ``counts.sum()`` of them.
+    """
+    first, count = held
+    tokens, picks = idx.shape
+    with jax.named_scope("sparkdl.moe.dispatch"):
+        local = idx - first
+        here = (local >= 0) & (local < count)
+        key = jnp.where(here, local, count).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        # a compare and a sum, not a scatter-add of every pair
+        counts = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
+        rows = _rows_out(v, here, order, inverse)
+    with jax.named_scope("sparkdl.moe.experts"):
+        hidden = jnp.square(jax.nn.relu(grouped_matmul(rows, w_up, counts)))
+        rows = grouped_matmul(hidden, w_down, counts)
+    with jax.named_scope("sparkdl.moe.dispatch"):
+        back = _rows_back(rows, here, order, inverse)
+        out = (back.astype(jnp.float32) * weights[..., None]).sum(1)
+    return out.astype(v.dtype), counts
+
+
+class LatentMoE(nn.Module):
+    """Routed experts in a latent width beside a shared expert
+    (Nemotron-3's ``E`` layer): sigmoid routing over
+    ``n_routed_experts``, non-gated ``relu^2`` experts between a
+    projection into ``latent`` and one back, the shared expert on the
+    full width. Holds ``experts_held`` of the routed experts and adds
+    what THEY give (:func:`latent_experts`). ``cfg`` is a
+    :class:`~sparkdl_tpu.models.hybrid.HybridConfig`."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        dense = functools.partial(_dense, cfg.attn)
+        _, count = cfg.experts_held
+        x = u.reshape(-1, u.shape[-1])
+        with jax.named_scope("sparkdl.moe.route"):
+            logits = nn.Dense(cfg.n_routed_experts, use_bias=False,
+                              dtype=jnp.float32, name="router")(x)
+            bias = self.param("router_bias", nn.initializers.normal(0.02),
+                              (cfg.n_routed_experts,))
+            idx, weights = route_sigmoid(
+                logits, bias, cfg.top_k, cfg.routed_scale)
+        w_up = self.param("w_up", nn.initializers.lecun_normal(batch_axis=0),
+                          (count, cfg.latent, cfg.expert_d_ff))
+        w_down = self.param("w_down",
+                            nn.initializers.lecun_normal(batch_axis=0),
+                            (count, cfg.expert_d_ff, cfg.latent))
+        # once a traced layer: the share, and the buffer it is built with
+        observe.inc("moe.dispatch", held=count, of=cfg.n_routed_experts,
+                    picks=cfg.top_k, rows=x.shape[0] * cfg.top_k,
+                    product="gmm")
+        routed, counts = latent_experts(
+            dense(cfg.latent, "latent_in")(x), idx, weights,
+            w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),
+            cfg.experts_held)
+        # what each held expert received, and who chose whom: read with
+        # mutable=["intermediates"] (the load's shape; the check's picks)
+        self.sow("intermediates", "expert_counts", counts)
+        self.sow("intermediates", "picks", idx)
+        out = dense(u.shape[-1], "latent_out")(routed)
+        with jax.named_scope("sparkdl.moe.shared"):
+            shared = dense(u.shape[-1], "shared_down")(jnp.square(
+                jax.nn.relu(dense(cfg.shared_d_ff, "shared_up")(x))))
+        return (out + shared).reshape(u.shape)

@@ -220,3 +220,63 @@ def test_train_step_carries_the_kernel_names(one_chip, monkeypatch):
     assert sorted(kernels) == [
         "sparkdl_flash_dkv", "sparkdl_flash_dq", "sparkdl_flash_fwd",
         "sparkdl_flash_fwd"]
+
+
+def test_hybrid_train_step_goes_with_the_rows_routed_here(
+        one_chip, monkeypatch):
+    """One LoRA step of the patterned decoder, a layer of each kind at
+    Nemotron-3-Super's widths and the benchmark cell's 1 x 8192 tokens,
+    the chip holding 128 of 512 routed experts: the chunked scan and the
+    sorted dispatch compile for the chip; the experts' products are
+    ragged-dot kernels over the (token, pick) buffer, and nothing in
+    the step has the shape of every expert held on every token."""
+    import re
+
+    import optax
+
+    from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask
+    from sparkdl_tpu.ops import attention, grouped_matmul
+    from sparkdl_tpu.parallel.train import make_lm_loss_fn, make_train_step
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "_use_pallas", lambda: True)
+    cfg = HybridConfig(
+        pattern="ME*", vocab_size=4096, d_model=4096, n_heads=32,
+        n_kv_heads=2, head_dim=128, ssm_heads=128, ssm_head_dim=64,
+        ssm_groups=8, ssm_state=128, conv_kernel=4, chunk_size=128,
+        n_routed_experts=512, experts_held=(128, 128), top_k=22,
+        latent=1024, expert_d_ff=2688, shared_d_ff=5376, routed_scale=5.0,
+        dtype=jnp.bfloat16, attention="flash", remat=True, lora_rank=8)
+    model = HybridDecoder(cfg)
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    mask = lora_mask(params)
+    opt = optax.masked(optax.adamw(1e-4), mask)
+    step = make_train_step(
+        make_lm_loss_fn(model, loss="fused", chunk=256, ce_bf16=True),
+        opt, param_mask=mask)
+    on_chip = functools.partial(
+        jax.tree.map,
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip))
+    tokens = _shape(one_chip, (1, 8192), jnp.int32)
+    text = jax.jit(step).lower(
+        on_chip(params), on_chip(jax.eval_shape(opt.init, params)),
+        {"inputs": tokens, "targets": tokens}).compile().as_text()
+    stacks = re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"",
+        text)
+    # up and down, forward, the remat's forward again, and the backward
+    # in the rows alone (the experts are frozen, so no product a group
+    # for their own gradient is left), each under the experts' scope
+    grouped = [s for s in stacks if grouped_matmul.NAME in s]
+    assert len(grouped) == 6, stacks
+    assert all("sparkdl.moe.experts" in s for s in grouped)
+    assert sum("sparkdl.attn" in s for s in stacks) == 4
+    rows = 8192 * 22
+    assert f"bf16[{rows},2688]" in text
+    for dense in ("[8192,128,2688]", "[128,8192,2688]", "[8192,2688,128]"):
+        assert dense not in text
+    for scope in ("sparkdl.ssm.scan", "sparkdl.ssm.conv", "sparkdl.moe.route",
+                  "sparkdl.moe.dispatch", "sparkdl.moe.shared", "sparkdl.attn"):
+        assert scope in text, scope
