@@ -12,15 +12,28 @@ in float32; the decays and the carried state stay in ``state_dtype``
 (float32: a bfloat16 state loses the early tokens of a long sequence,
 which ``tests/ops/test_ssd.py`` holds as its control).
 
-Plain ``jax.numpy``, differentiated by JAX. Its share of the chip's
+On a TPU the scan runs as the Pallas kernels of
+:mod:`sparkdl_tpu.ops.pallas.ssd_scan`, forward and backward (a chunk's
+decay stays in VMEM and the state is carried across the chunks on the
+chip), for the shapes :func:`~sparkdl_tpu.ops.pallas.ssd_scan.
+ssd_blocks` takes; elsewhere, and for the other shapes, as the plain
+``jax.numpy`` of :func:`_ssd_plain`, differentiated by JAX (the dispatch
+of :mod:`sparkdl_tpu.ops.attention`). Which one a traced call took is
+``observe``'s ``ssd.scan`` counter; the scan's share of the chip's
 roofline is on the record (``ssd_scan_roofline.train_hybrid``).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from sparkdl_tpu import observe
+from sparkdl_tpu.ops._dispatch import pad_to, use_pallas as _use_pallas
 
-def ssd_chunked(x, dt, A, B, C, D, chunk=128, state_dtype=jnp.float32):
+
+def ssd_chunked(x, dt, A, B, C, D, chunk=128, state_dtype=jnp.float32,
+                interpret=None):
     """``y`` (batch, seq, heads, head_dim) of the recurrence above.
 
     :param x: (batch, seq, heads, head_dim).
@@ -31,7 +44,80 @@ def ssd_chunked(x, dt, A, B, C, D, chunk=128, state_dtype=jnp.float32):
     :param D: (heads,), the skip.
     :param chunk: steps a chunk; a sequence that is no multiple of it
         is padded with steps that leave the state as it is (dt = 0).
+    :param state_dtype: of the carried state; the kernels carry float32
+        and leave any other to the plain path.
+    :param interpret: None: the kernels on a TPU where they take the
+        shape, the plain path elsewhere; False: the kernels compiled,
+        whatever the backend; True: interpreted, whatever the tiling
+        (tests).
     """
+    from sparkdl_tpu.ops.pallas.ssd_scan import ssd_blocks
+
+    b, s, h, p = x.shape
+    g, n = B.shape[2:]
+    blocks = None
+    if state_dtype == jnp.float32 and (interpret is not None or _use_pallas()):
+        blocks = ssd_blocks(h, p, g, n, chunk, x.dtype.itemsize,
+                            tiled=not interpret)
+    observe.inc("ssd.scan", path="pallas" if blocks else "jnp", seq=s,
+                heads=h, head_dim=p, groups=g, state=n, chunk=chunk,
+                heads_a_block=blocks.heads if blocks else 0)
+    if blocks is None:
+        return _ssd_plain(x, dt, A, B, C, D, chunk, state_dtype)
+    # a chunk's rows and a block's columns, as the mixer has them; only
+    # dt (heads numbers a step) changes layout: a head's steps as a row
+    x2, B2, C2 = (pad_to(a.reshape(b, s, -1), chunk, 1)[0] for a in (x, B, C))
+    dt_t = pad_to(dt.astype(jnp.float32).swapaxes(1, 2), chunk, 2)[0]
+    y = _ssd_kernels(
+        x2, dt_t, A.astype(jnp.float32)[:, None], B2, C2,
+        jnp.repeat(D.astype(jnp.float32), p)[None],      # the skip a column
+        (("heads", h), ("groups", g), ("chunk", chunk), ("blocks", blocks),
+         ("interpret", bool(interpret))))
+    return y[:, :s].reshape(b, s, h, p)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_kernels(x, dt_t, a, B, C, d_cols, static):
+    """The kernels on what they take (:func:`~sparkdl_tpu.ops.pallas.
+    ssd_scan.ssd_scan_fwd`'s arguments); `static` is its keywords."""
+    from sparkdl_tpu.ops.pallas.ssd_scan import ssd_scan_fwd
+
+    return ssd_scan_fwd(x, dt_t, a, B, C, d_cols, **dict(static))
+
+
+def _ssd_kernels_fwd(x, dt_t, a, B, C, d_cols, static):
+    from sparkdl_tpu.ops.pallas.ssd_scan import ssd_scan_fwd
+
+    y, states = ssd_scan_fwd(x, dt_t, a, B, C, d_cols, save_states=True,
+                             **dict(static))
+    return y, (x, dt_t, a, B, C, d_cols, states)
+
+
+def _ssd_kernels_bwd(static, res, dy):
+    """The kernel's five cotangents, and the two sums over everything
+    that a frozen ``A`` and ``D`` leave unused (XLA then drops them)."""
+    from sparkdl_tpu.ops.pallas.ssd_scan import ssd_scan_bwd
+
+    x, dt_t, a, B, C, d_cols, states = res
+    dx, ddt_t, da_t, dB, dC = ssd_scan_bwd(
+        x, dt_t, a, B, C, d_cols, dy, states, **dict(static))
+    # a group's blocks of heads added up (one a group: nothing to add)
+    b, s, groups = B.shape[0], B.shape[1], dict(static)["groups"]
+    dB, dC = (
+        t.reshape(b, s, groups, -1, B.shape[2] // groups).astype(jnp.float32)
+        .sum(3).reshape(B.shape).astype(B.dtype) for t in (dB, dC))
+    da = jnp.sum(da_t * dt_t, axis=(0, 2))[:, None]
+    dd_cols = jnp.einsum("bsc,bsc->c", dy, x,
+                         preferred_element_type=jnp.float32)[None]
+    return dx, ddt_t, da, dB, dC, dd_cols
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
+def _ssd_plain(x, dt, A, B, C, D, chunk, state_dtype):
+    """The scan in plain ``jax.numpy``, differentiated by JAX: the path
+    off the TPU and for shapes the kernels do not take."""
     b, s, h, p = x.shape
     g, n = B.shape[2:]
     pad = -s % chunk

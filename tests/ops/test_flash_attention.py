@@ -313,16 +313,6 @@ def test_causal_keep_is_one_definition():
     assert (64 + rows < 32 + cols).all() and len(rows)
 
 
-@pytest.fixture
-def telemetry(monkeypatch, tmp_path):
-    from sparkdl_tpu import observe
-
-    monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path))
-    observe._reset_for_tests()
-    yield observe
-    observe._reset_for_tests()
-
-
 def _tile_counts(observe):
     return {
         (c["labels"]["kernel"], c["labels"]["chosen"]):

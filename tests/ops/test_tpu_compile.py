@@ -15,6 +15,7 @@ one file for the same reason.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -180,14 +181,47 @@ def test_paged_engine_decode_step(one_chip, monkeypatch, quant):
         9 if quant else 1)
 
 
+# Nemotron-3-Super's state-space layer at the benchmark cell's tokens
+SSM_HEADS, SSM_HEAD_DIM, SSM_GROUPS, SSM_STATE, SSM_CHUNK = 128, 64, 8, 128, 128
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_ssd_scan(one_chip, backward):
+    """The scan's kernels alone at (1, 8192, 128 x 64, 8 x 128) with the
+    blocks the rule chooses: the forward, and the forward that saves
+    the states with the backward behind it."""
+    from sparkdl_tpu.ops.ssd import ssd_chunked
+
+    def fwd(*args):
+        return ssd_chunked(*args, chunk=SSM_CHUNK, interpret=False)
+
+    def fwd_bwd(*args):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=tuple(range(6)))(*args)
+
+    seq = 8192
+    heads = _shape(one_chip, (SSM_HEADS,), jnp.float32)
+    shared = _shape(one_chip, (1, seq, SSM_GROUPS, SSM_STATE), jnp.bfloat16)
+    compiled = _compile(
+        fwd_bwd if backward else fwd,
+        _shape(one_chip, (1, seq, SSM_HEADS, SSM_HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (1, seq, SSM_HEADS), jnp.float32), heads,
+        shared, shared, heads)
+    # (alone, the name stack starts at the kernel, and the forward's
+    # instruction is named jvp_sparkdl_ssd_fwd_ under a gradient)
+    kernels = [line.split(" = ")[0] for line in compiled.as_text().split("\n")
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 1 + backward
+    assert sum("sparkdl_ssd_fwd" in k for k in kernels) == 1
+    assert sum("sparkdl_ssd_bwd" in k for k in kernels) == backward
+
+
 def test_train_step_carries_the_kernel_names(one_chip, monkeypatch):
     """One LoRA train step at depth 1 with remat: in the compiled
     program each flash kernel is an instruction named after its
     ``pallas_call(name=...)``, which is the name its events take in a
     device trace (``%sparkdl_flash_fwd.1 = ...``): forward (twice, the
     second under remat), dq and dk/dv are told apart by name alone."""
-    import re
-
     import optax
 
     from sparkdl_tpu.models import Llama, LlamaConfig, lora_mask
@@ -226,20 +260,20 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
         one_chip, monkeypatch):
     """One LoRA step of the patterned decoder, a layer of each kind at
     Nemotron-3-Super's widths and the benchmark cell's 1 x 8192 tokens,
-    the chip holding 128 of 512 routed experts: the chunked scan and the
-    sorted dispatch compile for the chip; the experts' products are
+    the chip holding 128 of 512 routed experts: the scan's kernels and
+    the sorted dispatch compile for the chip; the experts' products are
     ragged-dot kernels over the (token, pick) buffer, and nothing in
-    the step has the shape of every expert held on every token."""
-    import re
-
+    the step has the shape of every expert held on every token, nor of
+    every chunk's and head's decay."""
     import optax
 
     from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask
-    from sparkdl_tpu.ops import attention, grouped_matmul
+    from sparkdl_tpu.ops import attention, grouped_matmul, ssd
     from sparkdl_tpu.parallel.train import make_lm_loss_fn, make_train_step
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     monkeypatch.setattr(grouped_matmul, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
     cfg = HybridConfig(
         pattern="ME*", vocab_size=4096, d_model=4096, n_heads=32,
         n_kv_heads=2, head_dim=128, ssm_heads=128, ssm_head_dim=64,
@@ -273,6 +307,18 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
     assert len(grouped) == 6, stacks
     assert all("sparkdl.moe.experts" in s for s in grouped)
     assert sum("sparkdl.attn" in s for s in stacks) == 4
+    # the scan: forward, the remat's forward again and the backward, each
+    # under the scope that the benchmark's readers sum
+    scan = [s for s in stacks if "sparkdl_ssd_" in s]
+    assert sorted(s.split("/")[-2] for s in scan) == [
+        "sparkdl_ssd_bwd", "sparkdl_ssd_fwd", "sparkdl_ssd_fwd"], stacks
+    assert all("sparkdl.ssm.scan" in s for s in scan)
+    # what is left under that scope beside the kernels: no loop over the
+    # chunks, and nothing of a (chunk, chunk) decay a chunk and head
+    for line in text.split("\n"):
+        if "sparkdl.ssm.scan" in line:
+            assert " while(" not in line, line
+            assert not re.search(r"f32\[[\d,]*128,128\]", line), line
     rows = 8192 * 22
     assert f"bf16[{rows},2688]" in text
     for dense in ("[8192,128,2688]", "[128,8192,2688]", "[8192,2688,128]"):
