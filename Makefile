@@ -1,6 +1,6 @@
 # Developer entry points.
 
-.PHONY: test test-fast bench chip-smoke native docs clean autotune autotune-plan
+.PHONY: test test-fast chip-smoke native docs clean
 
 test:
 	python -m pytest tests/ -q
@@ -8,17 +8,8 @@ test:
 test-fast:          # skip multiprocess gang tests (each worker imports jax/tf)
 	python -m pytest tests/ -q -m "not gang"
 
-bench:              # single-chip headline bench (run on a TPU host)
-	python bench.py
-
 chip-smoke:         # the main path once on one TPU chip (run on a TPU host)
 	python chip_smoke.py
-
-autotune:           # search the knob space; emit the per-device-kind profile
-	python -m sparkdl_tpu.perf.autotune --bench cpu-proxy
-
-autotune-plan:      # show the (pruned) trial plan without measuring
-	python -m sparkdl_tpu.perf.autotune --bench cpu-proxy --dry-run
 
 native:             # build the C++ control-plane transport
 	$(MAKE) -C native

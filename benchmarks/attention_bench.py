@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Attention benchmarks: flash kernel vs XLA reference across sequence
 lengths, plus the ring-attention overlap-vs-serialized schedule pair
-(ISSUE 10). Timing uses one jitted scan + host readback (see bench.py
-for why).
+(ISSUE 10). Timing uses one jitted scan + host readback, so that
+per-call dispatch stays out of the measured time.
 
 Every metric reports ``p50``/``p99`` over ``REPS`` timed invocations
 and the run appends schema-versioned lines to the PR 7 ledger

@@ -8,8 +8,7 @@ CPU smoke.
 
 Every record reports a RATE DISTRIBUTION over repeated timed runs —
 ``value`` is the p50 and ``tokens_per_sec_p99`` the slow tail (the
-99th percentile of run latency, so p99 <= p50 by construction) —
-matching the ``steps_per_sec_p50/p99`` split ``bench.py`` reports: a
+99th percentile of run latency, so p99 <= p50 by construction): a
 single-shot number hides exactly the jitter (noisy neighbor, thermal
 throttle, host GC) a p99 exposes.
 """

@@ -3,8 +3,8 @@
 BASELINE.json config 4) — the tabular trial harness of the perf
 platform.
 
-Like ``bench.py``/``serve_bench.py`` (the other two autotune
-harnesses), this bench:
+Like ``serve_bench.py`` and ``attention_bench.py`` (the other two
+autotune harnesses), this bench:
 
 - runs a **warm fit** first (XLA compile + trace outside the measured
   window), then ``--reps`` timed fits of the same estimator config,
@@ -68,8 +68,8 @@ def main(argv=None):
                     help="do not append to the history.jsonl ledger")
     args = ap.parse_args(argv)
 
-    # Same switch as bench.py/model_bench: CI smoke runs name their
-    # platform, before this process first touches a device.
+    # CI smoke runs name their platform, before this process first
+    # touches a device.
     plat = os.environ.get("SPARKDL_TPU_BENCH_PLATFORM")
     if plat:
         import jax
@@ -106,9 +106,9 @@ def main(argv=None):
         model = clf.fit(df)
         return model, time.perf_counter() - t0
 
-    # Warm fit: XLA compile/trace is not training throughput (the same
-    # outside-the-measured-window rule as bench.py's warm run); the
-    # timed reps all hit the in-process jit cache.
+    # Warm fit: XLA compile/trace is not training throughput and
+    # stays outside the measured window; the timed reps all hit the
+    # in-process jit cache.
     model, warm_fit_s = one_fit()
 
     # predict is timed PER REP too: a single transform invocation

@@ -31,7 +31,7 @@ twice — bf16 fleet then int8 fleet — and reports the throughput delta
 Perf ledger: unless ``--no-ledger``, the run lands as ONE
 ``history.jsonl`` line (``bench="serve_bench"`` via
 ``observe.perf.sample_metric``/``history_record``/``append_history``,
-exactly like ``attention_bench``/``allreduce_bench``), so
+exactly like ``attention_bench``), so
 ``python -m sparkdl_tpu.observe.compare`` can gate regressions against
 a committed baseline — ``ci/serve_smoke.py`` does.
 

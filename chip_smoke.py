@@ -34,6 +34,11 @@ the script exits nonzero before it prints anything.
 
 The phase functions take a :class:`Spec`; ``tests/test_chip_smoke.py``
 rehearses them on the CPU with a tiny one (interpreted kernels).
+
+``chipbench``'s ``correct`` now repeats, every run, phase ``train``'s
+checks (finite losses, the kernels in the compiled step) and holds the
+loss to a float32 reference; the serve and ``--chips 4`` phases are
+checked here alone until a serve cell and a four-chip cell are in.
 """
 
 import argparse

@@ -21,8 +21,7 @@ int8-vs-bf16 A/B. Asserts:
 - the run landed as a ``history.jsonl`` ledger line, and
   ``python -m sparkdl_tpu.observe.compare`` passes it against the
   committed baseline (``benchmarks/results/serve_baseline.json``) —
-  the same noise-aware gate ``attention_bench``/``allreduce_bench``
-  ride;
+  the same noise-aware gate ``attention_bench`` rides;
 - the int8-vs-bf16 throughput delta is present in the ledger record.
 
 Usage: ``SPARKDL_TPU_TELEMETRY_DIR=<dir> python ci/serve_smoke.py``

@@ -513,8 +513,8 @@ def donated_bytes_static(stablehlo_text):
 def peak_bytes(memory_stats, stablehlo_text=None):
     """Static peak of a compiled module from its ``memory_analysis``
     dict: argument + output + temp − aliased. THE one spelling of the
-    formula (the budget-delta proof and ``bench.py``'s
-    ``step_peak_bytes`` both call it); pass the lowering's StableHLO
+    formula (the budget-delta proof calls it); pass the lowering's
+    StableHLO
     to get the :func:`donated_bytes_static` fallback when the alias
     figure reads 0."""
     if not memory_stats:

@@ -3,10 +3,11 @@
 ``python -m sparkdl_tpu.observe.compare BASE CAND`` compares two
 performance records and **exits non-zero when a regression is found**
 — the CI perf gate is this exit code, so every PR's perf delta is
-enforced, not eyeballed (ROADMAP item 3/4). Either side may be:
+enforced, not eyeballed. Either side may be:
 
-- a **bench JSON** file (the one-line record ``bench.py`` /
-  ``benchmarks/*_bench.py`` print: ``{"metric": ..., "value": ...}``);
+- a **bench JSON** file (the one-line record the
+  ``benchmarks/*_bench.py`` scripts print:
+  ``{"metric": ..., "value": ...}``);
 - the committed **BASELINE.json** (its ``published`` map);
 - a **history ledger** (``benchmarks/results/history.jsonl``, one
   :func:`~sparkdl_tpu.observe.perf.history_record` per line). Default:

@@ -52,9 +52,10 @@ compile/execute histograms. Three pieces close the gap:
    against; see :mod:`sparkdl_tpu.observe.compare`.
 
 The single source of truth for chip peaks (the old per-file
-``PEAK_FLOPS = 197e12`` copies assumed v5e forever): ``bench.py``,
-``benchmarks/model_bench.py`` and ``benchmarks/step_breakdown.py`` all
-import :func:`peak_flops` keyed off the *probed* device kind.
+``PEAK_FLOPS = 197e12`` copies assumed v5e forever): the MFU gauges
+and ``analysis.comms`` read :func:`peak_flops` and its siblings keyed
+off the *probed* device kind. (The on-chip benchmark keeps its own
+table, ``chipbench/peaks.json``.)
 """
 
 import json
@@ -361,11 +362,10 @@ def step_breakdown(events, step_cat="train"):
 
 
 def make_breakdown(total_s, components, *, source, extra=None):
-    """The one breakdown document shape (``BREAKDOWN_SCHEMA``) shared
-    by the telemetry-derived attribution and the hand-rolled
-    ``benchmarks/step_breakdown.py`` decomposition, so the two are
-    cross-checkable in one file format. ``components`` maps name →
-    seconds; fractions are derived here."""
+    """The one breakdown document shape (``BREAKDOWN_SCHEMA``) of the
+    telemetry-derived attribution, which ``perf.autotune``'s pruner
+    reads. ``components`` maps name → seconds; fractions are derived
+    here."""
     total_s = float(total_s)
     doc = {
         "schema": BREAKDOWN_SCHEMA,

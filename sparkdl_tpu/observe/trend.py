@@ -6,7 +6,7 @@ the repo's perf memory, but its trajectory was invisible except by
 hand-reading JSONL. This renders it as one per-metric trajectory
 table: every record's git sha, p50/p99 (or raw value), and the
 relative delta vs the previous record of the SAME metric — so "how
-did the cpu-proxy headline move across the last five PRs" is one
+did ``serve_tokens_per_sec`` move across the last five PRs" is one
 command, and the committed baselines (``BASELINE.json`` published
 map, ``benchmarks/results/serve_baseline.json``) render beside the
 trajectory for at-a-glance drift.

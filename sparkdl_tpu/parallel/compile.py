@@ -34,7 +34,7 @@ variable, and nothing else (:func:`persistent_cache_dir` reads it).
 JAX itself reads it, every process that inherits the environment uses
 that directory, and nothing here points the cache anywhere else;
 unset, warm-start compilation is off. A program that wants a cache
-without being told where (``chip_smoke.py``, ``bench.py``) calls
+without being told where (``chip_smoke.py``) calls
 :func:`export_cache_dir`, which exports the fixed in-checkout
 ``.jax_cache`` under that same name — the path is part of JAX's cache
 key, so it is never built from a temp name, a uid, a pid or a time.
@@ -91,7 +91,7 @@ DEFAULT_MIN_ENTRY_BYTES = 0
 
 # Format 2 added `memory_stats` to the entry (recorded at write time —
 # a deserialized executable's memory_analysis drops alias accounting,
-# and the bench's step_peak_bytes contract needs the real figures on
+# and a caller that reports the step's peak needs the real figures on
 # warm starts too); format 3 `device_ids`, the devices the executable
 # loads onto. Older entries simply cold-recompile once.
 _AOT_FORMAT = 3
@@ -229,7 +229,7 @@ class CompiledStepCache:
     One entry per :func:`step_fingerprint`, written atomically
     (tmp + rename) so a preemption mid-write leaves no torn entry for
     the replacement rank to trip on. ``hits`` / ``misses`` count this
-    instance's outcomes (the bench reports ``warm_start`` off them);
+    instance's outcomes (a caller tells a warm start by them);
     the gang-wide view rides the observe counters.
     """
 
@@ -248,7 +248,7 @@ class CompiledStepCache:
         # result. Persisted inside the cache entry at write time
         # because a DESERIALIZED executable's runtime drops the alias
         # accounting (alias_size reads 0) — without the stored stats a
-        # warm-started bench would overstate its own peak.
+        # warm-started caller would overstate its step's peak.
         self.last_memory_stats = None
         # Device-side program footprint of every executable this
         # instance served (generated_code_size_in_bytes per

@@ -325,9 +325,9 @@ def global_batch(rng, vocab, batch, seq):
 
 
 def make_lm_loss_fn(model, *, loss="logits", chunk=512, ce_bf16=False):
-    """The language-model loss closure used by BOTH the headline bench
-    and the bench_variants sweep — one definition, so a variant the
-    sweep measured is exactly what a promotion into bench.py runs.
+    """The language-model loss closure that both cells of
+    ``BENCHMARK.json`` (``chipbench/kinds/train.py``,
+    ``train_hybrid.py``) and ``chip_smoke.py`` train through.
 
     ``loss="logits"``: materialized logits + standard CE.
     ``loss="fused"``: hidden states into :func:`fused_cross_entropy`

@@ -1,5 +1,6 @@
 """``sparkdl_tpu.perf``: the self-tuning runtime — close the loop from
-ledger to knobs (ROADMAP item 4, ISSUE 12 tentpole).
+ledger to knobs (ISSUE 12 tentpole; ROADMAP D5 holds it as a debt: it
+has searched CPU timings only and adopted nothing).
 
 The platform *measures* everything (PR 7 attribution/MFU, the
 ``history.jsonl`` ledger, ``observe.compare``'s noise-aware medians)
@@ -10,8 +11,8 @@ and *rewrites* programs under machine-checked proofs (PR 9 lint-to-fix)
   knob space from the :mod:`sparkdl_tpu.utils.knobs` registry (knobs
   are data, not code — the XGBoost-``hist`` idiom: the method is
   fixed, the bins are searched), runs short measured trials through
-  the EXISTING bench harnesses (``bench.py`` cpu-proxy,
-  ``benchmarks/serve_bench.py``, ``benchmarks/gbdt_bench.py``), judges
+  the EXISTING bench harnesses (``benchmarks/serve_bench.py``,
+  ``gbdt_bench.py``, ``attention_bench.py``), judges
   every candidate with ``observe.compare``'s rep-sample medians + IQR
   thresholds (never a single timed invocation), and prunes the space
   with step-time attribution — a step that is 80% compute never
@@ -27,7 +28,7 @@ and *rewrites* programs under machine-checked proofs (PR 9 lint-to-fix)
   winner-vs-default verification trial passes the compare gate;
   a regressing winner degrades to defaults — and says so.
 
-CLI: ``python -m sparkdl_tpu.perf.autotune --bench cpu-proxy``.
+CLI: ``python -m sparkdl_tpu.perf.autotune --bench gbdt|serve|attention``.
 """
 
 from sparkdl_tpu.perf.profile import (  # noqa: F401

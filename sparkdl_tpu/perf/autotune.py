@@ -37,10 +37,9 @@ the launcher pre-flight applies nothing.
 
 CLI::
 
-    python -m sparkdl_tpu.perf.autotune --bench cpu-proxy
     python -m sparkdl_tpu.perf.autotune --bench gbdt \\
         --values SPARKDL_TPU_GBDT_MAX_BINS=64,256 --reps 3
-    python -m sparkdl_tpu.perf.autotune --bench cpu-proxy --dry-run
+    python -m sparkdl_tpu.perf.autotune --bench attention --dry-run
 """
 
 import argparse
@@ -141,7 +140,7 @@ class SubprocessTrialRunner:
         return next(iter(metrics))
 
     def _bounded_run(self, args, env):
-        """subprocess with a REAL timeout (the bench.py lesson): a
+        """subprocess with a REAL timeout: a
         child wedged in an accelerator runtime can survive the
         kill-then-communicate path of ``subprocess.run``, so kill the
         whole process group and abandon the pipes after a grace
@@ -208,32 +207,6 @@ class SubprocessTrialRunner:
         return metrics
 
 
-class CpuProxyRunner(SubprocessTrialRunner):
-    """The flagship bench's deviceless headline (``bench.py`` —
-    cpu-proxy on hosts without a chip, the on-chip metric when
-    hardware exists; the ledger line's sole metric is the primary
-    either way)."""
-
-    bench = "cpu-proxy"
-    ledger_bench = "bench.py"
-
-    def command(self):
-        return [sys.executable, os.path.join(ROOT, "bench.py")]
-
-    def attribution(self):
-        # Static, by construction rather than measurement: the
-        # measured program is ONE jitted lax.scan over fixed
-        # device-resident batches — no input pipeline, no host
-        # callbacks, no collectives. Declaring it lets the pruner do
-        # its job (drop data-pipeline knobs) without pretending a
-        # telemetry run happened.
-        return {
-            "source": "static:bench.py single fused scan",
-            "fractions": {"compute": 1.0, "data_wait": 0.0,
-                          "collective": 0.0, "host_callback": 0.0},
-        }
-
-
 class GbdtRunner(SubprocessTrialRunner):
     bench = "gbdt"
     ledger_bench = "gbdt_bench"
@@ -274,8 +247,8 @@ class AttentionRunner(SubprocessTrialRunner):
                 os.path.join(ROOT, "benchmarks", "attention_bench.py")]
 
     def attribution(self):
-        # static, like CpuProxyRunner: one jitted kernel scan — no
-        # input pipeline, no collectives
+        # static, by construction and not by measurement: one jitted
+        # kernel scan — no input pipeline, no collectives
         return {
             "source": "static:attention_bench jitted kernel scan",
             "fractions": {"compute": 1.0, "data_wait": 0.0,
@@ -292,8 +265,8 @@ class AttentionRunner(SubprocessTrialRunner):
         return seqs[0]
 
 
-RUNNERS = {"cpu-proxy": CpuProxyRunner, "gbdt": GbdtRunner,
-           "serve": ServeRunner, "attention": AttentionRunner}
+RUNNERS = {"gbdt": GbdtRunner, "serve": ServeRunner,
+           "attention": AttentionRunner}
 
 
 # -- space derivation + pruning ---------------------------------------------
@@ -572,8 +545,7 @@ def main(argv=None):
         description="Search the registered knob space with measured "
                     "bench trials; emit a verified per-device-kind "
                     "profile the launcher pre-flight applies.")
-    ap.add_argument("--bench", choices=sorted(RUNNERS),
-                    default="cpu-proxy")
+    ap.add_argument("--bench", choices=sorted(RUNNERS), required=True)
     ap.add_argument("--knob", action="append", default=None,
                     help="restrict the space to this knob (repeatable)")
     ap.add_argument("--values", action="append", default=None,

@@ -12,9 +12,9 @@ stamped with the host fingerprint + git sha that measured it:
      "device_kind": "cpu",
      "host": "host/x86_64/cpu64",
      "git_sha": "1b268b0", "created": "2026-08-04T00:00:00Z",
-     "bench": "cpu-proxy",
+     "bench": "gbdt",
      "status": "verified",
-     "knobs": {"SPARKDL_TPU_LOSS_CHUNK": "1024"},
+     "knobs": {"SPARKDL_TPU_GBDT_MAX_BINS": "64"},
      "evidence": {"...": "trial + verification compare reports"}}
 
 Committed profiles live one-per-(device kind, bench) under

@@ -68,7 +68,7 @@ class Knob:
     tunable: bool = False
     trial_values: tuple = ()
     benches: tuple = ()  # trial harnesses that honor it:
-                         # cpu-proxy|serve|gbdt|attention
+                         # serve|gbdt|attention
     component: str = None  # attribution component gating its relevance
 
 
@@ -91,20 +91,7 @@ def _build():
           "host-side producer queue bound of prefetch_to_device "
           "(deeper read-ahead for spiky producers)",
           tunable=True, trial_values=(2, 4, 8),
-          benches=("cpu-proxy",), component="data_wait"),
-        # NOT tunable, deliberately: this selects WHICH program the
-        # bench measures (the undonated control the perf-regress
-        # smoke's donation gate depends on), not a performance
-        # setting of the workload — a profile pinning it would make
-        # every future ledger line measure the control step.
-        k("SPARKDL_TPU_BENCH_NO_DONATE", "bool", "0", "train",
-          "1 measures the UNDONATED control step (a measurement-mode "
-          "selector, never autotuned)"),
-        k("SPARKDL_TPU_LOSS_CHUNK", "int", "512", "train",
-          "vocab-chunk size of the chunked LM loss in bench.py's "
-          "measured step (promoted.json wins when present)",
-          tunable=True, trial_values=(256, 512, 1024),
-          benches=("cpu-proxy",)),
+          component="data_wait"),
         k("SPARKDL_TPU_OVERLAP", "bool", "1", "parallel",
           "default overlap schedule for ring attention / pipeline "
           "hops when the caller does not pass overlap= explicitly",
@@ -161,20 +148,6 @@ def _build():
           "numbers are not meaningful"),
         k("SPARKDL_TPU_BENCH_PLATFORM", "str", None, "bench",
           "force a jax platform for bench children"),
-        k("SPARKDL_TPU_BENCH_CPU_PROXY", "bool", "0", "bench",
-          "measure the fixed-shape deviceless CPU-proxy headline"),
-        k("SPARKDL_TPU_BENCH_PROBE_TIMEOUT", "int", "150", "bench",
-          "per-probe timeout (s)"),
-        k("SPARKDL_TPU_BENCH_RUN_TIMEOUT", "int", "1500", "bench",
-          "measured-run timeout (s)"),
-        k("SPARKDL_TPU_BENCH_CACHE_MAX_AGE", "int", "604800", "bench",
-          "stale-fallback headline cache hard cap (s)"),
-        k("SPARKDL_TPU_BENCH_PROMOTED", "path", None, "bench",
-          "promoted.json override for the headline config"),
-        k("SPARKDL_TPU_VARIANTS_FULL", "bool", "0", "bench",
-          "bench_variants: sweep the full grid"),
-        k("SPARKDL_TPU_WORKLOAD", "str", None, "bench",
-          "workload_bench scenario selector"),
         k("SPARKDL_TPU_SERVE_SMOKE_TTFT_P99_S", "float", None, "bench",
           "serve smoke p99 TTFT bound override"),
         k("SPARKDL_TPU_SERVE_SMOKE_INTER_TOKEN_P99_S", "float", None,
@@ -351,8 +324,6 @@ def _build():
           "serving span-tree event cap"),
         k("SPARKDL_TPU_FLIGHTREC_EVENTS", "int", None, "observe",
           "flight-recorder ring capacity"),
-        k("SPARKDL_TPU_TRACE_DIR", "path", None, "observe",
-          "legacy trace dir alias"),
         k("SPARKDL_TPU_PROFILE", "str", None, "observe",
           "utils.profiler opt-in (jax profiler traces)"),
 
@@ -370,13 +341,9 @@ def _build():
         k("SPARKDL_TPU_PROFILE_AT_STEP", "int", None, "observe",
           "worker-side fixed-step A/B trigger: capture one forensic "
           "window when the rank reaches this train step"),
-        k("SPARKDL_TPU_BENCH_CAPTURE", "bool", "0", "observe",
-          "bench.py/serve_bench.py wrap the measured region (warm-up "
-          "excluded) in a profiler capture; set by their --capture "
-          "flags and forwarded to the measured child"),
         k("SPARKDL_TPU_BENCH_CAPTURE_DIR", "path", None, "observe",
-          "where bench --capture writes its xprof trace (defaults "
-          "beside the bench JSON)"),
+          "where serve_bench --capture writes its xprof trace "
+          "(defaults beside the bench JSON)"),
         k("SPARKDL_TPU_NATIVE_LOGS", "bool", None, "observe",
           "native control-plane log transport toggle"),
 
@@ -541,8 +508,8 @@ def is_registered(name):
 
 def tunable_knobs(bench=None):
     """The autotune search space: tunable knobs, optionally restricted
-    to those a given trial harness (``cpu-proxy`` | ``serve`` |
-    ``gbdt``) actually honors."""
+    to those a given trial harness (``serve`` | ``gbdt`` |
+    ``attention``) actually honors."""
     out = [kb for kb in all_knobs() if kb.tunable]
     if bench is not None:
         out = [kb for kb in out if bench in kb.benches]

@@ -11,6 +11,8 @@ wherever it runs, and on a host with a chip it must not take it.
 
 import os
 
+import pytest
+
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -25,3 +27,13 @@ os.environ.setdefault("SPARKDL_TPU_START_TIMEOUT", "180")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+@pytest.fixture(autouse=True)
+def _cold_slot_probe():
+    """The launcher caches its slot probe once a process; no test
+    inherits another's answer (or must remember to clear it)."""
+    yield
+    from sparkdl_tpu.horovod import launcher
+
+    launcher._probe_local_devices.cache_clear()

@@ -39,6 +39,7 @@ class _FakeEngine:
         self.cfg = _FakeCfg()
         self.fault = fault
         self.block = block
+        self.entered = threading.Event()   # set once run() is waiting
         self.delay = delay
         self.telemetry = None
         self.finish_reasons = {}
@@ -61,6 +62,7 @@ class _FakeEngine:
             fault, self.fault = self.fault, None
             raise fault
         if self.block is not None:
+            self.entered.set()
             self.block.wait()
         out = {}
         for rid, n in self._queued.items():
@@ -109,6 +111,14 @@ def _requests_total(fleet):
     return out
 
 
+def _wait_until(reached, what, timeout=30):
+    """Poll ``reached()``; ``what()`` is shown if it never comes."""
+    deadline = time.monotonic() + timeout
+    while not reached():
+        assert time.monotonic() < deadline, what()
+        time.sleep(0.01)
+
+
 def _fake_fleet(factory, **kw):
     kw.setdefault("poll_seconds", 0.05)
     kw.setdefault("hang_seconds", 60.0)
@@ -140,9 +150,11 @@ def test_admission_control_rejects_503_above_bound():
     counted as rejections, and NEVER hang; the fleet keeps serving
     after the burst."""
     gate = threading.Event()
+    engines = []
 
     def factory():
-        return _FakeEngine(block=gate)
+        engines.append(_FakeEngine(block=gate))
+        return engines[-1]
 
     fleet = _fake_fleet(factory, replicas=1, max_queue=2)
     try:
@@ -157,19 +169,32 @@ def test_admission_control_rejects_503_above_bound():
                 results.append((e.code, dict(e.headers)))
 
         threads = [threading.Thread(target=client) for _ in range(6)]
-        for t in threads:
+        # Fill the bound and WAIT for it: once the engine sits in
+        # run() behind the gate nothing leaves the queue, so the depth
+        # the frontend exports can only grow until the gate opens.
+        for t in threads[:2]:
             t.start()
-            time.sleep(0.05)   # let depth build deterministically
+        assert engines[0].entered.wait(timeout=30)
+        _wait_until(lambda: fleet.queue_depth() >= 2, fleet.queue_depth)
+        # the bound is full and held: every later arrival is refused
+        for t in threads[2:]:
+            t.start()
+        for t in threads[2:]:
+            t.join(timeout=30)
         gate.set()
-        for t in threads:
+        for t in threads[:2]:
             t.join(timeout=30)
         codes = [r[0] for r in results]
-        assert codes.count("ok") >= 2
+        assert codes.count("ok") == 2, codes
         rejected = [r for r in results if r[0] == 503]
-        assert rejected, f"no 503s in {codes}"
+        assert len(rejected) == 4, codes
         assert all(h.get("Retry-After") == "1" for _, h in rejected)
+        # every outcome accounted, nothing lost (a handler counts its
+        # request after it has answered, so the last count can trail
+        # the client's return)
+        _wait_until(lambda: sum(_requests_total(fleet).values()) >= 6,
+                    lambda: _requests_total(fleet))
         counts = _requests_total(fleet)
-        # every outcome accounted, nothing lost
         assert sum(counts.values()) == 6
         assert counts.get("503", 0) == len(rejected)
         rej = fleet.metrics.counter(

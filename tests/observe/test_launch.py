@@ -182,9 +182,7 @@ def test_two_rank_gang_reports_every_launch_span_with_telemetry_unset(
     assert not observe.enabled()
     threads = {t.name for t in threading.enumerate()}
     # what the benchmark's harness does before run(): its own probe
-    # (the first of this process only if no earlier test of the same
-    # xdist worker launched a gang: start from a cold cache)
-    launcher._probe_local_devices.cache_clear()
+    # (conftest's fixture leaves every test a cold cache)
     launcher.probe_local_devices("cpu")
     with caplog.at_level(logging.INFO, logger="HorovodRunner"):
         assert HorovodRunner(np=2).run(_job) == 2

@@ -233,7 +233,7 @@ def test_secondary_regression_protection_rules():
     regressing degrades the winner; an unprotected single-invocation
     secondary jittering down does NOT (the never-a-single-invocation
     rule applies to the degrade decision too)."""
-    kb = knob_reg.get("SPARKDL_TPU_LOSS_CHUNK")
+    kb = knob_reg.get("SPARKDL_TPU_PREFETCH_DEPTH")
 
     def run_factory(secondary_samples):
         class R(StubRunner):
@@ -270,7 +270,7 @@ def test_secondary_regression_protection_rules():
 
 
 def test_verification_pass_emits_verified_profile():
-    kb = knob_reg.get("SPARKDL_TPU_LOSS_CHUNK")
+    kb = knob_reg.get("SPARKDL_TPU_PREFETCH_DEPTH")
     runner = StubRunner(
         [({kb.name: "1024"}, [1200, 1201, 1202, 1203])],
         default=[1000, 1001, 1002, 1003])
@@ -334,7 +334,7 @@ def test_trial_ledger_readback_filters_by_bench_tag(tmp_path):
 
     class FakeBenchRunner(at.SubprocessTrialRunner):
         bench = "cpu-proxy"
-        ledger_bench = "bench.py"
+        ledger_bench = "fake_bench"
 
         def command(self):
             return ["true"]
@@ -348,14 +348,14 @@ def test_trial_ledger_readback_filters_by_bench_tag(tmp_path):
             return 0, "", ""
 
     runner = FakeBenchRunner(history_path=str(history))
-    with pytest.raises(at.TrialError, match="bench='bench.py'"):
+    with pytest.raises(at.TrialError, match="bench='fake_bench'"):
         runner.run({})
     # and a correctly-tagged line IS picked up, even with the foreign
     # one interleaved after it
     class GoodRunner(FakeBenchRunner):
         def _bounded_run(self, args, env):
             operf.append_history(
-                operf.history_record({PRIMARY: 10.0}, bench="bench.py",
+                operf.history_record({PRIMARY: 10.0}, bench="fake_bench",
                                      device_kind="cpu"), str(history))
             operf.append_history(
                 operf.history_record({"other": 1.0},
@@ -370,18 +370,19 @@ def test_trial_ledger_readback_filters_by_bench_tag(tmp_path):
 
 
 def test_trial_timeout_is_a_failed_trial_not_a_crash(tmp_path):
-    runner = at.CpuProxyRunner(history_path=str(tmp_path / "h.jsonl"),
-                               timeout=0.3)
+    runner = at.GbdtRunner(history_path=str(tmp_path / "h.jsonl"),
+                           timeout=0.3)
     runner.command = lambda: [
         "python", "-c", "import time; time.sleep(30)"]
     with pytest.raises(at.TrialError, match="timed out"):
         runner.run({})
 
 
-def test_cpu_proxy_runner_static_attribution_is_compute_bound():
-    """The cpu-proxy harness declares (not measures) that its program
-    is one fused scan: the pruner must see a compute-bound report."""
-    r = at.CpuProxyRunner(history_path="/dev/null")
+def test_attention_runner_static_attribution_is_compute_bound():
+    """The attention harness declares (not measures) that its program
+    is one jitted kernel scan: the pruner must see a compute-bound
+    report."""
+    r = at.AttentionRunner(history_path="/dev/null")
     rep = r.attribution()
     assert rep["fractions"]["compute"] >= at.COMPUTE_BOUND_FRACTION
     kept, pruned = at.prune_space(

@@ -10,7 +10,7 @@ import pytest
 
 from sparkdl_tpu.perf import profile as prof
 
-KNOB = "SPARKDL_TPU_LOSS_CHUNK"
+KNOB = "SPARKDL_TPU_PREFETCH_DEPTH"
 
 
 def _verified(tmp_path, knobs=None, **kw):

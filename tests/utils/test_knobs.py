@@ -5,6 +5,7 @@ the catalog (same pattern as the analysis ``--list-rules`` docs
 test) — and every TUNABLE knob must be documented in the performance
 docs' knob catalog. Tier-1: pure source greps, no jax."""
 
+import ast
 import re
 from pathlib import Path
 
@@ -16,19 +17,21 @@ REPO = Path(__file__).resolve().parents[2]
 # test helpers synthesize knob-shaped names (fake envs, negative
 # cases) that are not platform surface.
 SCAN_ROOTS = ("sparkdl_tpu", "sparkdl", "horovod", "benchmarks", "ci",
-              "examples", "bench.py", "__graft_entry__.py")
+              "examples", "chip_smoke.py", "__graft_entry__.py")
 
 _NAME_RE = re.compile(r"SPARKDL_TPU_[A-Z0-9_]*[A-Z0-9]")
 
 
-def _source_names():
-    names = set()
+def _source_files():
     for root in SCAN_ROOTS:
         path = REPO / root
-        files = [path] if path.is_file() else sorted(path.rglob("*.py"))
-        for f in files:
-            for m in _NAME_RE.finditer(f.read_text(errors="replace")):
-                names.add(m.group(0))
+        yield from [path] if path.is_file() else sorted(path.rglob("*.py"))
+
+
+def _source_names():
+    names = set()
+    for f in _source_files():
+        names.update(_NAME_RE.findall(f.read_text(errors="replace")))
     return names
 
 
@@ -42,28 +45,44 @@ def test_every_env_var_in_tree_is_registered():
         "(name, type, default, subsystem, tunable-or-not)")
 
 
-def test_no_dead_registry_entries():
-    """The reverse direction: a registered knob no source file
-    mentions is stale catalog — delete it or wire it. The registry
-    file itself is EXCLUDED from this scan (every registered name
-    appears there as a string literal, which would make the gate
-    vacuous)."""
+def _string_constants(tree):
+    """Every string constant of a module that is not a docstring."""
+    docstrings = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef)):
+            first = node.body[0] if node.body else None
+            if (isinstance(first, ast.Expr)
+                    and isinstance(first.value, ast.Constant)
+                    and isinstance(first.value.value, str)):
+                docstrings.add(id(first.value))
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+                and id(node) not in docstrings):
+            yield node.value
+
+
+def test_every_registered_knob_has_a_reader():
+    """The reverse direction: a registered name that no code under
+    the source roots holds as a string (a docstring or a comment is a
+    mention, not a reader) configures nothing: delete it from the
+    registry. The registry file itself is excluded (every registered
+    name is a string literal there), and so is the chaos family, whose
+    names are composed at the injection sites."""
     registry_file = (REPO / "sparkdl_tpu" / "utils"
                      / "knobs.py").resolve()
-    in_tree = set()
-    for root in SCAN_ROOTS:
-        path = REPO / root
-        files = [path] if path.is_file() else sorted(path.rglob("*.py"))
-        for f in files:
-            if f.resolve() == registry_file:
-                continue
-            for m in _NAME_RE.finditer(f.read_text(errors="replace")):
-                in_tree.add(m.group(0))
+    read = set()
+    for f in _source_files():
+        if f.resolve() == registry_file:
+            continue
+        tree = ast.parse(f.read_text(errors="replace"))
+        for text in _string_constants(tree):
+            read.update(_NAME_RE.findall(text))
     dead = sorted(
         kb.name for kb in knobs.all_knobs()
-        if kb.name not in in_tree and kb.subsystem != "chaos"
+        if kb.name not in read and kb.subsystem != "chaos"
     )
-    assert not dead, f"registered knobs never read in the tree: {dead}"
+    assert not dead, f"registered knobs that nothing reads: {dead}"
 
 
 def test_tunable_knobs_documented_in_performance_docs():
@@ -85,7 +104,7 @@ def test_registry_shape():
             assert kb.trial_values, (
                 f"{kb.name}: tunable knobs must declare trial_values")
         for bench in kb.benches:
-            assert bench in ("cpu-proxy", "serve", "gbdt", "attention")
+            assert bench in ("serve", "gbdt", "attention")
 
 
 def test_prefix_family_membership():
@@ -125,14 +144,14 @@ def test_read_int_and_bool_helpers():
 
 
 def test_tunable_bench_filter():
-    cpu = {kb.name for kb in knobs.tunable_knobs("cpu-proxy")}
-    assert "SPARKDL_TPU_LOSS_CHUNK" in cpu
-    assert "SPARKDL_TPU_GBDT_MAX_BINS" not in cpu
+    serve = {kb.name for kb in knobs.tunable_knobs("serve")}
+    assert "SPARKDL_TPU_SERVE_DECODE_CHUNK" in serve
+    assert "SPARKDL_TPU_GBDT_MAX_BINS" not in serve
     # measurement-mode selectors are never part of the search space
-    assert "SPARKDL_TPU_BENCH_NO_DONATE" not in cpu
+    assert "SPARKDL_TPU_BENCH_TINY" not in serve
     gbdt = {kb.name for kb in knobs.tunable_knobs("gbdt")}
     assert "SPARKDL_TPU_GBDT_MAX_BINS" in gbdt
     attn = {kb.name for kb in knobs.tunable_knobs("attention")}
     assert {"SPARKDL_TPU_FLASH_BLOCK_Q",
             "SPARKDL_TPU_FLASH_BLOCK_KV"} <= attn
-    assert "SPARKDL_TPU_LOSS_CHUNK" not in attn
+    assert "SPARKDL_TPU_SERVE_DECODE_CHUNK" not in attn
