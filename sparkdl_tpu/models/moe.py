@@ -19,7 +19,9 @@ Beyond-reference capability (the reference scales data only, SURVEY.md
   deployment's it holds, routes over all of them, sorts the (token,
   pick) pairs by expert and runs one grouped product a projection
   over the pairs that land here. No token is dropped whatever the
-  imbalance, and work goes with the pairs, not with the experts held.
+  imbalance, and work goes with the pairs, not with the experts held:
+  the products and, on a TPU, the rows' way out and back
+  (:mod:`sparkdl_tpu.ops.pallas.moe_rows`) visit only the pairs held.
   This is the one the on-chip benchmark measures
   (``nemotron3super-lora-train``, PERF.md); the exchange that would
   bring the other chips' tokens here is not built.
@@ -36,6 +38,7 @@ import numpy as np
 
 from sparkdl_tpu import observe
 from sparkdl_tpu.models.llama import _dense
+from sparkdl_tpu.ops._dispatch import use_pallas as _use_pallas
 from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
 
@@ -317,11 +320,94 @@ def expert_parallel_moe_a2a(mesh, cfg, *, axis_name="expert",
 #
 # The (token, pick) pairs are sorted by expert, the pairs of experts held
 # elsewhere last. `order[r]` is the pair that lies at row r of the sorted
-# buffer and `inverse` undoes it. Rows past the pairs that land here are
-# never computed by the grouped products, forward or backward: `here`
-# masks them where they come back to their tokens, in both directions.
-# Both directions are gathers: a scatter-add of 180,000 rows is what JAX
-# would otherwise derive, and the TPU runs that a row at a time.
+# buffer; the first `n = counts.sum()` rows are the pairs that land here,
+# and only they are numbers: the grouped products compute no row past `n`,
+# forward or backward.
+#
+# Rows move between tokens and the buffer in one of two ways, decided by
+# what the code can observe (:func:`dispatch_path`):
+#
+# - "pallas": the kernels of `ops/pallas/moe_rows.py`. A take writes
+#   `rows[r] = v[token of r]` for r < n and an add sums `w[r] * y[r]` into
+#   the token of r for r < n; each is the other's gradient. Rows past `n`
+#   are neither read nor written: the work follows `n`, not tokens x picks.
+# - "jnp": plain gathers over all tokens x picks rows in both directions
+#   (`inverse` undoes `order`), `here` masking what comes back from a row
+#   past `n` (a select, so that whatever lies there does no harm). Gathers,
+#   because a scatter-add of 180,000 rows is what JAX would otherwise
+#   derive, and the TPU runs that a row at a time.
+
+
+def dispatch_path(tokens, latent, interpret=None):
+    """Which way the rows of a layer of (tokens, latent) move: "pallas"
+    on a TPU for the shapes the kernels take (interpreted, for tests,
+    whatever the backend), else "jnp"."""
+    from sparkdl_tpu.ops.pallas.moe_rows import takes_shape
+
+    if interpret is None and not _use_pallas():
+        return "jnp"
+    taken = takes_shape(tokens, latent, tiled=not interpret)
+    return "pallas" if taken else "jnp"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take(v, tok, n, static):
+    """(tokens, d) -> (tokens x picks, d) sorted by expert, the first
+    `n` rows of it; `tok[r]` is row r's token. `static`: ``(tile,
+    interpret)`` of the kernels."""
+    from sparkdl_tpu.ops.pallas.moe_rows import take_rows
+
+    tile, interpret = static
+    return take_rows(v, tok, n, tile=tile, interpret=interpret)
+
+
+def _take_fwd(v, tok, n, static):
+    return _take(v, tok, n, static), (tok, n, v.shape[0])
+
+
+def _take_bwd(static, res, g):
+    from sparkdl_tpu.ops.pallas.moe_rows import add_rows
+
+    tile, interpret = static
+    tok, n, tokens = res
+    d_v = add_rows(g, tok, jnp.ones(tok.shape, jnp.float32), n,
+                   tokens=tokens, tile=tile, interpret=interpret)
+    return d_v.astype(g.dtype), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _add(y, weights, w_row, here, order, tok, n, static):
+    """(tokens x picks, d) sorted by expert -> (tokens, d) float32: each
+    token's picks held here, weighted and summed; of `y` the first `n`
+    rows are read. `w_row` is `weights` in the rows' order, as the sort
+    carried them there: the gradient comes to `weights`."""
+    from sparkdl_tpu.ops.pallas.moe_rows import add_rows
+
+    tile, interpret = static
+    return add_rows(y, tok, w_row, n, tokens=here.shape[0], tile=tile,
+                    interpret=interpret)
+
+
+def _add_fwd(y, weights, w_row, here, order, tok, n, static):
+    return (_add(y, weights, w_row, here, order, tok, n, static),
+            (y, w_row, here, order, tok, n))
+
+
+def _add_bwd(static, res, g):
+    from sparkdl_tpu.ops.pallas.moe_rows import take_rows
+
+    tile, interpret = static
+    y, w_row, here, order, tok, n = res
+    d_y, dots = take_rows(g, tok, n, w_row, y, tile=tile, interpret=interpret)
+    # back to the pairs' order by a sort on the pair each row holds: a
+    # gather of 180,000 single numbers takes the TPU six times as long
+    _, dots = jax.lax.sort((order, dots), num_keys=1)
+    d_weights = jnp.where(here, dots.reshape(here.shape), 0)
+    return (d_y, d_weights, jnp.zeros_like(w_row), None, None, None, None)
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+_add.defvjp(_add_fwd, _add_bwd)
 
 
 @jax.custom_vjp
@@ -361,7 +447,7 @@ _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
-def latent_experts(v, idx, weights, w_up, w_down, held):
+def latent_experts(v, idx, weights, w_up, w_down, held, interpret=None):
     """The routed experts' part of a layer that THIS chip's experts
     give: ``sum_k weights_k * e_k(v)`` over the picks whose expert lies
     in ``held``, with ``e(v) = relu(v @ w_up)^2 @ w_down``.
@@ -373,28 +459,48 @@ def latent_experts(v, idx, weights, w_up, w_down, held):
         latent): the experts held.
     :param held: ``(first, count)``: experts ``first .. first + count
         - 1`` live here.
+    :param interpret: None: :func:`dispatch_path` decides how the rows
+        move; True: the kernels interpreted (tests).
     :returns: ``(out (tokens, latent), counts (count,))``, the rows
         each held expert received. The buffer has tokens x picks rows
         whatever the routing, so no pair is ever dropped; the grouped
-        products visit ``counts.sum()`` of them.
+        products visit ``counts.sum()`` of them, and on the "pallas"
+        path so do the row movements around them.
     """
+    from sparkdl_tpu.ops.pallas.moe_rows import ROWS_TILE
+
     first, count = held
     tokens, picks = idx.shape
+    kernels = dispatch_path(tokens, v.shape[1], interpret) == "pallas"
+    static = (ROWS_TILE, bool(interpret))
     with jax.named_scope("sparkdl.moe.dispatch"):
         local = idx - first
         here = (local >= 0) & (local < count)
         key = jnp.where(here, local, count).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        inverse = jnp.argsort(order)
         # a compare and a sum, not a scatter-add of every pair
         counts = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
-        rows = _rows_out(v, here, order, inverse)
+        n = counts.sum()
+        if kernels:
+            # one sort carries each pair's number and weight to its row
+            _, order, w_row = jax.lax.sort(
+                (key, jnp.arange(key.shape[0], dtype=jnp.int32),
+                 jax.lax.stop_gradient(weights).reshape(-1)),
+                num_keys=1, is_stable=True)
+            tok = order // picks
+            rows = _take(v, tok, n, static)
+        else:
+            order = jnp.argsort(key, stable=True)
+            inverse = jnp.argsort(order)
+            rows = _rows_out(v, here, order, inverse)
     with jax.named_scope("sparkdl.moe.experts"):
         hidden = jnp.square(jax.nn.relu(grouped_matmul(rows, w_up, counts)))
         rows = grouped_matmul(hidden, w_down, counts)
     with jax.named_scope("sparkdl.moe.dispatch"):
-        back = _rows_back(rows, here, order, inverse)
-        out = (back.astype(jnp.float32) * weights[..., None]).sum(1)
+        if kernels:
+            out = _add(rows, weights, w_row, here, order, tok, n, static)
+        else:
+            back = _rows_back(rows, here, order, inverse)
+            out = (back.astype(jnp.float32) * weights[..., None]).sum(1)
     return out.astype(v.dtype), counts
 
 
@@ -430,7 +536,7 @@ class LatentMoE(nn.Module):
         # once a traced layer: the share, and the buffer it is built with
         observe.inc("moe.dispatch", held=count, of=cfg.n_routed_experts,
                     picks=cfg.top_k, rows=x.shape[0] * cfg.top_k,
-                    product="gmm")
+                    product="gmm", path=dispatch_path(x.shape[0], cfg.latent))
         routed, counts = latent_experts(
             dense(cfg.latent, "latent_in")(x), idx, weights,
             w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),

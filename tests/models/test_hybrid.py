@@ -12,7 +12,7 @@ import pytest
 
 from chipbench import reference_hybrid
 from sparkdl_tpu import observe
-from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask
+from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask, moe
 from sparkdl_tpu.models.moe import LatentMoE, latent_experts, route_sigmoid
 from sparkdl_tpu.parallel.train import cross_entropy_loss, make_lm_loss_fn
 
@@ -162,35 +162,142 @@ def dense_masked(v, idx, weights, w_up, w_down, held):
     return out
 
 
-def test_sparse_dispatch_is_the_dense_layer_under_heavy_imbalance():
-    """A router biased so that one expert held takes nearly every
-    token and another none: no pair is dropped, forward or backward."""
-    tokens, k, latent, d_ff, held = 96, 3, 32, 48, (4, 8)
+def routed(held, bias, latent, tokens=96, k=3, d_ff=48, n_experts=16):
+    """(v, weights, idx, w_up, w_down) of a layer of `tokens` that holds
+    `held`, its router biased by `bias` (expert -> added score)."""
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     v = jax.random.normal(keys[0], (tokens, latent))
-    w_up = jax.random.normal(keys[1], (held[1], latent, d_ff)) * 0.2
+    # (the hidden layer as large at any width as at 32)
+    w_up = jax.random.normal(keys[1], (held[1], latent, d_ff)) * (
+        0.2 * (32 / latent) ** 0.5)
     w_down = jax.random.normal(keys[2], (held[1], d_ff, latent)) * 0.2
-    bias = jnp.zeros(16).at[6].set(10.0).at[9].set(-10.0)
+    scores = jnp.zeros(n_experts)
+    for expert, add in bias.items():
+        scores = scores.at[expert].set(add)
     idx, weights = route_sigmoid(
-        jax.random.normal(keys[3], (tokens, 16)), bias, k, scale=2.5)
+        jax.random.normal(keys[3], (tokens, n_experts)), scores, k, scale=2.5)
+    return v, weights, idx, w_up, w_down
 
-    def sparse(v, weights):
-        return latent_experts(v, idx, weights, w_up, w_down, held)
+
+def out_and_gradients(layer, v, weights):
+    """A layer's output and the gradients in `v` and `weights` of a
+    loss that weighs every element differently."""
+    out, counts = jax.jit(layer)(v, weights)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(layer(*a)[0])),
+                             argnums=(0, 1)))(v, weights)
+    return out, grads, counts
+
+
+# tokens 96 x picks 3 = 288 rows of the sorted buffer, in tiles of 32
+DISPATCH = {
+    # one expert held takes nearly every token and another none
+    "heavy imbalance": dict(held=(4, 8), bias={6: 10.0, 9: -10.0},
+                            latent=128, path="pallas"),
+    "every pair held": dict(held=(0, 16), bias={}, latent=128, path="pallas"),
+    "no pair held": dict(held=(12, 4), bias={e: -10.0 for e in range(12, 16)},
+                         latent=128, path="pallas"),
+    "n off the tile": dict(held=(2, 5), bias={}, latent=256, path="pallas"),
+    "width off the tiling": dict(held=(4, 8), bias={6: 10.0}, latent=32,
+                                 path="jnp"),
+}
+
+
+@pytest.mark.parametrize("case", DISPATCH)
+def test_sparse_dispatch_is_the_dense_layer_under_heavy_imbalance(
+        case, monkeypatch):
+    """Whatever the routing sends here, all of it, none of it, or nearly
+    all of it to one expert, no pair is dropped, forward or backward:
+    the rows' kernels (interpreted), the plain gathers and every expert
+    held on every token give one layer. A width the kernels do not take
+    falls to the plain gathers."""
+    from sparkdl_tpu.ops.pallas import moe_rows
+
+    monkeypatch.setattr(moe_rows, "ROWS_TILE", 32)
+    held, bias, latent, path = (DISPATCH[case][k] for k in (
+        "held", "bias", "latent", "path"))
+    v, weights, idx, w_up, w_down = routed(held, bias, latent)
+    tokens, k = idx.shape
+    assert moe.dispatch_path(tokens, latent, interpret=True) == path
+    assert moe.dispatch_path(tokens, latent) == "jnp"        # off the TPU
+
+    def sparse(interpret):
+        return lambda v, weights: latent_experts(
+            v, idx, weights, w_up, w_down, held, interpret=interpret)
 
     with HIGHEST:
-        (out, counts) = jax.jit(sparse)(v, weights)
-        want = dense_masked(v, idx, weights, w_up, w_down, held)
-        got_g = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(sparse(*a)[0])),
-                                 argnums=(0, 1)))(v, weights)
-        want_g = jax.grad(lambda *a: jnp.sum(jnp.sin(dense_masked(
-            *a[:1], idx, a[1], w_up, w_down, held))), argnums=(0, 1))(
-                v, weights)
-    assert counts[6 - 4] == tokens and counts[9 - 4] == 0
-    here = (idx >= 4) & (idx < 12)
-    assert int(counts.sum()) == int(here.sum()) < tokens * k
+        out, got_g, counts = out_and_gradients(sparse(True), v, weights)
+        plain, plain_g, plain_counts = out_and_gradients(
+            sparse(None), v, weights)
+        want, want_g, _ = out_and_gradients(
+            lambda v, weights: (dense_masked(
+                v, idx, weights, w_up, w_down, held), None), v, weights)
+    here = (idx >= held[0]) & (idx < held[0] + held[1])
+    n = int(counts.sum())
+    assert n == int(here.sum()) and (counts == plain_counts).all()
+    if case == "heavy imbalance":
+        assert counts[6 - 4] == tokens and counts[9 - 4] == 0
+        assert 0 < n < tokens * k
+    elif case == "every pair held":
+        assert n == tokens * k and n % 32 == 0       # every tile live
+    elif case == "no pair held":
+        assert n == 0
+    elif case == "n off the tile":
+        assert n % 32 and 32 < n < tokens * k - 32
     np.testing.assert_allclose(out, want, atol=2e-5)
-    for g, w in zip(got_g, want_g):
-        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5)
+    np.testing.assert_allclose(plain, want, atol=2e-5)
+    for g, p, w in zip(got_g, plain_g, want_g):
+        # (a sum over the width that nearly cancels keeps the rounding
+        # of its terms, which are as large as the largest gradient)
+        atol = 2e-5 + 2e-6 * float(np.abs(w).max())
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=atol)
+        np.testing.assert_allclose(p, w, rtol=1e-4, atol=atol)
+
+
+@jax.custom_vjp
+def nan_past(x, n):
+    """`x` with NaN in every row from `n` on, its cotangent too."""
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < n, x, jnp.nan)
+
+
+nan_past.defvjp(lambda x, n: (nan_past(x, n), n),
+                lambda n, g: (nan_past(g, n), None))
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["pallas", "jnp"])
+def test_what_the_sorted_buffer_holds_past_the_pairs_is_never_used(
+        interpret, monkeypatch):
+    """The grouped products compute no row past ``counts.sum()``: with
+    NaN there, in what the second product gives and in what comes back
+    from the first, the layer's output and gradients are what they
+    were."""
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+    from sparkdl_tpu.ops.pallas import moe_rows
+
+    monkeypatch.setattr(moe_rows, "ROWS_TILE", 32)
+    held, latent = (4, 8), 128
+    v, weights, idx, w_up, w_down = routed(held, {6: 10.0, 9: -10.0}, latent)
+
+    def layer(v, weights):
+        return latent_experts(v, idx, weights, w_up, w_down, held,
+                              interpret=interpret)
+
+    def poisoned(lhs, rhs, counts):
+        """The product with NaN past the pairs in its result, and (the
+        first product, which takes the rows) in what it hands back."""
+        n = counts.sum()
+        if lhs.shape[1] == latent:
+            lhs = nan_past(lhs, n)
+        return nan_past(grouped_matmul(lhs, rhs, counts), n)
+
+    with HIGHEST:
+        clean, clean_g, counts = out_and_gradients(layer, v, weights)
+        monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+        got, got_g, _ = out_and_gradients(layer, v, weights)
+    assert 0 < int(counts.sum()) < idx.size
+    for a, b in zip((got, *got_g), (clean, *clean_g)):
+        assert np.isfinite(a).all()
+        np.testing.assert_array_equal(a, b)
 
 
 def test_four_shares_of_a_layer_add_up_to_the_uncut_reference():
@@ -243,4 +350,4 @@ def test_dispatch_is_counted_once_a_traced_layer(monkeypatch, tmp_path):
         c["value"] for c in before if c["name"] == "moe.dispatch") == 2
     assert counted[0]["labels"] == {
         "held": "16", "of": "32", "picks": "3", "rows": "30",
-        "product": "gmm"}
+        "product": "gmm", "path": "jnp"}

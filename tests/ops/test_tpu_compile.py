@@ -216,6 +216,48 @@ def test_ssd_scan(one_chip, backward):
     assert sum("sparkdl_ssd_bwd" in k for k in kernels) == backward
 
 
+@pytest.mark.parametrize("backward", [False, True])
+def test_moe_rows(one_chip, backward):
+    """The rows' way out and back alone at the hybrid cell's shape,
+    8192 tokens x 22 picks of 1024: a take and an add, and behind them
+    the gradients, which are the other kernel each (the way back's with
+    its weights and its dots)."""
+    from sparkdl_tpu.models.moe import _add, _take
+    from sparkdl_tpu.ops.pallas.moe_rows import ROWS_TILE, takes_shape
+
+    tokens, picks, width = 8192, 22, 1024
+    assert takes_shape(tokens, width)
+    static = (ROWS_TILE, False)
+
+    def fwd(v, y, weights, w_row, here, order, n):
+        tok = order // picks
+        return (_take(v, tok, n, static),
+                _add(y, weights, w_row, here, order, tok, n, static))
+
+    def fwd_bwd(v, y, weights, *plan):
+        def loss(*a):
+            out = fwd(*a, *plan)
+            return sum(x.astype(jnp.float32).sum() for x in out), out
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            v, y, weights)
+
+    pairs = _shape(one_chip, (tokens * picks,), jnp.int32)
+    compiled = _compile(
+        fwd_bwd if backward else fwd,
+        _shape(one_chip, (tokens, width), jnp.bfloat16),
+        _shape(one_chip, (tokens * picks, width), jnp.bfloat16),
+        _shape(one_chip, (tokens, picks), jnp.float32),
+        _shape(one_chip, (tokens * picks,), jnp.float32),
+        _shape(one_chip, (tokens, picks), jnp.bool_), pairs,
+        _shape(one_chip, (), jnp.int32))
+    kernels = [line.split(" = ")[0] for line in compiled.as_text().split("\n")
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 2 * (1 + backward)
+    assert sum("sparkdl_moe_take" in k for k in kernels) == 1 + backward
+    assert sum("sparkdl_moe_add" in k for k in kernels) == 1 + backward
+
+
 def test_train_step_carries_the_kernel_names(one_chip, monkeypatch):
     """One LoRA train step at depth 1 with remat: in the compiled
     program each flash kernel is an instruction named after its
@@ -262,18 +304,18 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
     Nemotron-3-Super's widths and the benchmark cell's 1 x 8192 tokens,
     the chip holding 128 of 512 routed experts: the scan's kernels and
     the sorted dispatch compile for the chip; the experts' products are
-    ragged-dot kernels over the (token, pick) buffer, and nothing in
-    the step has the shape of every expert held on every token, nor of
-    every chunk's and head's decay."""
+    ragged-dot kernels over the (token, pick) buffer, the rows go out
+    and back by the take and add kernels and no gather makes the whole
+    buffer, and nothing in the step has the shape of every expert held
+    on every token, nor of every chunk's and head's decay."""
     import optax
 
-    from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask
+    from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask, moe
     from sparkdl_tpu.ops import attention, grouped_matmul, ssd
     from sparkdl_tpu.parallel.train import make_lm_loss_fn, make_train_step
 
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setattr(grouped_matmul, "_use_pallas", lambda: True)
-    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    for module in (attention, grouped_matmul, ssd, moe):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
     cfg = HybridConfig(
         pattern="ME*", vocab_size=4096, d_model=4096, n_heads=32,
         n_kv_heads=2, head_dim=128, ssm_heads=128, ssm_head_dim=64,
@@ -306,6 +348,17 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
     grouped = [s for s in stacks if grouped_matmul.NAME in s]
     assert len(grouped) == 6, stacks
     assert all("sparkdl.moe.experts" in s for s in grouped)
+    # the rows' way out and back, each the other's gradient: out in the
+    # forward, in the remat's forward again and (the way back's gradient)
+    # in the backward; back in the forward and (the way out's gradient) in
+    # the backward. The remat's forward needs no way back: nothing the
+    # backward reads lies behind it. All under the scope that
+    # `moe_dispatch_ms.train_hybrid` sums
+    moved = [s for s in stacks if "sparkdl_moe_take" in s
+             or "sparkdl_moe_add" in s]
+    assert sorted(s.split("/")[-2] for s in moved) == (
+        ["sparkdl_moe_add"] * 2 + ["sparkdl_moe_take"] * 3), moved
+    assert all("sparkdl.moe.dispatch" in s for s in moved)
     assert sum("sparkdl.attn" in s for s in stacks) == 4
     # the scan: forward, the remat's forward again and the backward, each
     # under the scope that the benchmark's readers sum
@@ -321,6 +374,9 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
             assert not re.search(r"f32\[[\d,]*128,128\]", line), line
     rows = 8192 * 22
     assert f"bf16[{rows},2688]" in text
+    for line in text.split("\n"):
+        if "sparkdl.moe.dispatch" in line and " gather(" in line:
+            assert f"[{rows},1024]" not in line.split(" gather(")[0], line
     for dense in ("[8192,128,2688]", "[128,8192,2688]", "[8192,2688,128]"):
         assert dense not in text
     for scope in ("sparkdl.ssm.scan", "sparkdl.ssm.conv", "sparkdl.moe.route",
