@@ -21,7 +21,9 @@ decode-only machinery):
 - :mod:`.convert` — HuggingFace Llama checkpoint import/export
 - :mod:`.moe` — expert-parallel MoE (psum-combine and a2a dispatch),
   and one chip's share of a layer's experts by sorted dispatch
+  (``relu2`` experts in a latent width, gated experts on the full one)
 - :mod:`.mamba2` — the Mamba-2 state-space mixer (training)
+- :mod:`.mla` — multi-head latent attention, expanded form (training)
 """
 
 from sparkdl_tpu.models.bert import (  # noqa: F401

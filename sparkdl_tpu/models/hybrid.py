@@ -1,11 +1,20 @@
 """A decoder whose layers are a PATTERN: one mixer a layer, chosen by a
-letter (the ``nemotron_h`` family's ``hybrid_override_pattern``).
+letter. The ``nemotron_h`` family publishes its pattern
+(``hybrid_override_pattern``: ``M``, ``E``, ``*``); a family whose
+published layer is TWO residual steps, attention then feed-forward, is
+two letters a published layer (``glm4_moe_lite``: ``LD`` for a leading
+dense layer, ``LG`` for every other).
 
     M  a Mamba-2 state-space mixer       (:mod:`.mamba2`)
     E  routed experts in a latent width, and a shared expert
                                          (:class:`.moe.LatentMoE`)
     *  grouped-query causal attention    (:class:`.llama.Attention`,
        with no rotary embedding: the state-space layers carry the order)
+    L  multi-head latent attention, rope on a narrow part of a head
+                                         (:class:`.mla.LatentAttention`)
+    D  a dense gated MLP                 (:class:`.llama.MLP`)
+    G  gated routed experts on the full width, and a shared expert
+                                         (:class:`.moe.GatedMoE`)
 
 Every layer is ``x <- x + mixer(RMSNorm(x))``; then a final norm and the
 head. :class:`HybridDecoder` has :class:`.llama.Llama`'s call contract,
@@ -14,10 +23,11 @@ so :func:`sparkdl_tpu.parallel.train.make_lm_loss_fn` and
 
 Supported: LoRA training of ONE chip's share of a deployment that
 divides each layer's routed experts and the vocabulary over chips
-(``experts_held``, ``vocab_size``). Not supported: serving (no recurrent
-state beside the cache), multi-token prediction, the exchange between
-the chips that share a layer. docs/hybrid.rst has the equations and the
-map from published keys.
+(``experts_held``, ``vocab_size``), or that holds them whole. Not
+supported: serving (no recurrent state and no latent cache beside the
+paged one; ``L`` runs its expanded form only), multi-token prediction,
+the exchange between the chips that share a layer. docs/hybrid.rst has
+the equations and the maps from published keys.
 """
 
 import dataclasses
@@ -27,27 +37,45 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.models.llama import Attention, LlamaConfig, RMSNorm
+from sparkdl_tpu.models.llama import MLP, Attention, LlamaConfig, RMSNorm
 from sparkdl_tpu.models.mamba2 import Mamba2Mixer
-from sparkdl_tpu.models.moe import LatentMoE
+from sparkdl_tpu.models.mla import LatentAttention
+from sparkdl_tpu.models.moe import GatedMoE, LatentMoE
 
-# published key -> field, for every key a mixer's shape is read from
+# ``model_type`` -> {published key -> field}, for every key a mixer's
+# shape is read from; a file without the key is ``nemotron_h``'s
 PUBLISHED = {
-    "vocab_size": "vocab_size", "hidden_size": "d_model",
-    "hybrid_override_pattern": "pattern",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "mamba_num_heads": "ssm_heads", "mamba_head_dim": "ssm_head_dim",
-    "n_groups": "ssm_groups", "ssm_state_size": "ssm_state",
-    "conv_kernel": "conv_kernel", "chunk_size": "chunk_size",
-    "time_step_min": "time_step_min", "time_step_max": "time_step_max",
-    "time_step_floor": "time_step_floor",
-    "num_experts_per_tok": "top_k", "moe_latent_size": "latent",
-    "moe_intermediate_size": "expert_d_ff",
-    "moe_shared_expert_intermediate_size": "shared_d_ff",
-    "routed_scaling_factor": "routed_scale", "norm_eps": "rms_eps",
+    "nemotron_h": {
+        "vocab_size": "vocab_size", "hidden_size": "d_model",
+        "hybrid_override_pattern": "pattern",
+        "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+        "head_dim": "head_dim",
+        "mamba_num_heads": "ssm_heads", "mamba_head_dim": "ssm_head_dim",
+        "n_groups": "ssm_groups", "ssm_state_size": "ssm_state",
+        "conv_kernel": "conv_kernel", "chunk_size": "chunk_size",
+        "time_step_min": "time_step_min", "time_step_max": "time_step_max",
+        "time_step_floor": "time_step_floor",
+        "num_experts_per_tok": "top_k", "moe_latent_size": "latent",
+        "moe_intermediate_size": "expert_d_ff",
+        "moe_shared_expert_intermediate_size": "shared_d_ff",
+        "routed_scaling_factor": "routed_scale", "norm_eps": "rms_eps",
+    },
+    # the pattern and the shared expert's width are BUILT from
+    # num_hidden_layers, first_k_dense_replace and n_shared_experts
+    "glm4_moe_lite": {
+        "vocab_size": "vocab_size", "hidden_size": "d_model",
+        "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+        "q_lora_rank": "q_rank", "kv_lora_rank": "kv_rank",
+        "qk_nope_head_dim": "qk_nope_dim", "qk_rope_head_dim": "qk_rope_dim",
+        "v_head_dim": "v_dim", "rope_theta": "rope_theta",
+        "intermediate_size": "dense_d_ff",
+        "num_experts_per_tok": "top_k",
+        "moe_intermediate_size": "expert_d_ff",
+        "routed_scaling_factor": "routed_scale", "rms_norm_eps": "rms_eps",
+    },
 }
-SCOPES = {"M": "sparkdl.ssm", "E": "sparkdl.moe", "*": "sparkdl.attn"}
+SCOPES = {"M": "sparkdl.ssm", "E": "sparkdl.moe", "*": "sparkdl.attn",
+          "L": "sparkdl.mla", "D": "sparkdl.mlp", "G": "sparkdl.moe"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +104,15 @@ class HybridConfig:
     expert_d_ff: int = 48
     shared_d_ff: int = 96
     routed_scale: float = 1.0
+    # latent attention: the ranks of the two latents, a head's part
+    # without and with position, and a head's values
+    q_rank: int = 48
+    kv_rank: int = 32
+    qk_nope_dim: int = 24
+    qk_rope_dim: int = 8
+    v_dim: int = 32
+    rope_theta: float = 10000.0
+    dense_d_ff: int = 128
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = False
@@ -85,43 +122,66 @@ class HybridConfig:
     lora_targets: Sequence[str] = ("in_proj", "out_proj", "q_proj", "v_proj")
 
     def __post_init__(self):
-        if not self.pattern or set(self.pattern) - set(SCOPES):
+        """A mixer's shape is checked where the pattern has its
+        letter."""
+        has = set(self.pattern)
+        if not has or has - set(SCOPES):
             raise ValueError(
                 f"pattern {self.pattern!r}: one letter a layer, of "
                 f"{sorted(SCOPES)}")
-        if self.head_dim * self.n_heads != self.d_model:
+        if "*" in has and self.head_dim * self.n_heads != self.d_model:
             raise ValueError(
                 "llama.Attention's heads are d_model / n_heads wide: "
                 f"head_dim={self.head_dim} x n_heads={self.n_heads} "
                 f"is not d_model={self.d_model}")
         first, count = self.experts_held
-        if not (0 <= first and count > 0
+        if has & {"E", "G"} and not (
+                0 <= first and count > 0
                 and first + count <= self.n_routed_experts):
             raise ValueError(
                 f"experts_held={self.experts_held} is no share of "
                 f"n_routed_experts={self.n_routed_experts}")
-        if self.ssm_heads % self.ssm_groups:
+        if "M" in has and self.ssm_heads % self.ssm_groups:
             raise ValueError(
                 f"ssm_heads={self.ssm_heads} not divisible by "
                 f"ssm_groups={self.ssm_groups}")
+        if "L" in has and self.qk_rope_dim % 2:
+            raise ValueError(
+                f"qk_rope_dim={self.qk_rope_dim}: rope turns pairs")
+        if ("L" in has and self.attention == "flash"
+                and self.v_dim != self.qk_nope_dim + self.qk_rope_dim):
+            raise ValueError(
+                "the flash kernels take one head size: "
+                f"v_dim={self.v_dim} is not qk_nope_dim + qk_rope_dim="
+                f"{self.qk_nope_dim + self.qk_rope_dim}")
 
     @classmethod
     def from_published(cls, config, **kw):
-        """From a ``nemotron_h`` ``config.json``'s keys (a dict). Where
-        the file holds a chip's share, ``n_routed_experts`` there is the
-        experts held: pass the router's width and ``experts_held``."""
-        fields = {field: config[key] for key, field in PUBLISHED.items()}
+        """From a ``config.json``'s keys (a dict), by the map of its
+        ``model_type``. Where the file holds a chip's share,
+        ``n_routed_experts`` there is the experts held: pass the
+        router's width and ``experts_held``."""
+        kind = config.get("model_type", "nemotron_h")
+        fields = {field: config[key] for key, field in PUBLISHED[kind].items()}
+        if kind == "glm4_moe_lite":
+            dense = config["first_k_dense_replace"]
+            fields["pattern"] = "LD" * dense + "LG" * (
+                config["num_hidden_layers"] - dense)
+            fields["shared_d_ff"] = (config["n_shared_experts"]
+                                     * config["moe_intermediate_size"])
         held = config["n_routed_experts"]
         return cls(**{"n_routed_experts": held, "experts_held": (0, held),
                       **fields, **kw})
 
     @property
     def attn(self):
-        """What ``llama.Attention`` and ``llama._dense`` read."""
+        """What ``llama.Attention``, ``llama.MLP`` and ``llama._dense``
+        read."""
         return LlamaConfig(
             vocab_size=self.vocab_size, d_model=self.d_model,
             n_layers=len(self.pattern), n_heads=self.n_heads,
-            n_kv_heads=self.n_kv_heads, rms_eps=self.rms_eps,
+            n_kv_heads=self.n_kv_heads, d_ff=self.dense_d_ff,
+            rms_eps=self.rms_eps,
             dtype=self.dtype, attention=self.attention,
             lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
             lora_targets=tuple(self.lora_targets))
@@ -140,6 +200,12 @@ class HybridLayer(nn.Module):
                 return x + Mamba2Mixer(cfg, name="mamba")(h)
             if self.kind == "E":
                 return x + LatentMoE(cfg, name="moe")(h)
+            if self.kind == "L":
+                return x + LatentAttention(cfg, name="mla")(h)
+            if self.kind == "D":
+                return x + MLP(cfg.attn, name="mlp")(h)
+            if self.kind == "G":
+                return x + GatedMoE(cfg, name="moe")(h)
             return x + Attention(cfg.attn, name="attn")(
                 h, None, None, None)
 

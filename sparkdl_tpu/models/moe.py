@@ -14,16 +14,19 @@ Beyond-reference capability (the reference scales data only, SURVEY.md
   the ICI to their expert's shard in fixed-capacity buffers
   (Switch/Mixtral execution model) — FLOPs scale with capacity, the
   sparse-MoE point; a token past an expert's capacity is dropped.
-- sorted dispatch on ONE chip's share (:func:`latent_experts`, the
-  layer :class:`LatentMoE`): the layer is told which experts of the
-  deployment's it holds, routes over all of them, sorts the (token,
-  pick) pairs by expert and runs one grouped product a projection
-  over the pairs that land here. No token is dropped whatever the
-  imbalance, and work goes with the pairs, not with the experts held:
-  the products and, on a TPU, the rows' way out and back
-  (:mod:`sparkdl_tpu.ops.pallas.moe_rows`) visit only the pairs held.
-  This is the one the on-chip benchmark measures
-  (``nemotron3super-lora-train``, PERF.md); the exchange that would
+- sorted dispatch on ONE chip's share (:func:`sorted_experts`): the
+  layer is told which experts of the deployment's it holds, routes
+  over all of them, sorts the (token, pick) pairs by expert and runs
+  two grouped products over the pairs that land here. No token is
+  dropped whatever the imbalance, and work goes with the pairs, not
+  with the experts held: the products and, on a TPU, the rows' way out
+  and back (:mod:`sparkdl_tpu.ops.pallas.moe_rows`) visit only the
+  pairs held. It serves TWO expert forms (``FORMS``): ``relu2``
+  experts in a latent width (:class:`LatentMoE`,
+  ``nemotron3super-lora-train``) and gated ``silu(gate) * up`` experts
+  on the full width, gate and up fused into the first product
+  (:class:`GatedMoE`, ``glm47flash-lora-train``). These are the ones
+  the on-chip benchmark measures (PERF.md); the exchange that would
   bring the other chips' tokens here is not built.
 """
 
@@ -447,22 +450,38 @@ _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
-def latent_experts(v, idx, weights, w_up, w_down, held, interpret=None):
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def _gated(h):
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+# what lies between an expert's two products: name -> (rows, n) ->
+# (rows, d_ff). "gated" takes gate | up side by side from ONE product
+FORMS = {"relu2": _relu2, "gated": _gated}
+
+
+def sorted_experts(v, idx, weights, w_in, w_down, held, interpret=None,
+                   form="relu2"):
     """The routed experts' part of a layer that THIS chip's experts
     give: ``sum_k weights_k * e_k(v)`` over the picks whose expert lies
-    in ``held``, with ``e(v) = relu(v @ w_up)^2 @ w_down``.
+    in ``held``, with ``e(v) = FORMS[form](v @ w_in) @ w_down``.
 
-    :param v: (tokens, latent) tokens in the experts' latent width.
+    :param v: (tokens, d) tokens in the experts' width.
     :param idx, weights: (tokens, picks) of :func:`route_sigmoid` over
         ALL the deployment's experts.
-    :param w_up, w_down: (count, latent, d_ff) and (count, d_ff,
-        latent): the experts held.
+    :param w_in, w_down: the experts held: (count, d, d_ff) and
+        (count, d_ff, d); for ``form="gated"`` ``w_in`` is (count, d,
+        2 x d_ff), an expert's gate and up matrices side by side.
     :param held: ``(first, count)``: experts ``first .. first + count
         - 1`` live here.
     :param interpret: None: :func:`dispatch_path` decides how the rows
         move; True: the kernels interpreted (tests).
-    :returns: ``(out (tokens, latent), counts (count,))``, the rows
-        each held expert received. The buffer has tokens x picks rows
+    :returns: ``(out (tokens, d), counts (count,))``, the rows each
+        held expert received. The buffer has tokens x picks rows
         whatever the routing, so no pair is ever dropped; the grouped
         products visit ``counts.sum()`` of them, and on the "pallas"
         path so do the row movements around them.
@@ -493,7 +512,7 @@ def latent_experts(v, idx, weights, w_up, w_down, held, interpret=None):
             inverse = jnp.argsort(order)
             rows = _rows_out(v, here, order, inverse)
     with jax.named_scope("sparkdl.moe.experts"):
-        hidden = jnp.square(jax.nn.relu(grouped_matmul(rows, w_up, counts)))
+        hidden = FORMS[form](grouped_matmul(rows, w_in, counts))
         rows = grouped_matmul(hidden, w_down, counts)
     with jax.named_scope("sparkdl.moe.dispatch"):
         if kernels:
@@ -504,13 +523,37 @@ def latent_experts(v, idx, weights, w_up, w_down, held, interpret=None):
     return out.astype(v.dtype), counts
 
 
+# ``relu2`` experts: (v, idx, weights, w_up, w_down, held, interpret=None)
+latent_experts = sorted_experts
+
+
+def _route(module, x):
+    """``(idx, weights)`` of the tokens `x` over ALL of ``cfg``'s routed
+    experts: the router and its selection bias, as `module`'s own."""
+    cfg = module.cfg
+    with jax.named_scope("sparkdl.moe.route"):
+        logits = nn.Dense(cfg.n_routed_experts, use_bias=False,
+                          dtype=jnp.float32, name="router")(x)
+        bias = module.param("router_bias", nn.initializers.normal(0.02),
+                            (cfg.n_routed_experts,))
+        return route_sigmoid(logits, bias, cfg.top_k, cfg.routed_scale)
+
+
+def _held(module, idx, counts):
+    """Sow what each held expert received, and who chose whom: read with
+    ``mutable=["intermediates"]`` (the load's shape; the check's
+    picks)."""
+    module.sow("intermediates", "expert_counts", counts)
+    module.sow("intermediates", "picks", idx)
+
+
 class LatentMoE(nn.Module):
     """Routed experts in a latent width beside a shared expert
     (Nemotron-3's ``E`` layer): sigmoid routing over
     ``n_routed_experts``, non-gated ``relu^2`` experts between a
     projection into ``latent`` and one back, the shared expert on the
     full width. Holds ``experts_held`` of the routed experts and adds
-    what THEY give (:func:`latent_experts`). ``cfg`` is a
+    what THEY give (:func:`sorted_experts`). ``cfg`` is a
     :class:`~sparkdl_tpu.models.hybrid.HybridConfig`."""
 
     cfg: Any
@@ -521,13 +564,7 @@ class LatentMoE(nn.Module):
         dense = functools.partial(_dense, cfg.attn)
         _, count = cfg.experts_held
         x = u.reshape(-1, u.shape[-1])
-        with jax.named_scope("sparkdl.moe.route"):
-            logits = nn.Dense(cfg.n_routed_experts, use_bias=False,
-                              dtype=jnp.float32, name="router")(x)
-            bias = self.param("router_bias", nn.initializers.normal(0.02),
-                              (cfg.n_routed_experts,))
-            idx, weights = route_sigmoid(
-                logits, bias, cfg.top_k, cfg.routed_scale)
+        idx, weights = _route(self, x)
         w_up = self.param("w_up", nn.initializers.lecun_normal(batch_axis=0),
                           (count, cfg.latent, cfg.expert_d_ff))
         w_down = self.param("w_down",
@@ -536,17 +573,57 @@ class LatentMoE(nn.Module):
         # once a traced layer: the share, and the buffer it is built with
         observe.inc("moe.dispatch", held=count, of=cfg.n_routed_experts,
                     picks=cfg.top_k, rows=x.shape[0] * cfg.top_k,
-                    product="gmm", path=dispatch_path(x.shape[0], cfg.latent))
-        routed, counts = latent_experts(
+                    product="gmm", form="relu2",
+                    path=dispatch_path(x.shape[0], cfg.latent))
+        routed, counts = sorted_experts(
             dense(cfg.latent, "latent_in")(x), idx, weights,
             w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),
             cfg.experts_held)
-        # what each held expert received, and who chose whom: read with
-        # mutable=["intermediates"] (the load's shape; the check's picks)
-        self.sow("intermediates", "expert_counts", counts)
-        self.sow("intermediates", "picks", idx)
+        _held(self, idx, counts)
         out = dense(u.shape[-1], "latent_out")(routed)
         with jax.named_scope("sparkdl.moe.shared"):
-            shared = dense(u.shape[-1], "shared_down")(jnp.square(
-                jax.nn.relu(dense(cfg.shared_d_ff, "shared_up")(x))))
+            shared = dense(u.shape[-1], "shared_down")(_relu2(
+                dense(cfg.shared_d_ff, "shared_up")(x)))
         return (out + shared).reshape(u.shape)
+
+
+class GatedMoE(nn.Module):
+    """Gated routed experts on the full width beside a shared expert of
+    the same form (GLM-4.7-Flash's ``G`` layer, the DeepSeek-V3 family's
+    expert layer with one group): sigmoid routing over
+    ``n_routed_experts``; an expert is ``W_down (silu(W_gate x) * W_up
+    x)``, its gate and up matrices held side by side (``w_gate_up``) so
+    that the layer is still two grouped products a pass. Holds
+    ``experts_held`` of the routed experts and adds what THEY give
+    (:func:`sorted_experts`). ``cfg`` is a
+    :class:`~sparkdl_tpu.models.hybrid.HybridConfig`."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        dense = functools.partial(_dense, cfg.attn)
+        _, count = cfg.experts_held
+        d = u.shape[-1]
+        x = u.reshape(-1, d)
+        idx, weights = _route(self, x)
+        w_gate_up = self.param(
+            "w_gate_up", nn.initializers.lecun_normal(batch_axis=0),
+            (count, d, 2 * cfg.expert_d_ff))
+        w_down = self.param("w_down",
+                            nn.initializers.lecun_normal(batch_axis=0),
+                            (count, cfg.expert_d_ff, d))
+        observe.inc("moe.dispatch", held=count, of=cfg.n_routed_experts,
+                    picks=cfg.top_k, rows=x.shape[0] * cfg.top_k,
+                    product="gmm", form="gated",
+                    path=dispatch_path(x.shape[0], d))
+        routed, counts = sorted_experts(
+            x, idx, weights, w_gate_up.astype(cfg.dtype),
+            w_down.astype(cfg.dtype), cfg.experts_held, form="gated")
+        _held(self, idx, counts)
+        with jax.named_scope("sparkdl.moe.shared"):
+            shared = dense(d, "shared_down")(
+                nn.silu(dense(cfg.shared_d_ff, "shared_gate")(x))
+                * dense(cfg.shared_d_ff, "shared_up")(x))
+        return (routed + shared).reshape(u.shape)

@@ -350,4 +350,4 @@ def test_dispatch_is_counted_once_a_traced_layer(monkeypatch, tmp_path):
         c["value"] for c in before if c["name"] == "moe.dispatch") == 2
     assert counted[0]["labels"] == {
         "held": "16", "of": "32", "picks": "3", "rows": "30",
-        "product": "gmm", "path": "jnp"}
+        "product": "gmm", "form": "relu2", "path": "jnp"}

@@ -382,3 +382,112 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
     for scope in ("sparkdl.ssm.scan", "sparkdl.ssm.conv", "sparkdl.moe.route",
                   "sparkdl.moe.dispatch", "sparkdl.moe.shared", "sparkdl.attn"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_attention_at_head_size_256(one_chip, backward):
+    """The three kernels at latent attention's expanded shape, 20 heads
+    of 256 with as many key/value heads over S = 8192: tiles of 512,
+    the streamed side in major blocks under the VMEM budget."""
+    from sparkdl_tpu.ops.attention import flash_attention
+    from sparkdl_tpu.ops.pallas.flash_attention import (
+        VMEM_BUDGET,
+        flash_tiles,
+    )
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False, scale=256 ** -0.5)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    for kernel in ("fwd", "dq", "dkv"):
+        tiles = flash_tiles(kernel, 8192, 256, 2)
+        assert (tiles.bq, tiles.bk) == (512, 512) and tiles.major < 8192
+        assert tiles.vmem_bytes <= VMEM_BUDGET
+    qkv = _shape(one_chip, (1, 8192, 20, 256), jnp.bfloat16)
+    compiled = _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+    kernels = re.findall(
+        r"%(\w+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())
+    names = ["sparkdl_flash_fwd"] + ["sparkdl_flash_dq", "sparkdl_flash_dkv"
+                                     ] * backward
+    assert len(kernels) == len(names), kernels
+    assert all(sum(name in k for k in kernels) == 1 for name in names), kernels
+
+
+def test_mla_train_step_fits_and_carries_its_kernels(one_chip, monkeypatch):
+    """One LoRA step of ``glm47flash-lora-train`` as the benchmark
+    builds it (``chipbench/configs/glm-4.7-flash.json``: pattern ``LD``
+    + ``LG`` x 6 at the published widths, every expert held, 1 x 8192
+    tokens): the flash kernels of each latent-attention mixer lie under
+    ``sparkdl.mla.core``, the grouped products under
+    ``sparkdl.moe.experts``, the rows move by the plain gathers (a token
+    side of 8192 x 2048 float32 is past the rows' kernels), nothing has
+    the shape of every expert on every token, and arguments and
+    temporaries fit the chip."""
+    import optax
+
+    from chipbench import run as harness
+    from chipbench.kinds.train_hybrid import init_params
+    from sparkdl_tpu.models import HybridConfig, HybridDecoder, lora_mask, moe
+    from sparkdl_tpu.ops import attention, grouped_matmul
+    from sparkdl_tpu.parallel.train import make_lm_loss_fn, make_train_step
+
+    for module in (attention, grouped_matmul, moe):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+    spec = harness.load_cell("glm47flash-lora-train")
+    job = spec["traffic"]
+    cfg = HybridConfig.from_published(
+        spec["config"], dtype=jnp.bfloat16, lora_rank=job["lora_rank"],
+        lora_targets=tuple(job["lora_targets"]), attention=job["attention"],
+        remat=job["remat"])
+    assert cfg.pattern == "LD" + "LG" * 6
+    assert moe.dispatch_path(job["seq"], cfg.d_model) == "jnp"
+    # the tree the kind builds: bf16 but for the float32 adapters
+    params = jax.eval_shape(lambda: init_params(cfg, 0))
+    mask = lora_mask(params)
+    opt = optax.masked(optax.adamw(job["lr"]), mask)
+    step = make_train_step(
+        make_lm_loss_fn(HybridDecoder(cfg), loss=job["loss"],
+                        chunk=job["loss_chunk"], ce_bf16=True),
+        opt, param_mask=mask)
+    on_chip = functools.partial(
+        jax.tree.map,
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip))
+    tokens = _shape(one_chip, (job["batch"], job["seq"]), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(jax.eval_shape(opt.init, params)),
+        {"inputs": tokens, "targets": tokens}).compile()
+    memory = compiled.memory_analysis()
+    assert 9.0e9 < memory.argument_size_in_bytes < 9.2e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 15e9
+    text = compiled.as_text()
+    stacks = re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"",
+        text)
+    # seven mixers: forward, the remat's forward again, dq and dk/dv
+    flash = [s for s in stacks if "sparkdl_flash_" in s]
+    assert sorted(s.split("/")[-2] for s in flash) == (
+        ["sparkdl_flash_dkv"] * 7 + ["sparkdl_flash_dq"] * 7
+        + ["sparkdl_flash_fwd"] * 14), stacks
+    assert all("sparkdl.mla/" in s and "sparkdl.mla.core" in s for s in flash)
+    # six expert layers: gate | up and down, forward, the remat's
+    # forward again and the backward in the rows alone
+    grouped = [s for s in stacks if grouped_matmul.NAME in s]
+    assert len(grouped) == 36 and len(stacks) == 64, stacks
+    assert all("sparkdl.moe.experts" in s for s in grouped)
+    assert len(stacks) >= job["min_kernels"]
+    assert "sparkdl_moe_take" not in text and "sparkdl_moe_add" not in text
+    rows = job["seq"] * cfg.top_k
+    assert f"bf16[{rows},3072]" in text and f"bf16[{rows},2048]" in text
+    for dense in ("[8192,64,1536]", "[64,8192,1536]", "[64,8192,3072]",
+                  "[8192,64,3072]"):
+        assert dense not in text
+    for scope in ("sparkdl.mla.latent", "sparkdl.mla.core", "sparkdl.mlp",
+                  "sparkdl.moe.route", "sparkdl.moe.dispatch",
+                  "sparkdl.moe.shared", "sparkdl.lm_head_loss"):
+        assert scope in text, scope
