@@ -248,6 +248,106 @@ def cross_entropy_loss(logits, labels, *, ignore_index=None):
     return nll.mean()
 
 
+def _chunks(x, chunk, pad):
+    """``(B, S, ...)`` as ``(n, B, chunk, ...)``: the scan's axis first,
+    the sequence padded at its end to a whole chunk."""
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    b = x.shape[0]
+    return x.reshape((b, -1, chunk) + x.shape[2:]).swapaxes(0, 1)
+
+
+def _chunked(hidden, labels, chunk, ignore_index):
+    """What both forms of :func:`fused_cross_entropy` scan, a chunk of
+    the sequence at a time: hidden states, labels and the float32 mask
+    of the tokens that count; and how many count (of the labels alone)."""
+    pad = -hidden.shape[1] % chunk
+    valid = jnp.ones(labels.shape, jnp.float32) if ignore_index is None \
+        else (labels != ignore_index).astype(jnp.float32)
+    return (tuple(_chunks(x, chunk, pad) for x in (hidden, labels, valid)),
+            jnp.maximum(valid.sum(), 1.0))
+
+
+def _chunk_forward(h, w, lbl, matmul_dtype):
+    """One chunk's float32 logits, their log-sum-exp and the negative
+    log-likelihood of its labels: ``(B, c, V)``, ``(B, c)``, ``(B, c)``."""
+    hm = h if matmul_dtype is None else h.astype(matmul_dtype)
+    logits = jax.lax.dot_general(
+        hm, w, (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
+    return logits, lse, lse - gold
+
+
+def _scan_loss(hidden, w_head, labels, chunk, ignore_index, matmul_dtype, *,
+               recompute):
+    """The mean loss by a scan that keeps no chunk's logits. With
+    `recompute` a chunk is a ``jax.checkpoint``, so a gradient taken
+    through the scan makes its logits again in the backward pass."""
+    xs, count = _chunked(hidden, labels, chunk, ignore_index)
+    w = w_head if matmul_dtype is None else w_head.astype(matmul_dtype)
+
+    def chunk_nll(h, lbl):
+        return _chunk_forward(h, w, lbl, matmul_dtype)[2]
+
+    if recompute:
+        chunk_nll = jax.checkpoint(chunk_nll)
+
+    def body(loss_sum, x):
+        h, lbl, m = x
+        return loss_sum + (chunk_nll(h, lbl) * m).sum(), None
+
+    loss_sum, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
+    return loss_sum / count
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _one_pass_loss(hidden, w_head, labels, chunk, ignore_index, matmul_dtype):
+    """:func:`fused_cross_entropy` with a frozen head. Alone it is the
+    plain scan; under a gradient its forward rule makes the hidden
+    states' gradient beside each chunk's logits and keeps that, so the
+    head is passed over twice a step and not three times."""
+    return _scan_loss(hidden, w_head, labels, chunk, ignore_index,
+                      matmul_dtype, recompute=False)
+
+
+def _one_pass_fwd(hidden, w_head, labels, chunk, ignore_index, matmul_dtype):
+    b, s, d = hidden.shape
+    xs, count = _chunked(hidden, labels, chunk, ignore_index)
+    w = w_head if matmul_dtype is None else w_head.astype(matmul_dtype)
+
+    def body(loss_sum, x):
+        h, lbl, m = x
+        logits, lse, nll = _chunk_forward(h, w, lbl, matmul_dtype)
+        # the loss's gradient in the logits, up to the upstream scalar
+        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        p = jnp.exp(logits - lse[..., None])
+        dlogits = jnp.where(ids == lbl[..., None], p - 1.0, p) \
+            * (m / count)[..., None]
+        # what the transpose of the chunk's product is: float32
+        # accumulation, then the product's operand dtype, then hidden's
+        dh = jax.lax.dot_general(
+            dlogits, w, (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if matmul_dtype is not None:
+            dh = dh.astype(matmul_dtype)
+        return loss_sum + (nll * m).sum(), dh.astype(h.dtype)
+
+    loss_sum, dhc = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
+    # the one residual: un-padded, in hidden's shape
+    return loss_sum / count, dhc.swapaxes(0, 1).reshape(b, -1, d)[:, :s]
+
+
+def _one_pass_bwd(chunk, ignore_index, matmul_dtype, dhidden, upstream):
+    return (upstream * dhidden).astype(dhidden.dtype), None, None
+
+
+_one_pass_loss.defvjp(_one_pass_fwd, _one_pass_bwd)
+
+
 @jax.named_scope("sparkdl.lm_head_loss")
 def fused_cross_entropy(hidden, w_head, labels, *, chunk_size=256,
                         ignore_index=None, matmul_dtype=None,
@@ -257,10 +357,20 @@ def fused_cross_entropy(hidden, w_head, labels, *, chunk_size=256,
     logits tensor in HBM.
 
     The sequence axis is scanned in ``chunk_size`` slices; each slice's
-    logits live only inside one fused chunk (``jax.checkpoint`` makes
-    the backward recompute them instead of saving them). For a 32k
-    vocab at batch 8 x seq 1024 this replaces a ~1 GiB fp32 logits
-    round-trip (plus its log_softmax twin) with a ~32 MiB working set.
+    logits live only inside one chunk of the scan. For a 32k vocab at
+    batch 8 x seq 1024 this replaces a ~1 GiB fp32 logits round-trip
+    (plus its log_softmax twin) with a ~32 MiB working set.
+
+    Two forms, chosen from ``freeze_head``. A frozen head's only
+    gradient is the hidden states', ``(softmax - onehot) @ w_head^T``
+    up to the scalar from upstream, known the moment a chunk's logits
+    are: the forward scan makes it and keeps it (``hidden``'s size),
+    and the backward multiplies it by that scalar (``one_pass``: two
+    products over the head a step). A trainable head also needs
+    ``hidden^T @ dlogits`` summed over the chunks, a float32 ``(D, V)``
+    buffer: there ``jax.checkpoint`` makes the backward scan recompute
+    each chunk's logits (``recompute``: three products). The
+    ``loss.fused`` counter says which form a traced call built.
 
     :param hidden: ``(B, S, D)`` final hidden states (any float dtype).
     :param w_head: ``(D, V)`` unembedding matrix.
@@ -270,50 +380,23 @@ def fused_cross_entropy(hidden, w_head, labels, *, chunk_size=256,
     :param matmul_dtype: cast both matmul operands (e.g. bf16 halves
         the ``w_head`` HBM read; accumulation stays fp32 via
         ``preferred_element_type``).
-    :param freeze_head: ``stop_gradient`` the head (LoRA-style frozen
-        unembedding) so its dW matmul is never emitted.
+    :param freeze_head: the head gets no gradient (LoRA-style frozen
+        unembedding): its dW matmul is never emitted, and the loss
+        takes the ``one_pass`` form.
     """
-    b, s, d = hidden.shape
+    from sparkdl_tpu import observe
+
+    s = hidden.shape[1]
     chunk = min(chunk_size, s)
-    n = -(-s // chunk)
-    pad = n * chunk - s
-    valid = jnp.ones((b, s), jnp.float32) if ignore_index is None else \
-        (labels != ignore_index).astype(jnp.float32)
-    if pad:
-        hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pad)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    hc = hidden.reshape(b, n, chunk, d).swapaxes(0, 1)   # (n, B, c, D)
-    lc = labels.reshape(b, n, chunk).swapaxes(0, 1)
-    vc = valid.reshape(b, n, chunk).swapaxes(0, 1)
-
+    # once a traced call: the form the step was built with
+    observe.inc("loss.fused", form="one_pass" if freeze_head else "recompute",
+                tokens=hidden.shape[0] * s, vocab=w_head.shape[1],
+                chunk=chunk, chunks=-(-s // chunk))
     if freeze_head:
-        w_head = jax.lax.stop_gradient(w_head)
-    w = w_head if matmul_dtype is None else w_head.astype(matmul_dtype)
-
-    def chunk_nll(h, lbl):
-        hm = h if matmul_dtype is None else h.astype(matmul_dtype)
-        logits = jax.lax.dot_general(
-            hm, w, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
-        return lse - gold                                # (B, c)
-
-    chunk_nll = jax.checkpoint(chunk_nll)
-
-    def body(acc, xs):
-        h, lbl, m = xs
-        nll = chunk_nll(h, lbl)
-        return (acc[0] + (nll * m).sum(), acc[1] + m.sum()), None
-
-    (loss_sum, count), _ = jax.lax.scan(
-        body,
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hc, lc, vc),
-    )
-    return loss_sum / jnp.maximum(count, 1.0)
+        return _one_pass_loss(hidden, w_head, labels, chunk, ignore_index,
+                              matmul_dtype)
+    return _scan_loss(hidden, w_head, labels, chunk, ignore_index,
+                      matmul_dtype, recompute=True)
 
 
 def global_batch(rng, vocab, batch, seq):

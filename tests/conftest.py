@@ -37,3 +37,16 @@ def _cold_slot_probe():
     from sparkdl_tpu.horovod import launcher
 
     launcher._probe_local_devices.cache_clear()
+
+
+@pytest.fixture
+def telemetry(monkeypatch, tmp_path):
+    """``observe`` with telemetry on, and reset around the test: the
+    counters that say what a traced call was built with count only
+    then."""
+    from sparkdl_tpu import observe
+
+    monkeypatch.setenv(observe.TELEMETRY_DIR_ENV, str(tmp_path))
+    observe._reset_for_tests()
+    yield observe
+    observe._reset_for_tests()

@@ -93,6 +93,126 @@ def test_freeze_head_zeroes_w_grad(data):
     assert not np.any(np.asarray(gw))    # head frozen
 
 
+def _frozen(hidden, w, labels, **kw):
+    return fused_cross_entropy(hidden, w, labels, freeze_head=True, **kw)
+
+
+@pytest.mark.parametrize("chunk", [5, 8, 64])
+def test_frozen_head_matches_reference(data, chunk):
+    """The one-pass form: the hidden states' gradient is made in the
+    forward scan, beside each chunk's logits."""
+    hidden, w, labels = data
+    ref, g_ref = jax.value_and_grad(_reference)(hidden, w, labels)
+    got, g = jax.value_and_grad(_frozen)(hidden, w, labels,
+                                         chunk_size=chunk)
+    np.testing.assert_allclose(float(got), float(ref), rtol=1e-6)
+    assert g.shape == hidden.shape and g.dtype == hidden.dtype
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-6)
+
+
+def test_frozen_head_ignore_index(data):
+    hidden, w, labels = data
+    labels = labels.at[:, ::3].set(-1)
+    ref, g_ref = jax.value_and_grad(_reference)(
+        hidden, w, labels, ignore_index=-1)
+    got, g = jax.value_and_grad(_frozen)(
+        hidden, w, labels, chunk_size=5, ignore_index=-1)
+    np.testing.assert_allclose(float(got), float(ref), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-6)
+    assert not np.any(np.asarray(g)[:, ::3])     # an ignored token's row
+
+
+def test_frozen_head_matmul_dtype_bf16_close_to_reference(data):
+    hidden, w, labels = data
+    ref, g_ref = jax.value_and_grad(_reference)(hidden, w, labels)
+    got, g = jax.value_and_grad(_frozen)(
+        hidden, w, labels, chunk_size=8, matmul_dtype=jnp.bfloat16)
+    np.testing.assert_allclose(float(got), float(ref), rtol=2e-2)
+    assert g.dtype == hidden.dtype
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=3e-2)
+    # bf16 hidden states, as the cells have them: a bf16 gradient
+    g16 = jax.grad(_frozen)(hidden.astype(jnp.bfloat16), w, labels,
+                            chunk_size=8, matmul_dtype=jnp.bfloat16)
+    assert g16.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(g16, np.float32),
+                               np.asarray(g_ref), atol=3e-2)
+
+
+def test_frozen_head_under_an_upstream_scale(data):
+    """The backward rule is the saved gradient times what arrives from
+    upstream: a scale other than 1 shows that it is applied."""
+    hidden, w, labels = data
+    g_ref = jax.grad(lambda h: 3.0 * _reference(h, w, labels))(hidden)
+    g = jax.grad(lambda h: 3.0 * _frozen(h, w, labels, chunk_size=5))(hidden)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=3e-6)
+
+
+@pytest.mark.parametrize("matmul_dtype", [None, jnp.bfloat16])
+def test_one_pass_and_recompute_agree(data, matmul_dtype):
+    """The two forms are one expression: the frozen head's makes the
+    gradient once, the trainable head's recomputes the logits for it."""
+    hidden, w, labels = data
+    labels = labels.at[0, 1].set(-1)
+    kw = dict(chunk_size=5, ignore_index=-1, matmul_dtype=matmul_dtype)
+    one, g_one = jax.value_and_grad(_frozen)(hidden, w, labels, **kw)
+    two, g_two = jax.value_and_grad(fused_cross_entropy)(
+        hidden, w, labels, **kw)
+    np.testing.assert_allclose(float(one), float(two), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(g_one), np.asarray(g_two),
+                               atol=1e-6 if matmul_dtype is None else 2e-3)
+
+
+def _products_over_the_head(fn, *args):
+    """``dot_general`` equations of `fn`'s jaxpr, inner jaxprs too, that
+    take the ``(D, V)`` head: each is one pass over it."""
+    def walk(jaxpr):
+        found = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found += any(v.aval.shape == (D, V) for v in eqn.invars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += walk(sub)
+        return found
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def test_a_frozen_head_is_passed_over_twice_a_step(data):
+    """Logits and the hidden states' gradient: two products over the
+    head under ``value_and_grad``, where the recompute form has three;
+    the loss alone, with no gradient asked for, has one."""
+    hidden, w, labels = data
+    frozen = lambda h: _frozen(h, w, labels, chunk_size=5)
+    recompute = lambda h: fused_cross_entropy(
+        h, jax.lax.stop_gradient(w), labels, chunk_size=5)
+    assert _products_over_the_head(jax.value_and_grad(frozen), hidden) == 2
+    assert _products_over_the_head(jax.value_and_grad(recompute), hidden) == 3
+    assert _products_over_the_head(frozen, hidden) == 1
+    assert _products_over_the_head(recompute, hidden) == 1
+
+
+def test_loss_fused_says_which_form_a_step_was_built_with(data, telemetry):
+    hidden, w, labels = data
+
+    def forms():
+        return {c["labels"]["form"]: (c["value"], c["labels"])
+                for c in telemetry.metrics().snapshot()["counters"]
+                if c["name"] == "loss.fused"}
+
+    step = jax.jit(jax.value_and_grad(
+        lambda h: _frozen(h, w, labels, chunk_size=5)))
+    step(hidden)
+    step(hidden)                             # cached: traces nothing
+    (value, labels_), = forms().values()
+    assert value == 1
+    assert labels_ == {"form": "one_pass", "tokens": str(B * S),
+                       "vocab": str(V), "chunk": "5", "chunks": "3"}
+    jax.jit(jax.grad(lambda h: fused_cross_entropy(
+        h, w, labels, chunk_size=64)))(hidden)
+    value, labels_ = forms()["recompute"]
+    assert value == 1 and (labels_["chunk"], labels_["chunks"]) == ("12", "1")
+    assert forms()["one_pass"][0] == 1
+
+
 def test_fused_ce_under_pjit_mesh(data):
     """The bench/flagship path: fused CE inside a jitted step over a
     ('data','model') mesh, batch sharded on data AND the unembed head
