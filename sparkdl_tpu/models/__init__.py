@@ -24,6 +24,8 @@ decode-only machinery):
   (``relu2`` experts in a latent width, gated experts on the full one)
 - :mod:`.mamba2` — the Mamba-2 state-space mixer (training)
 - :mod:`.mla` — multi-head latent attention, expanded form (training)
+- :mod:`.mixed_attention` — gated, QK-normed attention for decoders that
+  mix window and full attention layers (training)
 """
 
 from sparkdl_tpu.models.bert import (  # noqa: F401

@@ -3,7 +3,8 @@ letter. The ``nemotron_h`` family publishes its pattern
 (``hybrid_override_pattern``: ``M``, ``E``, ``*``); a family whose
 published layer is TWO residual steps, attention then feed-forward, is
 two letters a published layer (``glm4_moe_lite``: ``LD`` for a leading
-dense layer, ``LG`` for every other).
+dense layer, ``LG`` for every other; ``afmoe``: ``S`` or ``F`` by the
+layer's ``layer_types`` entry, then ``D`` or ``G``).
 
     M  a Mamba-2 state-space mixer       (:mod:`.mamba2`)
     E  routed experts in a latent width, and a shared expert
@@ -12,21 +13,29 @@ dense layer, ``LG`` for every other).
        with no rotary embedding: the state-space layers carry the order)
     L  multi-head latent attention, rope on a narrow part of a head
                                          (:class:`.mla.LatentAttention`)
+    S  gated grouped-query attention over a causal WINDOW
+       (``sliding_window``), with rope and a norm a head on q and k
+                                 (:class:`.mixed_attention.MixedAttention`)
+    F  the same mixer over the whole causal prefix, without rope
     D  a dense gated MLP                 (:class:`.llama.MLP`)
     G  gated routed experts on the full width, and a shared expert
                                          (:class:`.moe.GatedMoE`)
 
-Every layer is ``x <- x + mixer(RMSNorm(x))``; then a final norm and the
-head. :class:`HybridDecoder` has :class:`.llama.Llama`'s call contract,
-so :func:`sparkdl_tpu.parallel.train.make_lm_loss_fn` and
+Every layer is ``x <- x + mixer(RMSNorm(x))``, or with ``post_norm``
+(``afmoe``'s sandwich) ``x <- x + RMSNorm(mixer(RMSNorm(x)))``; the
+embedding is scaled by ``sqrt(d_model)`` where ``scale_embedding`` says
+so; then a final norm and the head. :class:`HybridDecoder` has
+:class:`.llama.Llama`'s call contract, so
+:func:`sparkdl_tpu.parallel.train.make_lm_loss_fn` and
 ``make_train_step`` take it as they take ``Llama``.
 
 Supported: LoRA training of ONE chip's share of a deployment that
 divides each layer's routed experts and the vocabulary over chips
 (``experts_held``, ``vocab_size``), or that holds them whole. Not
 supported: serving (no recurrent state and no latent cache beside the
-paged one; ``L`` runs its expanded form only), multi-token prediction,
-the exchange between the chips that share a layer. docs/hybrid.rst has
+paged one; ``L`` runs its expanded form only; ``S`` has no cache that
+keeps a window's pages alone), multi-token prediction, the exchange
+between the chips that share a layer. docs/hybrid.rst has
 the equations and the maps from published keys.
 """
 
@@ -39,6 +48,7 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.models.llama import MLP, Attention, LlamaConfig, RMSNorm
 from sparkdl_tpu.models.mamba2 import Mamba2Mixer
+from sparkdl_tpu.models.mixed_attention import MixedAttention
 from sparkdl_tpu.models.mla import LatentAttention
 from sparkdl_tpu.models.moe import GatedMoE, LatentMoE
 
@@ -46,6 +56,7 @@ from sparkdl_tpu.models.moe import GatedMoE, LatentMoE
 # shape is read from; a file without the key is ``nemotron_h``'s
 PUBLISHED = {
     "nemotron_h": {
+        "n_routed_experts": "n_routed_experts",
         "vocab_size": "vocab_size", "hidden_size": "d_model",
         "hybrid_override_pattern": "pattern",
         "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
@@ -63,6 +74,7 @@ PUBLISHED = {
     # the pattern and the shared expert's width are BUILT from
     # num_hidden_layers, first_k_dense_replace and n_shared_experts
     "glm4_moe_lite": {
+        "n_routed_experts": "n_routed_experts",
         "vocab_size": "vocab_size", "hidden_size": "d_model",
         "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
         "q_lora_rank": "q_rank", "kv_lora_rank": "kv_rank",
@@ -73,9 +85,25 @@ PUBLISHED = {
         "moe_intermediate_size": "expert_d_ff",
         "routed_scaling_factor": "routed_scale", "rms_norm_eps": "rms_eps",
     },
+    # the pattern is BUILT from layer_types and num_dense_layers, the
+    # shared expert's width from num_shared_experts; a norm follows
+    # every mixer, and mup_enabled scales the embedding
+    "afmoe": {
+        "num_experts": "n_routed_experts",
+        "vocab_size": "vocab_size", "hidden_size": "d_model",
+        "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+        "head_dim": "head_dim", "sliding_window": "sliding_window",
+        "rope_theta": "rope_theta", "intermediate_size": "dense_d_ff",
+        "num_experts_per_tok": "top_k",
+        "moe_intermediate_size": "expert_d_ff",
+        "route_scale": "routed_scale", "rms_norm_eps": "rms_eps",
+        "mup_enabled": "scale_embedding",
+    },
 }
+LAYER_TYPES = {"sliding_attention": "S", "full_attention": "F"}
 SCOPES = {"M": "sparkdl.ssm", "E": "sparkdl.moe", "*": "sparkdl.attn",
-          "L": "sparkdl.mla", "D": "sparkdl.mlp", "G": "sparkdl.moe"}
+          "L": "sparkdl.mla", "D": "sparkdl.mlp", "G": "sparkdl.moe",
+          "S": "sparkdl.attn", "F": "sparkdl.attn"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,8 +140,14 @@ class HybridConfig:
     qk_rope_dim: int = 8
     v_dim: int = 32
     rope_theta: float = 10000.0
+    # the window of the ``S`` layers: the newest keys a query sees
+    sliding_window: int = 32
     dense_d_ff: int = 128
     rms_eps: float = 1e-5
+    # a second norm a layer, on the mixer's output before it is added
+    post_norm: bool = False
+    # the embedding times sqrt(d_model)
+    scale_embedding: bool = False
     dtype: Any = jnp.bfloat16
     remat: bool = False
     attention: str = "reference"
@@ -145,6 +179,14 @@ class HybridConfig:
             raise ValueError(
                 f"ssm_heads={self.ssm_heads} not divisible by "
                 f"ssm_groups={self.ssm_groups}")
+        if "S" in has and (self.head_dim % 2 or self.sliding_window < 1):
+            raise ValueError(
+                f"head_dim={self.head_dim}: rope turns pairs; "
+                f"sliding_window={self.sliding_window}: a query sees itself")
+        if has & {"S", "F"} and self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_heads={self.n_heads} not divisible by "
+                f"n_kv_heads={self.n_kv_heads}")
         if "L" in has and self.qk_rope_dim % 2:
             raise ValueError(
                 f"qk_rope_dim={self.qk_rope_dim}: rope turns pairs")
@@ -169,8 +211,19 @@ class HybridConfig:
                 config["num_hidden_layers"] - dense)
             fields["shared_d_ff"] = (config["n_shared_experts"]
                                      * config["moe_intermediate_size"])
-        held = config["n_routed_experts"]
-        return cls(**{"n_routed_experts": held, "experts_held": (0, held),
+        if kind == "afmoe":
+            types = config["layer_types"]
+            if len(types) != config["num_hidden_layers"]:
+                raise ValueError(
+                    f"layer_types has {len(types)} entries for "
+                    f"num_hidden_layers={config['num_hidden_layers']}")
+            fields["pattern"] = "".join(
+                LAYER_TYPES[t] + ("D" if i < config["num_dense_layers"]
+                                  else "G") for i, t in enumerate(types))
+            fields["shared_d_ff"] = (config["num_shared_experts"]
+                                     * config["moe_intermediate_size"])
+            fields["post_norm"] = True
+        return cls(**{"experts_held": (0, fields["n_routed_experts"]),
                       **fields, **kw})
 
     @property
@@ -197,17 +250,24 @@ class HybridLayer(nn.Module):
         with jax.named_scope(SCOPES[self.kind]):
             h = RMSNorm(cfg.rms_eps, name="norm")(x)
             if self.kind == "M":
-                return x + Mamba2Mixer(cfg, name="mamba")(h)
-            if self.kind == "E":
-                return x + LatentMoE(cfg, name="moe")(h)
-            if self.kind == "L":
-                return x + LatentAttention(cfg, name="mla")(h)
-            if self.kind == "D":
-                return x + MLP(cfg.attn, name="mlp")(h)
-            if self.kind == "G":
-                return x + GatedMoE(cfg, name="moe")(h)
-            return x + Attention(cfg.attn, name="attn")(
-                h, None, None, None)
+                h = Mamba2Mixer(cfg, name="mamba")(h)
+            elif self.kind == "E":
+                h = LatentMoE(cfg, name="moe")(h)
+            elif self.kind == "L":
+                h = LatentAttention(cfg, name="mla")(h)
+            elif self.kind == "D":
+                h = MLP(cfg.attn, name="mlp")(h)
+            elif self.kind == "G":
+                h = GatedMoE(cfg, name="moe")(h)
+            elif self.kind in "SF":
+                h = MixedAttention(
+                    cfg, cfg.sliding_window if self.kind == "S" else None,
+                    name="attn")(h)
+            else:
+                h = Attention(cfg.attn, name="attn")(h, None, None, None)
+            if cfg.post_norm:
+                h = RMSNorm(cfg.rms_eps, name="post_norm")(h)
+            return x + h
 
 
 class HybridDecoder(nn.Module):
@@ -221,6 +281,8 @@ class HybridDecoder(nn.Module):
         cfg = self.cfg
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
                      name="embed")(tokens)
+        if cfg.scale_embedding:
+            x = x * cfg.d_model ** 0.5
         layer = nn.remat(HybridLayer) if cfg.remat else HybridLayer
         for i, kind in enumerate(cfg.pattern):
             x = layer(cfg, kind, name=f"layer_{i}")(x)

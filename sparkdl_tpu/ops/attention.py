@@ -34,27 +34,27 @@ _DEFAULT_FLASH_BLOCK_KV = int(
 # outside and differentiate through standard XLA transposes.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, causal, scale, bq, bk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, causal, scale, bq, bk, interpret, window):
     from sparkdl_tpu.ops.pallas.flash_attention import flash_attention_bhsd
 
     return flash_attention_bhsd(
         q, k, v, causal=causal, scale=scale, bq=bq, bk=bk,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
 
 
-def _flash_core_fwd(q, k, v, causal, scale, bq, bk, interpret):
+def _flash_core_fwd(q, k, v, causal, scale, bq, bk, interpret, window):
     from sparkdl_tpu.ops.pallas.flash_attention import flash_attention_bhsd
 
     o, lse = flash_attention_bhsd(
         q, k, v, causal=causal, scale=scale, bq=bq, bk=bk,
-        interpret=interpret, return_lse=True,
+        interpret=interpret, return_lse=True, window=window,
     )
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(causal, scale, bq, bk, interpret, res, do):
+def _flash_core_bwd(causal, scale, bq, bk, interpret, window, res, do):
     import jax.numpy as jnp
 
     from sparkdl_tpu.ops.pallas.flash_attention import (
@@ -70,7 +70,7 @@ def _flash_core_bwd(causal, scale, bq, bk, interpret, res, do):
     )
     dq, dk, dv = flash_attention_bwd_bhsd(
         q, k, v, do, lse, delta, causal=causal, scale=scale,
-        bq=bq, bk=bk, interpret=interpret,
+        bq=bq, bk=bk, interpret=interpret, window=window,
     )
     return dq, dk, dv
 
@@ -78,11 +78,17 @@ def _flash_core_bwd(causal, scale, bq, bk, interpret, res, do):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
-                    block=None, block_q=None, block_kv=None):
+def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
+                    interpret=None, block=None, block_q=None,
+                    block_kv=None):
     """Fused attention on (batch, seq, heads, head_dim) tensors —
     pallas forward AND backward on TPU (or ``interpret=True`` for
     tests); XLA reference elsewhere.
+
+    ``window`` (static; causal only): a query at position i sees the
+    keys j with ``0 <= i - j < window``, and the kernels walk only the
+    tiles such a pair lies in. ``None``, or a window that covers the
+    sequence, is the causal program.
 
     Left alone, each of the three kernels takes the tiles
     ``flash_tiles`` chooses from (seq, head_dim, dtype). ``block``
@@ -98,7 +104,8 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
     """
     if interpret is None:
         if not _use_pallas():
-            return attention_reference(q, k, v, causal=causal, scale=scale)
+            return attention_reference(q, k, v, causal=causal, scale=scale,
+                                       window=window)
         interpret = False
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -126,7 +133,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None, interpret=None,
         return attention_reference(q, k, v, causal=False, scale=scale)
     kt, _ = _pad_to(kt, mult, 2)
     vt, _ = _pad_to(vt, mult, 2)
-    out = _flash_core(qt, kt, vt, causal, scale, bq, bk, interpret)
+    out = _flash_core(qt, kt, vt, causal, scale, bq, bk, interpret, window)
     if pad:
         out = out[:, :, :s, :]
     return out.transpose(0, 2, 1, 3)

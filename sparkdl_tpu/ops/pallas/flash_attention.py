@@ -45,8 +45,14 @@ for the chip (what each choice bought on a v5e is in PERF.md, PR 25):
   (``preferred_element_type``) and fp32 softmax state.
 
 A causal row always sees key 0, which is in the first tile walked, so
-the running maximum is finite from the first tile on and no
-fully-masked-row guard is needed.
+the running maximum is finite from the first tile on and the causal
+forward needs no fully-masked-row guard. Under a window that no longer
+holds: the first tile walked crosses the window's far edge and its
+later rows see nothing in it, so their running maximum is still
+``NEG_INF`` and ``exp(s - m)`` reads 1 where it should read 0. The
+windowed forward therefore masks its probabilities too, as dq and
+dk/dv always have (a row sees itself, so its state is finite by the
+last tile walked).
 """
 
 import functools
@@ -72,9 +78,10 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
-def _causal_keep(q_start, k_start, bq, bk, transposed=False):
-    """Block-local causal visibility mask (q_pos >= k_pos), shared by
-    the forward and both backward kernels so masking semantics can
+def _causal_keep(q_start, k_start, bq, bk, transposed=False, window=None):
+    """Block-local visibility mask: causal (q_pos >= k_pos) and, with
+    `window`, no further back than it (q_pos - k_pos < window). Shared
+    by the forward and both backward kernels so masking semantics can
     never diverge between them. ``(bq, bk)``, or ``(bk, bq)`` for the
     transposed score tile of dk/dv."""
     shape, q_axis = ((bk, bq), 1) if transposed else ((bq, bk), 0)
@@ -83,7 +90,10 @@ def _causal_keep(q_start, k_start, bq, bk, transposed=False):
     # tile lies is one scalar
     ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
              - jax.lax.broadcasted_iota(jnp.int32, shape, q_axis))
-    return ahead <= q_start - k_start
+    keep = ahead <= q_start - k_start
+    if window is not None:
+        keep = jnp.logical_and(keep, ahead > q_start - k_start - window)
+    return keep
 
 
 class FlashTiles(NamedTuple):
@@ -169,11 +179,50 @@ def flash_tiles(kernel, s, d, itemsize, bq=None, bk=None):
             return FlashTiles(bq, bk, step, need)
 
 
-def _count_tiles(kernel, s, d, tiles, explicit):
+def _window(window, causal, s):
+    """The window the kernels are built with: None where it covers the
+    sequence (that IS the causal kernel)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"window={window}: a window is causal and at least 1 wide")
+    return None if window >= s else int(window)
+
+
+def tiles_walked(kernel, s, tiles, window=None):
+    """Score tiles one (batch, head) of `kernel`'s causal walk visits
+    over a sequence of `s`, by the bounds the kernels walk by: every
+    tile with a visible pair, none else."""
+    dkv = kernel == "dkv"
+    stationary, streamed = (tiles.bk, tiles.bq) if dkv else (
+        tiles.bq, tiles.bk)
+    total = 0
+    for start in range(0, s, stationary):
+        if dkv:     # query tiles from the diagonal to the window's edge
+            first, last = start // streamed, s // streamed - 1
+            if window is not None:
+                last = min(last,
+                           (start + stationary + window - 2) // streamed)
+        else:       # key tiles from the window's edge to the diagonal
+            last = (start + stationary - 1) // streamed
+            first = 0 if window is None else max(
+                start - window + 1, 0) // streamed
+        total += last - first + 1
+    return total
+
+
+def _count_tiles(kernel, s, d, tiles, explicit, window=None):
     """``flash.tiles``: once a kernel a traced call, which tiles the
-    step was built with and who chose them."""
+    step was built with and who chose them, the window (0: none) and
+    the tiles a (batch, head) walks beside the causal walk's."""
+    if not observe.enabled():
+        return
     observe.inc("flash.tiles", kernel=kernel, s=s, d=d, bq=tiles.bq,
-                bk=tiles.bk, chosen="argument" if explicit else "rule")
+                bk=tiles.bk, chosen="argument" if explicit else "rule",
+                window=window or 0,
+                tiles_walked=tiles_walked(kernel, s, tiles, window),
+                tiles_causal=tiles_walked(kernel, s, tiles))
 
 
 def _scaled(x, scale):
@@ -202,7 +251,7 @@ def _lane_sums(p):
 
 
 def _walk(tile, carry, first_cross, n_cross, n_tiles, *, causal,
-          cross_first, whole):
+          cross_first, whole, edge=None):
     """Run ``tile(j, carry, masked)`` over one major block's tiles: the
     tiles wholly below the diagonal in ONE loop without a mask, and
     the `n_cross` tiles that cross it, from tile `first_cross` on,
@@ -210,10 +259,18 @@ def _walk(tile, carry, first_cross, n_cross, n_tiles, *, causal,
     PR 25). Tiles above the diagonal are not visited. Without `causal`
     every tile is plain. `cross_first` is dk/dv's order (the diagonal
     is at the low end of its walk over q tiles); `whole` says the major
-    block is the whole sequence, so the diagonal is in it."""
+    block is the whole sequence, so the diagonal is in it.
+
+    `edge` is a window's far side, two tile indices in walking order
+    (either may lie outside the block): with the diagonal last, the
+    first tile with a visible pair and the first wholly inside the
+    window; with it first, the first tile not wholly inside and the
+    first with no visible pair. The tiles between the two run masked,
+    in a loop of their own; what lies beyond is not visited."""
     plain = functools.partial(tile, masked=False)
     if not causal:
         return jax.lax.fori_loop(0, n_tiles, plain, carry)
+    masked = functools.partial(tile, masked=True)
 
     def cross(carry):
         for c in range(n_cross):
@@ -228,12 +285,42 @@ def _walk(tile, carry, first_cross, n_cross, n_tiles, *, causal,
 
     if cross_first:
         carry = maybe_cross(carry)
-        return jax.lax.fori_loop(
-            jnp.clip(first_cross + n_cross, 0, n_tiles), n_tiles, plain,
-            carry)
-    carry = jax.lax.fori_loop(
-        0, jnp.clip(first_cross, 0, n_tiles), plain, carry)
+        start = jnp.clip(first_cross + n_cross, 0, n_tiles)
+        if edge is None:
+            return jax.lax.fori_loop(start, n_tiles, plain, carry)
+        inside, seen = (jnp.maximum(jnp.clip(e, 0, n_tiles), start)
+                        for e in edge)
+        carry = jax.lax.fori_loop(start, inside, plain, carry)
+        return jax.lax.fori_loop(inside, seen, masked, carry)
+    stop = jnp.clip(first_cross, 0, n_tiles)
+    if edge is None:
+        carry = jax.lax.fori_loop(0, stop, plain, carry)
+    else:
+        seen, inside = (jnp.minimum(jnp.clip(e, 0, n_tiles), stop)
+                        for e in edge)
+        carry = jax.lax.fori_loop(seen, inside, masked, carry)
+        carry = jax.lax.fori_loop(inside, stop, plain, carry)
     return maybe_cross(carry)
+
+
+def _keys_edge(q_start, k0, bq, bk, window):
+    """`_walk`'s `edge` for a q-stationary kernel: of the key tiles of
+    the major block at `k0`, the first a row of the q tile at `q_start`
+    sees and the first that every row of it sees whole."""
+    if window is None:
+        return None
+    return ((jnp.maximum(q_start - (window - 1), 0) - k0) // bk,
+            (jnp.maximum(q_start + bq - window, 0) + bk - 1 - k0) // bk)
+
+
+def _queries_edge(k_start, q0, bq, bk, window):
+    """`_walk`'s `edge` for dk/dv: of the query tiles of the major
+    block at `q0`, the first that no longer sees the whole key tile at
+    `k_start` and the first that sees none of it."""
+    if window is None:
+        return None
+    return ((k_start + window - q0) // bq,
+            (k_start + bk + window - 2 - q0) // bq + 1)
 
 
 def _across_major_blocks(pl, scratch, init, walk, finish):
@@ -258,7 +345,7 @@ def _across_major_blocks(pl, scratch, init, walk, finish):
     pl.when(j == pl.num_programs(3) - 1)(lambda: finish(carry))
 
 
-def _make_kernel(t, causal, scale, with_lse=False):
+def _make_kernel(t, causal, scale, with_lse=False, window=None):
     from jax.experimental import pallas as pl
 
     bq, bk = t.bq, t.bk
@@ -283,10 +370,14 @@ def _make_kernel(t, causal, scale, with_lse=False):
             s = jax.lax.dot_general(
                 q, kb, _NT, preferred_element_type=jnp.float32)
             if masked:
-                s = jnp.where(
-                    _causal_keep(q_start, k0 + start, bq, bk), s, NEG_INF)
+                keep = _causal_keep(q_start, k0 + start, bq, bk,
+                                    window=window)
+                s = jnp.where(keep, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if masked and window is not None:
+                # a row that has seen nothing yet: exp(NEG_INF - NEG_INF)
+                p = jnp.where(keep, p, 0.0)
             alpha = jnp.exp(m - m_new)
             l = l * alpha + _lane_sums(p)
             # p in [0,1] keeps full relative precision through the
@@ -315,7 +406,8 @@ def _make_kernel(t, causal, scale, with_lse=False):
             lambda carry: _walk(
                 tile, carry, (q_start - k0) // bk, max(1, bq // bk),
                 n_tiles, causal=causal, cross_first=False,
-                whole=not scratch),
+                whole=not scratch,
+                edge=_keys_edge(q_start, k0, bq, bk, window)),
             finish)
 
     return kernel
@@ -328,17 +420,26 @@ def _tile_spec(pl, rows, width):
         (1, 1, rows, width), lambda bi, hi, i, j: (bi, hi, i, 0))
 
 
-def _streamed_block(causal, tile, major, *, upto):
+def _streamed_block(causal, tile, major, *, upto, window=None):
     """Which major block of a streamed operand grid step ``(i, j)``
     holds: block `j`, held at the last block the stationary tile `i`
     can see (`upto`: the forward and dq walk keys up to the diagonal)
-    or at the first (dk/dv walks queries from it), so that a grid step
-    with nothing to do fetches nothing."""
+    or at the first (dk/dv walks queries from it), and with a `window`
+    at the block of its far edge too, so that a grid step with nothing
+    to do fetches nothing."""
     if not causal:
         return lambda i, j: j
     if upto:
-        return lambda i, j: jnp.minimum(j, ((i + 1) * tile - 1) // major)
-    return lambda i, j: jnp.maximum(j, (i * tile) // major)
+        last = lambda i: ((i + 1) * tile - 1) // major
+        if window is None:
+            return lambda i, j: jnp.minimum(j, last(i))
+        return lambda i, j: jnp.clip(
+            j, jnp.maximum(i * tile - (window - 1), 0) // major, last(i))
+    first = lambda i: (i * tile) // major
+    if window is None:
+        return lambda i, j: jnp.maximum(j, first(i))
+    return lambda i, j: jnp.minimum(
+        jnp.maximum(j, first(i)), ((i + 1) * tile + window - 2) // major)
 
 
 def _scratch(t, s, *shapes):
@@ -368,10 +469,12 @@ def _compiler_params():
 @functools.partial(
     jax.jit, inline=True,
     static_argnames=("causal", "scale", "bq", "bk", "interpret",
-                     "return_lse"))
+                     "return_lse", "window"))
 def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=None,
-                         bk=None, interpret=False, return_lse=False):
-    """Flash attention on (batch, heads, seq, head_dim) arrays.
+                         bk=None, interpret=False, return_lse=False,
+                         window=None):
+    """Flash attention on (batch, heads, seq, head_dim) arrays; with
+    `window` (static) a query sees the `window` newest keys only.
 
     ``bq`` / ``bk`` left out are :func:`flash_tiles`'s; seq must be
     divisible by the tiles (the public wrapper in
@@ -383,11 +486,12 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=None,
 
     b, h, s, d = q.shape
     scale = scale or (d ** -0.5)
+    window = _window(window, causal, s)
     t = flash_tiles("fwd", s, d, q.dtype.itemsize, bq, bk)
-    _count_tiles("fwd", s, d, t, bq is not None or bk is not None)
+    _count_tiles("fwd", s, d, t, bq is not None or bk is not None, window)
 
     q_spec = _tile_spec(pl, t.bq, d)
-    block = _streamed_block(causal, t.bq, t.major, upto=True)
+    block = _streamed_block(causal, t.bq, t.major, upto=True, window=window)
     kv_spec = pl.BlockSpec(
         (1, 1, t.major, d),
         lambda bi, hi, i, j: (bi, hi, block(i, j), 0))
@@ -399,7 +503,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=None,
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         )
     return pl.pallas_call(
-        _make_kernel(t, causal, scale, with_lse=return_lse),
+        _make_kernel(t, causal, scale, with_lse=return_lse, window=window),
         out_shape=out_shape,
         grid=(b, h, s // t.bq, s // t.major),
         in_specs=[q_spec, kv_spec, kv_spec],
@@ -412,7 +516,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=None,
     )(q, k, v)
 
 
-def _make_dq_kernel(t, causal, scale):
+def _make_dq_kernel(t, causal, scale, window=None):
     from jax.experimental import pallas as pl
 
     bq, bk = t.bq, t.bk
@@ -437,7 +541,8 @@ def _make_dq_kernel(t, causal, scale):
             p = jnp.exp(s - lse)
             if masked:
                 p = jnp.where(
-                    _causal_keep(q_start, k0 + start, bq, bk), p, 0.0)
+                    _causal_keep(q_start, k0 + start, bq, bk,
+                                 window=window), p, 0.0)
             dp = jax.lax.dot_general(
                 do, vb, _NT, preferred_element_type=jnp.float32)
             ds = p * (dp - delta)
@@ -453,13 +558,14 @@ def _make_dq_kernel(t, causal, scale):
             lambda carry: _walk(
                 tile, carry, (q_start - k0) // bk, max(1, bq // bk),
                 n_tiles, causal=causal, cross_first=False,
-                whole=not scratch),
+                whole=not scratch,
+                edge=_keys_edge(q_start, k0, bq, bk, window)),
             finish)
 
     return kernel
 
 
-def _make_dkv_kernel(t, causal, scale):
+def _make_dkv_kernel(t, causal, scale, window=None):
     from jax.experimental import pallas as pl
 
     bq, bk = t.bq, t.bk
@@ -489,7 +595,8 @@ def _make_dkv_kernel(t, causal, scale):
             if masked:
                 p_t = jnp.where(
                     _causal_keep(q0 + start, k_start, bq, bk,
-                                 transposed=True), p_t, 0.0)
+                                 transposed=True, window=window),
+                    p_t, 0.0)
             dv = dv + jax.lax.dot_general(
                 p_t.astype(dob.dtype), dob, _NN,
                 preferred_element_type=jnp.float32)
@@ -508,13 +615,15 @@ def _make_dkv_kernel(t, causal, scale):
 
         # causal: q tiles wholly before this kv tile are never
         # visited, those on its diagonal are masked, the rest plain
+        # (up to the window's edge, where there is one)
         zeros = jnp.zeros(kb.shape, jnp.float32)
         _across_major_blocks(
             pl, scratch, (zeros, zeros),
             lambda carry: _walk(
                 tile, carry, (k_start - q0) // bq, max(1, bk // bq),
                 n_tiles, causal=causal, cross_first=True,
-                whole=not scratch),
+                whole=not scratch,
+                edge=_queries_edge(k_start, q0, bq, bk, window)),
             finish)
 
     return kernel
@@ -522,10 +631,10 @@ def _make_dkv_kernel(t, causal, scale):
 
 @functools.partial(
     jax.jit, inline=True,
-    static_argnames=("causal", "scale", "bq", "bk", "interpret"))
+    static_argnames=("causal", "scale", "bq", "bk", "interpret", "window"))
 def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
                              scale=None, bq=None, bk=None,
-                             interpret=False):
+                             interpret=False, window=None):
     """Fused backward: (dq, dk, dv) from saved (q, k, v, lse) and the
     output-gradient rowsum delta = sum(do * o, -1, keepdims=True); lse
     and delta are (B, H, S, 1) per the forward's tiling note.
@@ -538,19 +647,21 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
     b, h, s, d = q.shape
     scale = scale or (d ** -0.5)
     explicit = bq is not None or bk is not None
+    window = _window(window, causal, s)
     tq = flash_tiles("dq", s, d, q.dtype.itemsize, bq, bk)
     tk = flash_tiles("dkv", s, d, q.dtype.itemsize, bq, bk)
-    _count_tiles("dq", s, d, tq, explicit)
-    _count_tiles("dkv", s, d, tk, explicit)
+    _count_tiles("dq", s, d, tq, explicit, window)
+    _count_tiles("dkv", s, d, tk, explicit, window)
 
     q_tile = _tile_spec(pl, tq.bq, d)
     column = _tile_spec(pl, tq.bq, 1)
-    k_block = _streamed_block(causal, tq.bq, tq.major, upto=True)
+    k_block = _streamed_block(causal, tq.bq, tq.major, upto=True,
+                              window=window)
     kv_major = pl.BlockSpec(
         (1, 1, tq.major, d),
         lambda bi, hi, i, j: (bi, hi, k_block(i, j), 0))
     dq = pl.pallas_call(
-        _make_dq_kernel(tq, causal, scale),
+        _make_dq_kernel(tq, causal, scale, window),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(b, h, s // tq.bq, s // tq.major),
         in_specs=[q_tile, kv_major, kv_major, q_tile, column, column],
@@ -564,7 +675,8 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
     # dk/dv reads lse and delta as lane-dense rows: (B, H, 1, S)
     # holds the same numbers in the same order
     rows = (b, h, 1, s)
-    q_block = _streamed_block(causal, tk.bk, tk.major, upto=False)
+    q_block = _streamed_block(causal, tk.bk, tk.major, upto=False,
+                              window=window)
     k_tile = _tile_spec(pl, tk.bk, d)
     q_major = pl.BlockSpec(
         (1, 1, tk.major, d),
@@ -573,7 +685,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
         (1, 1, 1, tk.major),
         lambda bi, hi, i, j: (bi, hi, 0, q_block(i, j)))
     dk, dv = pl.pallas_call(
-        _make_dkv_kernel(tk, causal, scale),
+        _make_dkv_kernel(tk, causal, scale, window),
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),

@@ -173,9 +173,11 @@ def ring_self_attention(q, k, v, *, axis_name, causal=True, scale=None,
     return (acc_o / denom).astype(q.dtype)
 
 
-def attention_reference(q, k, v, *, causal=True, scale=None):
+def attention_reference(q, k, v, *, causal=True, scale=None, window=None):
     """Dense single-device attention (test oracle / the headline
-    TRAINING path — ``LlamaConfig.attention="reference"``).
+    TRAINING path — ``LlamaConfig.attention="reference"``). With
+    `window` (causal only) query i sees the keys j with
+    ``0 <= i - j < window``.
 
     Same MXU discipline as :func:`_block_attend`: scores and the PV
     product run in the input dtype with fp32 accumulation; only the
@@ -186,9 +188,14 @@ def attention_reference(q, k, v, *, causal=True, scale=None):
     scale = scale or (d ** -0.5)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     if causal:
         sq, sk = s.shape[-2:]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        back = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        mask = back >= 0
+        if window is not None:
+            mask = mask & (back < window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,

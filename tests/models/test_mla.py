@@ -149,7 +149,7 @@ def test_a_mixers_shape_is_checked_where_the_pattern_has_its_letter():
         config(qk_rope_dim=7)
     with pytest.raises(ValueError, match="one head size"):
         config(v_dim=16, attention="flash")
-    with pytest.raises(ValueError, match=r"\['\*', 'D', 'E', 'G', 'L', 'M'\]"):
+    with pytest.raises(ValueError, match=r"\['\*', 'D', 'E', 'F', 'G', 'L', 'M', 'S'\]"):
         config(pattern="LX")
 
 

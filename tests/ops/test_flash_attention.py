@@ -368,7 +368,7 @@ def test_env_tiles_still_override_the_rule(monkeypatch):
         seen = []
         monkeypatch.setattr(
             attention, "_flash_core",
-            lambda q, k, v, causal, scale, bq, bk, interpret:
+            lambda q, k, v, causal, scale, bq, bk, interpret, window:
                 seen.append((bq, bk)) or q)
         q = jnp.ones((1, 256, 1, 16), jnp.float32)
         attention.flash_attention(q, q, q, interpret=True)
@@ -378,3 +378,150 @@ def test_env_tiles_still_override_the_rule(monkeypatch):
         monkeypatch.undo()
         importlib.reload(attention)
     assert attention._DEFAULT_FLASH_BLOCK_Q == 0
+
+
+# -- ISSUE 35: a causal window, walked and not only masked -------------------
+
+
+def _windowed_pair(window, **tiles):
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=True, window=window,
+                               interpret=True, **tiles)
+
+    def ref(q_, k_, v_):
+        return attention_reference(q_, k_, v_, causal=True, window=window)
+
+    return flash, ref
+
+
+def _assert_same(flash, ref, q, k, v, cot, tol=2e-5, gtol=1e-4):
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(ref(q, k, v), np.float32), atol=tol, rtol=tol)
+    for name, got, want in zip(
+            "qkv", _all_grads(flash, q, k, v, cot),
+            _all_grads(ref, q, k, v, cot)):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=gtol, rtol=gtol, err_msg=f"d{name}")
+
+
+def test_reference_window_is_the_written_mask():
+    """``0 <= i - j < window``, written out in numpy."""
+    rng = np.random.RandomState(20)
+    q, k, v, _ = _qkv(rng, 1, 24, 2, 8, jnp.float32)
+    got = np.asarray(attention_reference(q, k, v, window=5))
+    i, j = np.arange(24)[:, None], np.arange(24)[None, :]
+    seen = (i - j >= 0) & (i - j < 5)
+    s = np.einsum("bqhd,bkhd->bhqk", np.asarray(q), np.asarray(k)) / 8 ** .5
+    s = np.where(seen, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True),
+                     np.asarray(v))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    with pytest.raises(ValueError, match="causal"):
+        attention_reference(q, k, v, causal=False, window=5)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=5, interpret=True)
+
+
+@pytest.mark.parametrize("window", [1, 100, 256, 300, 512, 1000])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_window_rule_tiles(tiles_256, window, dtype):
+    """Forward, dq, dk and dv against the reference at S = 1024 with
+    tiles of 256: windows below a tile, of a tile, not a multiple of
+    one, of two, and just under the sequence: plain tiles, tiles that
+    cross either edge, and tiles that cross both."""
+    rng = np.random.RandomState(21)
+    q, k, v, cot = _qkv(rng, 1, 1024, 2, 32, dtype)
+    tol = (2e-5, 1e-4) if dtype == jnp.float32 else (2e-2, 6e-2)
+    _assert_same(*_windowed_pair(window), q, k, v, cot, *tol)
+
+
+@pytest.mark.parametrize("window", [1024, 5000])
+def test_window_that_covers_the_sequence_is_the_causal_program(window):
+    """At and past the sequence the window compiles to the causal
+    kernels: the same jaxpr, the same bits."""
+    rng = np.random.RandomState(22)
+    q, k, v, cot = _qkv(rng, 1, 1024, 1, 32, jnp.float32)
+    causal = lambda q_, k_, v_: flash_attention(
+        q_, k_, v_, causal=True, interpret=True)
+    flash, ref = _windowed_pair(window)
+    for got, want in zip(
+            (flash(q, k, v), *_all_grads(flash, q, k, v, cot)),
+            (causal(q, k, v), *_all_grads(causal, q, k, v, cot))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    grads = lambda f: str(jax.make_jaxpr(
+        lambda *a: _all_grads(f, *a, cot))(q, k, v))
+    assert grads(flash) == grads(causal)
+    assert grads(_windowed_pair(512)[0]) != grads(causal)
+
+
+@pytest.mark.parametrize("window", [40, 128, 200, 384])
+@pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128), (64, 256),
+                                   (128, 128)])
+def test_window_across_major_blocks(monkeypatch, window, bq, bk):
+    """One tile a major block: the window's edge and the diagonal fall
+    in different grid steps, blocks before the edge are neither
+    fetched nor walked, and the state crosses in scratch."""
+    monkeypatch.setattr(flash_kernels, "VMEM_BUDGET", 1)
+    assert flash_kernels.flash_tiles("dkv", 512, 32, 4, bq, bk).major < 512
+    rng = np.random.RandomState(23)
+    q, k, v, cot = _qkv(rng, 1, 512, 2, 32, jnp.float32)
+    _assert_same(*_windowed_pair(window, block_q=bq, block_kv=bk),
+                 q, k, v, cot)
+
+
+def test_window_padded_sequence():
+    rng = np.random.RandomState(24)
+    q, k, v, cot = _qkv(rng, 2, 200, 2, 16, jnp.float32)
+    _assert_same(*_windowed_pair(70), q, k, v, cot)
+
+
+@pytest.mark.parametrize("upto", [True, False], ids=["keys", "queries"])
+def test_streamed_blocks_hold_at_both_ends_of_a_window(upto):
+    """The index maps fetch no block outside [first visible, last
+    visible] of a stationary tile: checked against the written mask."""
+    s, tile, major, window = 4096, 256, 512, 700
+    block = flash_kernels._streamed_block(
+        True, tile, major, upto=upto, window=window)
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = (i - j >= 0) & (i - j < window)
+    if not upto:
+        seen = seen.T            # rows: keys; columns: the queries
+    for t in range(s // tile):
+        cols = np.nonzero(seen[t * tile:(t + 1) * tile].any(0))[0]
+        first, last = cols.min() // major, cols.max() // major
+        held = [int(block(t, b)) for b in range(s // major)]
+        assert held == [min(max(b, first), last)
+                        for b in range(s // major)], (t, held)
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("s,tile,window,walked,causal", [
+    (8192, 512, 2048, 70, 136), (8192, 1024, 2048, 21, 36),
+    (8192, 512, None, 136, 136), (1024, 256, 1, 4, 10),
+    (1024, 256, 300, 9, 10)])
+def test_tiles_walked_counts_the_tiles_with_a_visible_pair(
+        kernel, s, tile, window, walked, causal):
+    t = flash_kernels.FlashTiles(tile, tile, s, 0)
+    assert flash_kernels.tiles_walked(kernel, s, t, window) == walked
+    assert flash_kernels.tiles_walked(kernel, s, t) == causal
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = (i >= j) & ((i - j < window) if window else True)
+    assert seen.reshape(s // tile, tile, s // tile, tile).any(
+        (1, 3)).sum() == walked
+
+
+def test_flash_tiles_counter_says_the_window(telemetry):
+    q = jnp.ones((1, 1024, 1, 16), jnp.float32)
+    jax.jit(jax.grad(lambda q_: flash_attention(
+        q_, q_, q_, causal=True, window=256, block=256,
+        interpret=True).sum()))(q)
+    counts = _tile_counts(telemetry)
+    assert sorted(counts) == [
+        ("dkv", "argument"), ("dq", "argument"), ("fwd", "argument")]
+    for _, labels in counts.values():
+        assert (labels["window"], labels["tiles_walked"],
+                labels["tiles_causal"]) == ("256", "7", "10")

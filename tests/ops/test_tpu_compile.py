@@ -418,6 +418,43 @@ def test_flash_attention_at_head_size_256(one_chip, backward):
     assert all(sum(name in k for k in kernels) == 1 for name in names), kernels
 
 
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_attention_with_a_window(one_chip, backward):
+    """The three kernels with a causal window of 2048 over S = 8192 at
+    32 heads of 128 (``trinitymini-lora-train``'s window layers): the
+    rule's tiles, the streamed side held at both ends of what a tile
+    sees, the walk's three loops, inside the VMEM budget."""
+    from sparkdl_tpu.ops.attention import flash_attention
+    from sparkdl_tpu.ops.pallas.flash_attention import (
+        VMEM_BUDGET,
+        flash_tiles,
+        tiles_walked,
+    )
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, window=2048, interpret=False)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    for kernel in ("fwd", "dq", "dkv"):
+        tiles = flash_tiles(kernel, 8192, HEAD_DIM, 2)
+        assert tiles.vmem_bytes <= VMEM_BUDGET
+        assert (tiles_walked(kernel, 8192, tiles, 2048)
+                < 0.65 * tiles_walked(kernel, 8192, tiles))
+    qkv = _shape(one_chip, (1, 8192, N_HEADS, HEAD_DIM), jnp.bfloat16)
+    compiled = _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+    kernels = re.findall(
+        r"%(\w+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())
+    names = ["sparkdl_flash_fwd"] + ["sparkdl_flash_dq", "sparkdl_flash_dkv"
+                                     ] * backward
+    assert len(kernels) == len(names), kernels
+    assert all(sum(name in k for k in kernels) == 1 for name in names), kernels
+
+
 def test_mla_train_step_fits_and_carries_its_kernels(one_chip, monkeypatch):
     """One LoRA step of ``glm47flash-lora-train`` as the benchmark
     builds it (``chipbench/configs/glm-4.7-flash.json``: pattern ``LD``
