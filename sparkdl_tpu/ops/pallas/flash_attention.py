@@ -17,12 +17,18 @@ for the chip (what each choice bought on a v5e is in PERF.md, PR 25):
   kernel (q for the forward and dq, k/v for dk/dv) is one tile of a
   grid ``(batch, heads, tiles, major blocks)``; the streamed side comes
   in *major* blocks, as much of the sequence as the reckoned VMEM
-  allows, and the kernel walks a major block in tile-wide steps. At
-  S = 2048 that is all of it: one grid step a tile, the state in the
-  loop's carry, no scratch. Past that the fourth grid axis carries the
-  state in VMEM scratch, so VMEM does not grow with S, and an index map
-  that stops at the diagonal keeps blocks no row can see from being
-  fetched at all.
+  allows, and the kernel walks a major block in tile-wide steps. The
+  VMEM the rule plans with is what the kernel then ASKS Mosaic for
+  (``vmem_limit_bytes``, only past what the default scope holds), so
+  through S = 32768 at head size 128 and S = 16384 at 256 that is all
+  of the sequence: one grid step a tile, the state in the loop's
+  carry, no scratch, K/V (or q/do) of a head fetched once. On a v5e at
+  S = 8192 that took a third off the forward and a tenth to a third
+  off the backward against major blocks of 1024-4096 under the default
+  scope, with the same bits out (PERF.md, PR 36). Past the ceiling the
+  fourth grid axis carries the state in VMEM scratch, so VMEM does not
+  grow with S, and an index map that stops at the diagonal keeps
+  blocks no row can see from being fetched at all.
 - **The causal mask is applied on the diagonal only.** A walk is ONE
   loop over the tiles wholly below the diagonal (no iota, no compare,
   no select), then the tiles that cross it, unrolled, with the mask;
@@ -65,11 +71,23 @@ from sparkdl_tpu import observe
 
 NEG_INF = -1e30
 _LANES = 128
-# Mosaic's scoped VMEM on a v5e is 16 MiB a kernel unless raised. The
-# rule lets a kernel reckon three quarters of it: the reckoning counts
-# pipelined blocks, scratch and the tile-sized temporaries, not what
-# the compiler keeps besides.
-VMEM_BUDGET = 12 * 2 ** 20
+# A v5e core has 128 MiB of VMEM, of which Mosaic scopes 16 MiB to a
+# kernel unless the kernel asks for more. The rule lets a kernel reckon
+# three eighths of the core's VMEM. The reckoning counts pipelined
+# blocks, scratch and the tile-sized temporaries, not what the compiler
+# keeps besides (compiled for a v5e, the kernels took 0.9 to 1.1 of
+# it), so a kernel whose reckoning is past three quarters of the
+# default scope (`_UNASKED_BYTES`, all the rule allowed before PR 36)
+# asks for the reckoning and a third more (`FlashTiles.vmem_limit`: at
+# most half of the core's VMEM). Under that it asks for nothing and is
+# the program it was. The ceiling is from a v5e (PERF.md, PR 36): at
+# S = 8192, 16384 and 32768 and head sizes 128 and 256 every kernel
+# was faster the more of the streamed side it held, up to a reckoning
+# of 76.5 MiB; the ceiling admits the whole of S = 32768 at head size
+# 128 (dk/dv reckons 42.5 MiB) and of S = 8192 at 256 (25.5 MiB), the
+# cells' shapes among them, and leaves the other half of VMEM alone.
+VMEM_BUDGET = 48 * 2 ** 20
+_UNASKED_BYTES = 12 * 2 ** 20
 # Rows of the stationary tile and width of a step, for every kernel:
 # 512 x 512 was the fastest of 128-2048 x 128-2048 for each of the
 # three on a v5e at (4, 32, 2048, 128) (PERF.md, PR 25).
@@ -104,6 +122,15 @@ class FlashTiles(NamedTuple):
     bk: int
     major: int
     vmem_bytes: int
+
+    @property
+    def vmem_limit(self):
+        """The ``vmem_limit_bytes`` the kernel asks Mosaic for: the
+        reckoned need and a third more, or 0 (nothing asked) where the
+        need is within what the default scope was always trusted with."""
+        if self.vmem_bytes <= _UNASKED_BYTES:
+            return 0
+        return self.vmem_bytes + self.vmem_bytes // 3
 
 
 def _round_up(x, m):
@@ -145,7 +172,10 @@ def flash_tiles(kernel, s, d, itemsize, bq=None, bk=None):
     ``_TARGET_TILE`` that divides the sequence. The streamed side then
     comes in the largest major block, a multiple of its tile that
     divides the sequence, whose reckoned VMEM stays under
-    ``VMEM_BUDGET``; tiles the rule chose halve until one fits.
+    ``VMEM_BUDGET``: the whole sequence where that fits (the tiles are
+    not halved to make it fit: 512 x 512 whole beat 256 x 512 whole in
+    every kernel; PERF.md, PR 36). Tiles the rule chose halve only
+    where not even one tile a block fits.
     """
     fit = min(_TARGET_TILE, s)
     while s % fit:
@@ -219,7 +249,9 @@ def _count_tiles(kernel, s, d, tiles, explicit, window=None):
     if not observe.enabled():
         return
     observe.inc("flash.tiles", kernel=kernel, s=s, d=d, bq=tiles.bq,
-                bk=tiles.bk, chosen="argument" if explicit else "rule",
+                bk=tiles.bk, major=tiles.major,
+                vmem_limit=tiles.vmem_limit,
+                chosen="argument" if explicit else "rule",
                 window=window or 0,
                 tiles_walked=tiles_walked(kernel, s, tiles, window),
                 tiles_causal=tiles_walked(kernel, s, tiles))
@@ -326,7 +358,8 @@ def _queries_edge(k_start, q0, bq, bk, window):
 def _across_major_blocks(pl, scratch, init, walk, finish):
     """``finish(walk(init))`` where the major block is the whole
     sequence (no scratch: a round trip through VMEM a program is a
-    fifth of the forward's time at S = 2048). Else the fourth grid axis
+    fifth of the forward's time at S = 2048; at S = 8192, with the
+    grid steps it brings, a third). Else the fourth grid axis
     walks the major blocks in turn: the state starts as `init` on the
     first, crosses grid steps in `scratch`, and `finish` runs on the
     last."""
@@ -452,12 +485,13 @@ def _scratch(t, s, *shapes):
     return [pltpu.VMEM(shape, jnp.float32) for shape in shapes]
 
 
-def _compiler_params():
+def _compiler_params(t):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"),
+        vmem_limit_bytes=t.vmem_limit or None,
     )
 
 
@@ -510,7 +544,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, scale=None, bq=None,
         out_specs=(q_spec, lse_spec) if return_lse else q_spec,
         scratch_shapes=_scratch(
             t, s, (t.bq, 1), (t.bq, _lane_sums_width(t.bk)), (t.bq, d)),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(t),
         interpret=interpret,
         name="sparkdl_flash_fwd",
     )(q, k, v)
@@ -667,7 +701,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
         in_specs=[q_tile, kv_major, kv_major, q_tile, column, column],
         out_specs=q_tile,
         scratch_shapes=_scratch(tq, s, (tq.bq, d)),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(tq),
         interpret=interpret,
         name="sparkdl_flash_dq",
     )(q, k, v, do, lse, delta)
@@ -694,7 +728,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal=True,
         in_specs=[q_major, k_tile, k_tile, q_major, row, row],
         out_specs=(k_tile, k_tile),
         scratch_shapes=_scratch(tk, s, (tk.bk, d), (tk.bk, d)),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(tk),
         interpret=interpret,
         name="sparkdl_flash_dkv",
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))

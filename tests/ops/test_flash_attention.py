@@ -157,14 +157,20 @@ def test_fused_backward_padded_seq():
 KERNELS = ("fwd", "dq", "dkv")
 
 
+MIB = 2 ** 20
+
+
 @pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("s", [64, 128, 200, 2048, 8192, 32768])
+@pytest.mark.parametrize("s", [64, 128, 200, 2048, 8192, 32768, 65536])
 def test_tile_rule(s, d, kernel):
     """The rule's tiles, for the sequence as ``flash_attention`` pads
     it: they divide it, a block's last two dimensions are multiples of
     (8, 128) or the whole dimension, and the VMEM the rule reckons
-    stays under the scoped limit, at the cell's S and far past it."""
+    stays under its ceiling, at the cells' S and far past it. A kernel
+    asks Mosaic for more than its default exactly where the reckoning
+    is past what the default was trusted with, and then for at least
+    the reckoning."""
     from sparkdl_tpu.ops._dispatch import block_for
 
     padded = -(-s // block_for(s)) * block_for(s)
@@ -177,12 +183,22 @@ def test_tile_rule(s, d, kernel):
         assert rows % 8 == 0 or rows == padded
     # dk/dv's lse and delta rows are (1, major) blocks: major is lanes
     assert t.major % 128 == 0 or t.major == padded
-    # v5e scopes 16 MiB of VMEM a kernel
-    assert t.vmem_bytes <= flash_kernels.VMEM_BUDGET < 16 * 2 ** 20
+    # a v5e core has 128 MiB of VMEM and Mosaic scopes 16 of it unasked
+    assert t.vmem_bytes <= flash_kernels.VMEM_BUDGET < 128 * MIB // 2
+    assert (t.vmem_limit > 0) == (t.vmem_bytes > 12 * MIB)
+    assert t.vmem_limit == 0 or (
+        t.vmem_bytes + 2 * MIB <= t.vmem_limit <= 128 * MIB // 2)
     assert t == flash_kernels.flash_tiles(kernel, padded, d, 2)   # pure
     if padded >= 512:
         # several MXU passes deep, not the 128 of before
         assert min(t.bq, t.bk) >= 256
+    # the streamed side is ONE major block at the cells' S = 8192 and
+    # through 32768 (no scratch, the state in the walk's carry); past
+    # the ceiling it comes in major blocks and VMEM stops growing with S
+    assert (t.major == padded) == (s <= 32768)
+    if s == 2048:
+        # the tiles PR 25 tuned there, and nothing asked of Mosaic
+        assert t[:3] == (512, 512, 2048) and t.vmem_limit == 0
 
 
 def test_tile_rule_keeps_explicit_tiles():
@@ -281,6 +297,48 @@ def test_major_blocks_carry_the_state(monkeypatch, causal, bq, bk):
             err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window"])
+def test_one_block_and_major_blocks_agree(monkeypatch, telemetry, window,
+                                          bq, bk):
+    """The kernels built with the streamed side whole (one grid step a
+    tile, the state in the walk's carry) against the same kernels
+    forced into one tile a major block (the state through scratch):
+    forward, ``lse``, dq, dk and dv, causal and with a window that is
+    no multiple of a tile. The entry points are called un-jitted, so
+    that no cached trace stands in for either build, and ``flash.tiles``
+    says that both were built."""
+    rng = np.random.RandomState(14)
+    q, k, v, do = (jnp.asarray(rng.randn(1, 2, 512, 32), jnp.float32)
+                   for _ in range(4))
+
+    def build():
+        o, lse = flash_kernels.flash_attention_bhsd.__wrapped__(
+            q, k, v, bq=bq, bk=bk, window=window, return_lse=True,
+            interpret=True)
+        delta = jnp.sum(do * o, -1, keepdims=True)
+        return (o, lse) + flash_kernels.flash_attention_bwd_bhsd.__wrapped__(
+            q, k, v, do, lse, delta, bq=bq, bk=bk, window=window,
+            interpret=True)
+
+    whole = build()
+    monkeypatch.setattr(flash_kernels, "VMEM_BUDGET", 1)
+    blocks = build()
+    majors = sorted(
+        (c["labels"]["kernel"], int(c["labels"]["major"]))
+        for c in telemetry.metrics().snapshot()["counters"]
+        if c["name"] == "flash.tiles")
+    step = max(bq, bk)
+    assert majors == [("dkv", step), ("dkv", 512), ("dq", step), ("dq", 512),
+                      ("fwd", step), ("fwd", 512)]
+    for name, got, want, tol in zip(
+            ("o", "lse", "dq", "dk", "dv"), whole, blocks,
+            (2e-5, 2e-5, 1e-4, 1e-4, 1e-4)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=tol, rtol=tol,
+            err_msg=name)
+
+
 def test_scale_is_folded_not_dropped():
     """A scale that is no power of two, through forward and backward:
     folded into q (forward, dq), k (dk/dv) and the final dq / dk."""
@@ -337,10 +395,25 @@ def test_flash_tiles_counted_once_a_traced_kernel(telemetry):
         assert value == 1
         assert (labels["s"], labels["d"]) == ("256", "16")
         assert (labels["bq"], labels["bk"]) == ("256", "256")
+        # the streamed side whole, inside what Mosaic scopes unasked
+        assert (labels["major"], labels["vmem_limit"]) == ("256", "0")
     jax.jit(lambda q_: flash_attention(
         q_, q_, q_, causal=True, block_q=64, interpret=True))(q)
     value, labels = _tile_counts(telemetry)[("fwd", "argument")]
     assert value == 1 and (labels["bq"], labels["bk"]) == ("64", "256")
+
+
+def test_flash_tiles_counter_says_what_was_asked_of_mosaic(telemetry):
+    """At S = 8192 the forward's streamed side is whole and the kernel
+    asks for the VMEM that takes (tracing counts; nothing runs)."""
+    q = jax.ShapeDtypeStruct((1, 1, 8192, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q_: flash_kernels.flash_attention_bhsd(
+        q_, q_, q_, interpret=True), q)
+    (value, labels), = _tile_counts(telemetry).values()
+    tiles = flash_kernels.flash_tiles("fwd", 8192, 128, 2)
+    assert value == 1 and tiles.vmem_limit > 16 * MIB
+    assert (labels["major"], labels["vmem_limit"]) == (
+        "8192", str(tiles.vmem_limit))
 
 
 def test_flash_tiles_not_counted_with_telemetry_off():
@@ -525,3 +598,4 @@ def test_flash_tiles_counter_says_the_window(telemetry):
     for _, labels in counts.values():
         assert (labels["window"], labels["tiles_walked"],
                 labels["tiles_causal"]) == ("256", "7", "10")
+        assert (labels["major"], labels["vmem_limit"]) == ("1024", "0")

@@ -72,11 +72,15 @@ def _shape(one_chip, shape, dtype):
 
 
 @pytest.mark.parametrize("backward", [False, True])
-@pytest.mark.parametrize("batch,seq", [(4, 2048), (1, 8192)])
+@pytest.mark.parametrize("batch,seq", [(4, 2048), (1, 8192), (1, 32768),
+                                       (1, 65536)])
 def test_flash_attention(one_chip, batch, seq, backward):
-    """The three kernels with the tiles the rule chooses: at the
-    benchmark cell's shape, and at S = 8192, where the streamed side no
-    longer fits VMEM whole and comes in major blocks."""
+    """The three kernels with the tiles the rule chooses: at the Mistral
+    cell's shape (whole inside Mosaic's default scope: nothing asked),
+    at S = 8192 and 32768, where the streamed side is whole too and
+    each kernel asks Mosaic for the VMEM that takes, and at S = 65536,
+    past the rule's ceiling, where it comes in major blocks. An
+    overfull VMEM fails here, without a chip."""
     from sparkdl_tpu.ops.attention import flash_attention
     from sparkdl_tpu.ops.pallas.flash_attention import flash_tiles
 
@@ -88,8 +92,10 @@ def test_flash_attention(one_chip, batch, seq, backward):
             lambda *a: fwd(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    assert (flash_tiles("fwd", seq, HEAD_DIM, 2).major < seq) == (
-        seq == 8192)
+    for kernel in ("fwd", "dq", "dkv"):
+        tiles = flash_tiles(kernel, seq, HEAD_DIM, 2)
+        assert (tiles.major == seq) == (seq <= 32768)
+        assert (tiles.vmem_limit > 0) == (seq > 2048)
     qkv = _shape(one_chip, (batch, seq, N_HEADS, HEAD_DIM), jnp.bfloat16)
     compiled = _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
     assert compiled.as_text().count("tpu_custom_call") >= (
@@ -388,7 +394,8 @@ def test_hybrid_train_step_goes_with_the_rows_routed_here(
 def test_flash_attention_at_head_size_256(one_chip, backward):
     """The three kernels at latent attention's expanded shape, 20 heads
     of 256 with as many key/value heads over S = 8192: tiles of 512,
-    the streamed side in major blocks under the VMEM budget."""
+    the streamed side whole under the rule's ceiling, and more VMEM
+    asked of Mosaic than the reckoning."""
     from sparkdl_tpu.ops.attention import flash_attention
     from sparkdl_tpu.ops.pallas.flash_attention import (
         VMEM_BUDGET,
@@ -405,8 +412,9 @@ def test_flash_attention_at_head_size_256(one_chip, backward):
 
     for kernel in ("fwd", "dq", "dkv"):
         tiles = flash_tiles(kernel, 8192, 256, 2)
-        assert (tiles.bq, tiles.bk) == (512, 512) and tiles.major < 8192
-        assert tiles.vmem_bytes <= VMEM_BUDGET
+        assert tiles[:3] == (512, 512, 8192)
+        assert 16 * 2 ** 20 < tiles.vmem_bytes <= VMEM_BUDGET
+        assert tiles.vmem_limit > tiles.vmem_bytes
     qkv = _shape(one_chip, (1, 8192, 20, 256), jnp.bfloat16)
     compiled = _compile(fwd_bwd if backward else fwd, qkv, qkv, qkv)
     kernels = re.findall(
@@ -422,8 +430,9 @@ def test_flash_attention_at_head_size_256(one_chip, backward):
 def test_flash_attention_with_a_window(one_chip, backward):
     """The three kernels with a causal window of 2048 over S = 8192 at
     32 heads of 128 (``trinitymini-lora-train``'s window layers): the
-    rule's tiles, the streamed side held at both ends of what a tile
-    sees, the walk's three loops, inside the VMEM budget."""
+    rule's tiles, the streamed side whole (one grid step a tile, so the
+    window's far edge and the diagonal are in one walk), the walk's
+    three loops, inside the VMEM each kernel asks for."""
     from sparkdl_tpu.ops.attention import flash_attention
     from sparkdl_tpu.ops.pallas.flash_attention import (
         VMEM_BUDGET,
@@ -441,7 +450,8 @@ def test_flash_attention_with_a_window(one_chip, backward):
 
     for kernel in ("fwd", "dq", "dkv"):
         tiles = flash_tiles(kernel, 8192, HEAD_DIM, 2)
-        assert tiles.vmem_bytes <= VMEM_BUDGET
+        assert tiles.major == 8192
+        assert tiles.vmem_bytes <= VMEM_BUDGET and tiles.vmem_limit
         assert (tiles_walked(kernel, 8192, tiles, 2048)
                 < 0.65 * tiles_walked(kernel, 8192, tiles))
     qkv = _shape(one_chip, (1, 8192, N_HEADS, HEAD_DIM), jnp.bfloat16)
