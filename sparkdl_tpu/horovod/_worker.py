@@ -104,9 +104,9 @@ def worker_io(rank, local_log_path=None):
     from sparkdl_tpu.horovod.control_plane import get_worker_client
 
     # Launch record (always on, no thread): the control-plane connect
-    # and, from here on, every compile or cache load JAX reports are
-    # lifecycle spans; they ride to the driver in LAUNCH frames, one
-    # before READY (main) and one before BYE (below).
+    # and, from here on, every trace, lowering and compile or cache
+    # load JAX reports are lifecycle spans; they ride to the driver in
+    # LAUNCH frames, one before READY (main) and one before BYE (below).
     observe.watch_compiles()
     with observe.span("worker.connect", cat="launch"):
         client = get_worker_client()
@@ -294,13 +294,20 @@ def main():
             import sparkdl_tpu.hvd as hvd
 
             hvd.init()
+            # one worker.backend a rank a launch: a gang's ranks reached
+            # their chips inside hvd.init, a single worker does here, so
+            # that a worker that reports READY has its chip
+            if not any(s["name"] == "worker.backend"
+                       for s in observe.launch_record().report()):
+                _state.reach_backend()
 
             # 4. Tell the driver this worker is up (gang barrier on the
             # driver side — fail-fast if any worker never arrives,
             # reference runner_base.py:54-58).
             if client is not None:
                 # the spans so far first: the driver holds this rank's
-                # boot, connect and hvd.init when it counts it ready
+                # boot, connect, hvd.init and reach of its chip when it
+                # counts it ready
                 client.send_launch_spans(observe.launch_record().drain())
                 client.send_ready()
             observe.instant("worker.ready", cat="worker", rank=rank)

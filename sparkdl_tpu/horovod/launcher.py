@@ -126,38 +126,66 @@ def probe_local_devices(platform):
 
     Every call is a ``gang.slot_probe`` launch span: the child's
     lifetime (one reach of the chip), or zero-length with
-    ``cached=True`` when the cache answered."""
+    ``cached=True`` when the cache answered. Where a child ran the span
+    says where its seconds went: ``child_boot_s`` (span start to the
+    child's first line of Python: fork and interpreter),
+    ``child_import_s`` (``import jax``), ``child_backend_s``
+    (``jax.local_devices()``) and ``child_exit_s`` (the child's last
+    line to the span's end: interpreter teardown and the chip's
+    release). The last two are what a probe that started no runtime
+    would not spend."""
     from sparkdl_tpu import observe
 
-    before = _probe_local_devices.cache_info().misses
+    before, started = _probe_local_devices.cache_info().misses, time.time()
     with observe.span("gang.slot_probe", cat="launch") as probe:
-        found = _probe_local_devices(platform)
-        probe.args["cached"] = (
+        found, child = _probe_local_devices(platform)
+        probe.args["cached"] = cached = (
             _probe_local_devices.cache_info().misses == before)
+        if child and not cached:
+            first, import_s, backend_s, last = child
+            probe.args.update(
+                child_boot_s=first - started,
+                child_import_s=import_s, child_backend_s=backend_s,
+                child_exit_s=time.time() - last)
     return found
+
+
+# the probe child's second line: its own clock at its first line of
+# Python, the seconds of `import jax` and of `jax.local_devices()`, its
+# clock at this line (an older worker image prints the first line alone)
+_CHILD_TIMES = "sparkdl-probe-times"
 
 
 @functools.lru_cache(maxsize=None)
 def _probe_local_devices(platform):
+    """``(LocalDevices, child's times or None)``."""
     if platform == "cpu":   # a gang on CPU devices: no child to ask
-        return LocalDevices(os.cpu_count() or 1, "cpu", None)
+        return LocalDevices(os.cpu_count() or 1, "cpu", None), None
     code = (
+        "import time\n"
+        "t0 = time.time()\n"
         "import jax\n"
+        "t1 = time.time()\n"
         + (f"jax.config.update('jax_platforms', {platform!r})\n" if platform else "")
         + "ds = jax.local_devices()\n"
+        "t2 = time.time()\n"
         "cs = [getattr(d, 'coords', None) or (i, 0, 0) "
         "for i, d in enumerate(ds)]\n"
         "print(len(ds), ds[0].platform, "
         "','.join(str(max(c) + 1) for c in zip(*cs)))\n"
+        f"print({_CHILD_TIMES!r}, t0, t1 - t0, t2 - t1, time.time())\n"
     )
     try:
         out = subprocess.run(
             [sys.executable, "-c", code],
             capture_output=True, text=True, timeout=120,
         )
-        count, seen, bounds = out.stdout.strip().splitlines()[-1].split()
+        lines, child = out.stdout.strip().splitlines(), None
+        if lines[-1].startswith(_CHILD_TIMES):
+            child = tuple(float(t) for t in lines.pop().split()[1:])
+        count, seen, bounds = lines[-1].split()
         return LocalDevices(
-            int(count), seen, tuple(int(b) for b in bounds.split(",")))
+            int(count), seen, tuple(int(b) for b in bounds.split(","))), child
     except subprocess.TimeoutExpired:
         raise SlotProbeError(
             "slot discovery timed out after 120s probing local "
@@ -732,6 +760,7 @@ def _launch_process_gang(np, main, kwargs, driver_log_verbosity,
     from sparkdl_tpu.horovod.control_plane import ControlPlaneServer
     from sparkdl_tpu.horovod.supervisor import GangFailure
     from sparkdl_tpu.horovod.topology import Placement, is_local_host
+    from sparkdl_tpu.observe.launch import job_line, summary_line
 
     spec_placement = Placement.from_env(os.environ)
     num_workers, mode, total_slots = _resolve_num_workers(np, spec_placement)
@@ -1088,6 +1117,8 @@ def _launch_process_gang(np, main, kwargs, driver_log_verbosity,
                     + _tail(os.path.join(job_dir, f"rank-{r}.log"))
                     for r in bad
                 )
+            # what rank 0 got through before it failed
+            logger.info("%s", job_line(observe.launch_report(launch_id)))
             # GangFailure (a RuntimeError) carries the evidence the
             # supervisor's transient-vs-permanent classifier reads:
             # per-rank exit codes (negative = signal = what preemption
@@ -1131,8 +1162,6 @@ def _launch_process_gang(np, main, kwargs, driver_log_verbosity,
                 time.sleep(0.05)
         observe.instant("gang.ready", cat="launch",
                         num_workers=num_workers)
-        from sparkdl_tpu.observe.launch import summary_line
-
         logger.info("%s", summary_line(observe.launch_report(launch_id)))
 
         # Monitor the running gang. If one rank dies while others are
@@ -1249,6 +1278,9 @@ def _launch_process_gang(np, main, kwargs, driver_log_verbosity,
         # connections are at EOF — process every buffered frame before
         # returning (no tail-of-job log lines lost).
         server.wait_drained(5.0)
+        # the job's last LAUNCH frame is in: rank 0's way to its first
+        # step (chip, trace, lower, compile or cache load) in one line
+        logger.info("%s", job_line(observe.launch_report(launch_id)))
 
         result_bytes = None
         deadline = time.monotonic() + 30

@@ -107,6 +107,26 @@ def _init_locked(jax):
     _state.initialized = True
 
 
+def reach_backend():
+    """This worker's reach of its chip, as its ``worker.backend``
+    launch span: the first question asked of the devices starts the
+    backend (JAX reports no backend start of its own), and the span
+    says what answered: ``platform``, ``devices`` (the local count) and
+    ``kind`` (the first's). One a rank a launch, before READY: a gang's
+    ranks reach where the runtime's world is checked
+    (:func:`_exchange_ranks`), a single worker straight after
+    ``hvd.init()`` (``horovod/_worker.py``). A gang's worker only:
+    never the driver's process, never local mode."""
+    import jax
+
+    from sparkdl_tpu import observe
+
+    with observe.span("worker.backend", cat="launch") as reach:
+        devices = jax.local_devices()
+        reach.args.update(platform=devices[0].platform, devices=len(devices),
+                          kind=devices[0].device_kind)
+
+
 def _exchange_ranks(rank, size):
     """``hvd.rank()`` is the rank the launcher gave this process — the
     one its payload, its control-plane connection and its log lines
@@ -124,13 +144,8 @@ def _exchange_ranks(rank, size):
     import jax
     from jax._src import distributed as _jd
 
-    from sparkdl_tpu import observe
-
-    # the first question asked of the devices starts the backend: in
-    # a gang this is each rank's reach of its chip. (JAX reports no
-    # backend start of its own; a single worker's falls in its job.)
-    with observe.span("worker.backend", cat="launch"):
-        processes = jax.process_count()
+    reach_backend()
+    processes = jax.process_count()
     if processes != size:
         raise RuntimeError(
             f"rank {rank}: the JAX runtime reports "

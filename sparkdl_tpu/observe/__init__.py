@@ -81,7 +81,7 @@ _registry = Registry()
 _timeline = Timeline()
 _launches = _launch.LaunchRecord()
 _open_spans = threading.local()    # .stack: this thread's open _Span
-_compile_watch = None              # (event, duration) listeners, once
+_compile_watch = None              # JAX's listeners, once
 _sink = None                       # callable(payload_dict) or None
 _sink_lock = threading.Lock()      # serializes flush() payloads
 _flusher = None
@@ -293,42 +293,85 @@ def launch_report(launch_id=None):
     sorted by start: the driver's own (``gang.slot_probe``,
     ``gang.slot_claim``, ``gang.spawn``, ``gang.rendezvous``,
     ``gang.ready``) and, per rank, the workers' (``worker.boot``,
-    ``worker.connect``, ``hvd.init``, ``worker.job``, ``xla.compile``),
+    ``worker.connect``, ``hvd.init``, ``worker.backend``, ``worker.job``,
+    ``jax.trace``, ``jax.lower``, ``xla.compile``),
     each with ``launch_id``, ``rank``, ``cause``, ``start``, ``end``.
     Call it after ``HorovodRunner.run()`` returns."""
     return _launches.report(launch_id)
 
 
+# what JAX reports of a program's way to the chip, by launch span
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    _COMPILE: "xla.compile",
+}
+
+
+class _Reported(threading.local):
+    cache = None    # the verdict precedes the compile's duration
+    open = 0        # traces and lowerings begun and not ended
+    nested = 0      # those ended inside the outermost one still open
+
+
 def watch_compiles():
-    """Record every backend compile, or load from the persistent
-    cache, that JAX reports in this process as an ``xla.compile``
-    launch span: when, how long, which program (``program``) and,
-    where JAX says so, whether the persistent cache answered
-    (``cache``: ``"hit"`` or ``"miss"``). Registers two
-    ``jax.monitoring`` listeners, once; a no-op returning False where
-    JAX is not imported yet."""
+    """Record what JAX reports of every program in this process as
+    launch spans: its tracing (``jax.trace``), its lowering
+    (``jax.lower``) and its backend compile, or load from the
+    persistent cache (``xla.compile``): when, how long, which program
+    (``program``) and, for a compile where JAX says so, whether the
+    cache answered (``cache``: ``"hit"`` or ``"miss"``). Of traces and
+    lowerings the outermost alone is a span, with the count of those
+    inside it (``nested``: a ``jit`` within a ``jit`` lies in its
+    caller's interval), so a job leaves a few spans a program at any
+    depth of model. Registers three ``jax.monitoring`` listeners,
+    once; a no-op returning False where JAX is not imported yet."""
     global _compile_watch
     jax = sys.modules.get("jax")
     if jax is None or _compile_watch is not None:
         return _compile_watch is not None
     verdicts = {"/jax/compilation_cache/cache_hits": "hit",
                 "/jax/compilation_cache/cache_misses": "miss"}
-    seen = threading.local()    # the verdict precedes the duration
+    seen = _Reported()
 
     def on_event(event, **_):
         if event in verdicts:
             seen.cache = verdicts[event]
 
-    def on_duration(event, duration, fun_name=None, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            complete("xla.compile", time.time() - duration, duration,
-                     cat=_launch.CAT, program=fun_name,
-                     cache=getattr(seen, "cache", None))
-            seen.cache = None
+    def on_enter(event, _start, **_):
+        # JAX reports each of the three when it begins too, as a scalar
+        if event in _JAX_PHASES and event != _COMPILE:
+            seen.open += 1
 
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    _compile_watch = (on_event, on_duration)
+    def on_span(event, start, end, fun_name=None, **_):
+        name = _JAX_PHASES.get(event)
+        if name == "xla.compile":
+            complete(name, start, end - start, cat=_launch.CAT,
+                     program=fun_name, cache=seen.cache)
+            seen.cache = None
+        elif name is not None:
+            seen.open = max(seen.open - 1, 0)
+            if seen.open:
+                seen.nested += 1
+            else:
+                complete(name, start, end - start, cat=_launch.CAT,
+                         program=fun_name, nested=seen.nested)
+                seen.nested = 0
+
+    def on_duration(event, duration, **kw):     # a JAX that gives no span
+        now = time.time()
+        on_span(event, now - duration, now, **kw)
+
+    watch = [("event", "event", on_event), ("scalar", "scalar", on_enter)]
+    if hasattr(jax.monitoring, "register_event_time_span_listener"):
+        # JAX's own start and end, not now - duration
+        watch.append(("event_time_span", "event_time_span", on_span))
+    else:
+        watch.append(("event_duration_secs", "event_duration", on_duration))
+    for register, _, listener in watch:
+        getattr(jax.monitoring, f"register_{register}_listener")(listener)
+    _compile_watch = watch
     return True
 
 
@@ -337,8 +380,8 @@ def _unwatch_compiles():
     if _compile_watch is not None:
         from jax._src import monitoring
 
-        monitoring.unregister_event_listener(_compile_watch[0])
-        monitoring.unregister_event_duration_listener(_compile_watch[1])
+        for _, unregister, listener in _compile_watch:
+            getattr(monitoring, f"unregister_{unregister}_listener")(listener)
         _compile_watch = None
 
 
