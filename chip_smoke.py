@@ -782,8 +782,8 @@ def main(argv=None):
     # for this process and the workers that inherit its environment.
     cache_dir = export_cache_dir()
     entries_before = _cache_entries(cache_dir)
-    # What is attached, asked of a child that exits before anything
-    # else starts (the launcher's own slot probe; its answer is kept).
+    # What is attached, without starting a runtime in this process
+    # (the launcher's own slot probe; its answer is kept).
     local = launcher.probe_local_devices(
         os.environ.get(launcher.WORKER_PLATFORM_ENV))
     if local.platform != "tpu" or local.count < args.chips:

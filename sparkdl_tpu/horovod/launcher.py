@@ -26,6 +26,7 @@ automatically when pyspark is importable (see
 
 import collections
 import functools
+import glob
 import logging
 import os
 import socket
@@ -66,7 +67,11 @@ class SlotProbeError(RuntimeError):
     died on a wedged accelerator). Surfaced instead of guessing a count:
     an optimistic guess turns into a misleading "only N slots" error
     at launch time. Never retried — the relaunch loop would just re-run
-    the same 120s probe against the same wedged backend."""
+    the same 120s probe against the same wedged backend. Raised only
+    where the probe asked a child: where the host's device nodes
+    answered, no runtime was started, and a wedged or busy chip fails
+    at the worker's start instead (a ``GangFailure`` of kind
+    ``start_failure`` with the rank's log tail)."""
 
 
 class SlotWaitTimeout(RuntimeError):
@@ -113,41 +118,126 @@ _NO_PROBE_HINT = (f"(only {WORKER_PLATFORM_ENV}=cpu, a gang on CPU "
                   "devices, is launched without this probe)")
 
 
-def probe_local_devices(platform):
+def probe_local_devices(platform, *, ask_child=False):
     """Count local accelerator devices WITHOUT initializing a backend in
     the driver process (a driver that claims the TPU would starve its
     own workers — the analogue of the reference's driver-has-no-GPU
-    assumption, ``runner_base.py:44-45``): a child does, reports what
-    it saw (count, platform, and the chip grid its devices span) and
-    has exited — ``subprocess.run`` reaps it — before any worker is
-    spawned. Cached: a host's devices do not change under a driver,
-    and a supervised relaunch need not pay the child's backend
-    start-up again (failures are not cached).
+    assumption, ``runner_base.py:44-45``). On a TPU host the device
+    nodes this process can open answer, read from the filesystem
+    (:func:`_read_device_nodes`): the count, the platform and, for the
+    grids seen on the chip, the chip grid. Elsewhere — no TPU nodes, a
+    forced platform, an environment that narrows what the runtime
+    would show — and where ``ask_child`` is set, a child starts a
+    backend, reports what it saw (count, platform, and the chip grid
+    its devices span) and has exited — ``subprocess.run`` reaps it —
+    before any worker is spawned. Cached: a host's devices do not
+    change under a driver, and a supervised relaunch need not pay the
+    child's backend start-up again (failures are not cached).
 
-    Every call is a ``gang.slot_probe`` launch span: the child's
-    lifetime (one reach of the chip), or zero-length with
-    ``cached=True`` when the cache answered. Where a child ran the span
-    says where its seconds went: ``child_boot_s`` (span start to the
-    child's first line of Python: fork and interpreter),
-    ``child_import_s`` (``import jax``), ``child_backend_s``
-    (``jax.local_devices()``) and ``child_exit_s`` (the child's last
-    line to the span's end: interpreter teardown and the chip's
-    release). The last two are what a probe that started no runtime
-    would not spend."""
+    Where the nodes answered no runtime was started, so a wedged or
+    busy chip is not found here: it fails at the worker's start, a
+    ``GangFailure`` of kind ``start_failure`` with the rank's log tail.
+
+    Every call is a ``gang.slot_probe`` launch span, zero-length with
+    ``cached=True`` when the cache answered. ``source`` says what
+    answered: ``"devices"`` (with ``generation`` and ``chips``) or
+    ``"child"`` (with ``chips``, the devices it found); a gang on CPU
+    devices asks neither. Where a child ran the span says where its
+    seconds went: ``child_boot_s`` (span start to the child's first
+    line of Python: fork and interpreter), ``child_import_s``
+    (``import jax``), ``child_backend_s`` (``jax.local_devices()``) and
+    ``child_exit_s`` (the child's last line to the span's end:
+    interpreter teardown and the chip's release)."""
     from sparkdl_tpu import observe
 
     before, started = _probe_local_devices.cache_info().misses, time.time()
     with observe.span("gang.slot_probe", cat="launch") as probe:
-        found, child = _probe_local_devices(platform)
+        found, says, child = _probe_local_devices(platform, ask_child)
         probe.args["cached"] = cached = (
             _probe_local_devices.cache_info().misses == before)
-        if child and not cached:
-            first, import_s, backend_s, last = child
-            probe.args.update(
-                child_boot_s=first - started,
-                child_import_s=import_s, child_backend_s=backend_s,
-                child_exit_s=time.time() - last)
+        if not cached:
+            probe.args.update(says)
+            if child:
+                first, import_s, backend_s, last = child
+                probe.args.update(
+                    child_boot_s=first - started,
+                    child_import_s=import_s, child_backend_s=backend_s,
+                    child_exit_s=time.time() - last)
     return found
+
+
+# Where the slot probe reads the host's device nodes and sysfs: the
+# root of the filesystem on a host, a fake tree in tests.
+_ROOT = "/"
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+# a TPU chip's PCI device id -> its generation (the table of
+# jax._src.hardware_utils, which the driver does not import)
+_TPU_PCI_IDS = {"0x005e": "v4", "0x0062": "v5p", "0x0063": "v5e",
+                "0x006f": "v6e"}
+# the chip grids the runtime has shown on v5e hosts (_local_tpu's
+# docstring); a multi-rank gang on another host asks a child for its own
+_CHIP_GRIDS = {("v5e", 1): (1, 1, 1), ("v5e", 4): (2, 2, 1)}
+# set in the driver's environment, each narrows or lays out what the
+# workers' runtime would show, and the nodes alone cannot say how
+_NARROWING_ENV = ("TPU_VISIBLE_CHIPS", "TPU_PROCESS_BOUNDS",
+                  "TPU_CHIPS_PER_PROCESS_BOUNDS")
+
+
+def _openable(pattern):
+    return [n for n in sorted(glob.glob(os.path.join(_ROOT, pattern)))
+            if os.access(n, os.R_OK | os.W_OK)]
+
+
+def _pci_ids(function):
+    """``(vendor, device)`` of a PCI function's sysfs directory, or
+    None where it has no such files."""
+    try:
+        ids = []
+        for name in ("vendor", "device"):
+            with open(os.path.join(function, name)) as f:
+                ids.append(f.read().strip())
+        return tuple(ids)
+    except OSError:
+        return None
+
+
+def _tpu_generations():
+    """The generation of each TPU chip behind a device node this
+    process can open (None for a Google function the table does not
+    know): ``/dev/accelN`` through its sysfs device link,
+    ``/dev/vfio/<group>`` through the functions of its IOMMU group.
+    The NODES are counted, not the PCI bus: in a container sysfs can
+    list every chip of the machine while only the nodes passed through
+    can be opened, and the runtime sees only those."""
+    functions = [
+        os.path.join(_ROOT, "sys/class/accel", os.path.basename(n),
+                     "device") for n in _openable("dev/accel[0-9]*")]
+    for node in _openable("dev/vfio/*"):
+        group = os.path.basename(node)
+        if group.isdigit():     # not the container node /dev/vfio/vfio
+            functions += sorted(glob.glob(os.path.join(
+                _ROOT, "sys/kernel/iommu_groups", group, "devices", "*")))
+    return [_TPU_PCI_IDS.get(ids[1]) for ids in map(_pci_ids, functions)
+            if ids and ids[0] == _GOOGLE_PCI_VENDOR]
+
+
+def _read_device_nodes(platform):
+    """``(LocalDevices, the span's args)`` from the host's TPU device
+    nodes, or None where they do not settle what a worker's runtime
+    would show: no TPU node, a generation the table lacks or two
+    generations, a platform other than the TPU asked for, or a driver
+    environment that picks another platform or narrows the chips."""
+    jax_platforms = os.environ.get("JAX_PLATFORMS", "")
+    if (platform not in (None, "tpu")
+            or not (jax_platforms == "" or jax_platforms.startswith("tpu"))
+            or any(k in os.environ for k in _NARROWING_ENV)):
+        return None
+    generations = _tpu_generations()
+    if not generations or None in generations or len(set(generations)) > 1:
+        return None
+    generation, chips = generations[0], len(generations)
+    return (LocalDevices(chips, "tpu", _CHIP_GRIDS.get((generation, chips))),
+            {"source": "devices", "generation": generation, "chips": chips})
 
 
 # the probe child's second line: its own clock at its first line of
@@ -157,10 +247,22 @@ _CHILD_TIMES = "sparkdl-probe-times"
 
 
 @functools.lru_cache(maxsize=None)
-def _probe_local_devices(platform):
-    """``(LocalDevices, child's times or None)``."""
-    if platform == "cpu":   # a gang on CPU devices: no child to ask
-        return LocalDevices(os.cpu_count() or 1, "cpu", None), None
+def _probe_local_devices(platform, ask_child=False):
+    """``(LocalDevices, the span's args, child's times or None)``: the
+    device nodes' answer where they settle it and no child is asked
+    for, else the child's."""
+    if platform == "cpu":   # a gang on CPU devices: nothing to ask
+        return LocalDevices(os.cpu_count() or 1, "cpu", None), {}, None
+    read = None if ask_child else _read_device_nodes(platform)
+    if read is not None:
+        return read + (None,)
+    found, child = _ask_child(platform)
+    return found, {"source": "child", "chips": found.count}, child
+
+
+def _ask_child(platform):
+    """``(LocalDevices, child's times or None)`` from a child that
+    starts a backend and exits."""
     code = (
         "import time\n"
         "t0 = time.time()\n"
@@ -217,17 +319,23 @@ def _local_tpu(platform, spec_placement, num_workers):
     only on a whole topology: on a four-chip v5e host four ranks join
     (``2,2,1``) and two (``2,1,1`` over chips 0 and 1) die in the
     runtime's start-up (chip runs, PR 21). So a multi-rank gang fills
-    the host or is refused here, by name, before anything is spawned."""
+    the host or is refused here, by name, before anything is spawned.
+    Only such a gang needs the grid: where the device nodes answered
+    without one (a host of a kind not seen on the chip), a child is
+    asked for it, once."""
     if spec_placement is not None or platform == "cpu":
         return None, None
     local = probe_local_devices(platform)
     if local.platform != "tpu":
         return None, None
+    if 1 < num_workers == local.count and local.chip_bounds is None:
+        local = probe_local_devices(platform, ask_child=True)
     if 1 < num_workers != local.count:
+        grid = (f" (grid {','.join(map(str, local.chip_bounds))})"
+                if local.chip_bounds else "")
         raise SlotExhaustionError(
             f"HorovodRunner requested {num_workers} ranks on a host of "
-            f"{local.count} TPU chips (grid "
-            f"{','.join(map(str, local.chip_bounds))}): same-host ranks "
+            f"{local.count} TPU chips{grid}: same-host ranks "
             "are one TPU slice, and the runtime starts none on a part "
             f"of the host's chips. Use np={local.count}, one rank a "
             "chip, or np=1, one process over all of them.")

@@ -215,9 +215,12 @@ def phases(spans, until=None):
 
 
 def _says(name, spans):
-    """What `name`'s spans say besides their length: where the probe
-    child's seconds went, what answered a worker's reach."""
+    """What `name`'s spans say besides their length: what the slot
+    probe read or where its child's seconds went, what answered a
+    worker's reach."""
     for args in (s["args"] for s in spans if s["name"] == name):
+        if args.get("source") == "devices":
+            return f" (devices: {args['chips']} x {args['generation']})"
         if "child_backend_s" in args:
             return (" (child: boot {child_boot_s:.1f} s, import "
                     "{child_import_s:.1f} s, chip {child_backend_s:.1f} s, "

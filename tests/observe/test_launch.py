@@ -465,6 +465,9 @@ def test_the_slot_probe_says_where_its_childs_seconds_went(
     # the operator's line says where the child's seconds went
     assert ("s, import 3.5 s, chip 7.2 s, exit -11.0 s)" in summary_line(
         observe.launch_report())) == child
+    # what answered: the child, and how many devices it found
+    assert first["args"].pop("source") == "child"
+    assert first["args"].pop("chips") == 4
     if not child:
         assert first["args"] == {}
         return
