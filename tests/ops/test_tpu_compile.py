@@ -264,6 +264,57 @@ def test_moe_rows(one_chip, backward):
     assert sum("sparkdl_moe_add" in k for k in kernels) == 1 + backward
 
 
+# (rows the kernel gets, groups held, d_model, width of the first
+# product, d_ff): the expert cells at their published widths, and
+# Mixtral's, whose down projection is the one contraction split
+EXPERT_WIDTHS = {
+    "glm": (32768, 64, 2048, 3072, 1536),
+    "trinity": (65536, 128, 2048, 2048, 1024),
+    "hybrid": (180224, 128, 1024, 2688, 2688),
+    "mixtral": (4096, 8, 4096, 2 * 14336, 14336),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("cell", list(EXPERT_WIDTHS))
+def test_grouped_matmul(one_chip, monkeypatch, cell, backward):
+    """Both grouped products of an expert layer, and their gradient in
+    the rows (the transposed products), with the tiles the rule
+    chooses: under Mosaic's default scope, which ``gmm`` does not
+    widen, the whole contraction at every cell's width compiles. An
+    overfull VMEM fails here, without a chip."""
+    from sparkdl_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda: True)
+    rows, groups, d, up, ff = EXPERT_WIDTHS[cell]
+    for k, n in ((d, up), (ff, d), (up, d), (d, ff)):
+        tiles = gm.gmm_tiles(k, n, 2, 2)
+        assert tiles.vmem_bytes <= gm.VMEM_BUDGET
+        assert (tiles.tk == k) == ((cell, k) not in (
+            ("mixtral", ff), ("mixtral", up))), (k, n, tiles)
+
+    def fwd(x, w_in, w_down, sizes):
+        hidden = jnp.square(gm.grouped_matmul(x, w_in, sizes)[:, :ff])
+        return gm.grouped_matmul(hidden, w_down, sizes)
+
+    def fwd_bwd(x, w_in, w_down, sizes):
+        # squares on both sides keep the forward products in the program
+        return jax.grad(lambda x: jnp.square(
+            fwd(x, w_in, w_down, sizes).astype(jnp.float32)).sum())(x)
+
+    compiled = _compile(
+        fwd_bwd if backward else fwd,
+        _shape(one_chip, (rows, d), jnp.bfloat16),
+        _shape(one_chip, (groups, d, up), jnp.bfloat16),
+        _shape(one_chip, (groups, ff, d), jnp.bfloat16),
+        _shape(one_chip, (groups,), jnp.int32))
+    stacks = re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"",
+        compiled.as_text())
+    assert len(stacks) == 2 * (1 + backward), stacks
+    assert all(gm.NAME in s for s in stacks)
+
+
 def test_train_step_carries_the_kernel_names(one_chip, monkeypatch):
     """One LoRA train step at depth 1 with remat: in the compiled
     program each flash kernel is an instruction named after its
